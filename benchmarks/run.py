@@ -52,9 +52,15 @@ def main() -> None:
                      f"{args.json_dir}")
         sys.exit(check_only(paths))
 
+    import os
+
     import jax
 
+    from repro.runtime.compile_cache import enable_compile_cache
+
     jax.config.update("jax_enable_x64", True)  # f64 QP solves (paper)
+    enable_compile_cache(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
     from benchmarks.common import emit
     only = set(args.only.split(",")) if args.only else None
     if only:
@@ -65,7 +71,6 @@ def main() -> None:
 
     import importlib
     import json
-    import os
 
     # provenance header: the same environment fingerprint every
     # BENCH_*.json record carries, for runs that only keep the CSV

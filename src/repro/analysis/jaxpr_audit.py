@@ -44,8 +44,7 @@ from repro.analysis.report import Finding
 
 # Small, fixed trace problem: big enough to exercise every code path
 # (selection, planning history, doubled halves), small enough that every
-# trace is milliseconds.  The pinned entries reuse the byte-golden recipe
-# (tests/golden/regen.py): l=16, d=4, B=3, C=2.0, seed 0.
+# trace is milliseconds: l=16, d=4, B=3, C=2.0, seed 0.
 AUDIT_L, AUDIT_D, AUDIT_B = 16, 4, 3
 
 # Primitives that sync the host; forbidden inside while_loop bodies.
@@ -404,8 +403,7 @@ def emit_golden(path: str) -> None:
     """(Re)write the pinned structural signatures.
 
     Run after an INTENTIONAL trace change to the feature-off engine, and
-    review the JSON diff — it is the structural counterpart of
-    ``tests/golden/regen.py`` for the byte fixtures.
+    review the JSON diff.
     """
     import jax
 

@@ -52,12 +52,13 @@ from repro.core.solver_fused import (FusedResult, solve_fused_batched,
                                      solve_fused_chunked_qp)
 from repro.core.sharded_lanes import (resolve_lane_mesh, solve_fused_sharded,
                                       solve_fused_sharded_qp)
+from repro.kernels.ref import HIGHEST
 
 
 def sqdist(X: jax.Array) -> jax.Array:
     """Pairwise squared distances (l, l) — the shared, gamma-free Gram work."""
     sq = jnp.sum(X * X, axis=-1)
-    d2 = sq[:, None] + sq[None, :] - 2.0 * (X @ X.T)
+    d2 = sq[:, None] + sq[None, :] - 2.0 * jnp.dot(X, X.T, precision=HIGHEST)
     return jnp.maximum(d2, 0.0)
 
 
@@ -779,10 +780,12 @@ def grid_decision(Xq, X, gammas, alpha: jax.Array,
     gammas = jnp.atleast_1d(jnp.asarray(gammas, X.dtype))
     sq_q = jnp.sum(Xq * Xq, axis=-1)
     sq_x = jnp.sum(X * X, axis=-1)
-    d2 = jnp.maximum(sq_q[:, None] + sq_x[None, :] - 2.0 * (Xq @ X.T), 0.0)
+    d2 = jnp.maximum(sq_q[:, None] + sq_x[None, :]
+                     - 2.0 * jnp.dot(Xq, X.T, precision=HIGHEST), 0.0)
 
     def per_gamma(gamma, a_g, b_g):
         Kq = jnp.exp(-gamma * d2)                      # (m, l) once per gamma
-        return jnp.einsum("ml,kcl->kcm", Kq, a_g) + b_g[..., None]
+        return (jnp.einsum("ml,kcl->kcm", Kq, a_g, precision=HIGHEST)
+                + b_g[..., None])
 
     return jax.vmap(per_gamma)(gammas, alpha, b)

@@ -27,6 +27,7 @@ import numpy as np
 
 from repro.core import qp as qp_mod
 from repro.core.solver import SolveResult, SolverConfig, solve
+from repro.kernels.ref import HIGHEST
 
 
 def class_index(y) -> Tuple[np.ndarray, np.ndarray]:
@@ -141,7 +142,7 @@ def ovr_decision(Kq: jax.Array, alpha: jax.Array, b: jax.Array) -> jax.Array:
     ``alpha`` (k, l) carries the label signs (signed dual), ``b`` is (k,).
     Returns (m, k): one binary decision value per class head.
     """
-    return Kq @ alpha.T + b[None, :]
+    return jnp.dot(Kq, alpha.T, precision=HIGHEST) + b[None, :]
 
 
 def ovr_predict(Kq: jax.Array, alpha: jax.Array, b: jax.Array) -> jax.Array:

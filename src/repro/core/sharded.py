@@ -34,13 +34,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-if hasattr(jax, "shard_map"):  # jax >= 0.6 public API
-    _shard_map = jax.shard_map
-    _SHARD_MAP_CHECK = {"check_vma": False}
-else:  # older jax: experimental namespace, check_rep spelling
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SHARD_MAP_CHECK = {"check_rep": False}
-
 from repro.core.qp import TAU
 from repro.core import step as step_mod
 from repro.core.solver import SolverConfig
@@ -333,9 +326,9 @@ def solve_sharded(X, y, C, gamma, mesh: Mesh, cfg: SolverConfig,
         return (c.alpha, c.t, obj, c.gap, c.done, c.n_planning, b)
 
     spec_l = P(axis)
-    out = jax.jit(_shard_map(
+    out = jax.jit(jax.shard_map(
         local_solve, mesh=mesh,
         in_specs=(P(axis, None), spec_l),
         out_specs=(spec_l, P(), P(), P(), P(), P(), P()),
-        **_SHARD_MAP_CHECK))(X, y)
+        check_vma=False))(X, y)
     return ShardedResult(*out)

@@ -69,13 +69,6 @@ import jax.numpy as jnp
 
 from jax.sharding import Mesh, PartitionSpec as Pspec
 
-if hasattr(jax, "shard_map"):  # jax >= 0.6 public API
-    _shard_map = jax.shard_map
-    _SHARD_MAP_CHECK = {"check_vma": False}
-else:  # older jax: experimental namespace, check_rep spelling
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SHARD_MAP_CHECK = {"check_rep": False}
-
 from repro.core.solver import SolverConfig
 from repro.core.solver_fused import FusedResult, solve_fused_batched_qp
 from repro.launch.mesh import make_lane_mesh
@@ -198,10 +191,10 @@ def _solve_sharded(X, P, L, U, gamma, cfg, mesh, axis, impl, block_l,
                 r.converged, r.n_planning, r.n_unshrink) + ring_leaves
 
     n_ring = len(dataclasses.fields(TelemetryRing)) if collect else 0
-    out = _shard_map(local_solve, mesh=mesh,
-                     in_specs=tuple(in_specs),
-                     out_specs=(lane1,) * (9 + n_ring),
-                     **_SHARD_MAP_CHECK)(X, *operands)
+    out = jax.shard_map(local_solve, mesh=mesh,
+                        in_specs=tuple(in_specs),
+                        out_specs=(lane1,) * (9 + n_ring),
+                        check_vma=False)(X, *operands)
 
     # gather-back: undo the schedule, strip the pad lanes
     out = tuple(jnp.take(leaf, inv[:B], axis=0) for leaf in out)

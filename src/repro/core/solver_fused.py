@@ -49,6 +49,7 @@ from repro.core.qp import TAU
 from repro.core.solver import DEFAULT_SHRINK_EVERY, SolverConfig
 from repro.kernels import ops
 from repro.kernels import row_source
+from repro.kernels.ref import take_lane as _take_lane
 from repro.telemetry.ring import (RingConfig, TelemetryRing, ring_init,
                                   ring_update)
 
@@ -292,11 +293,6 @@ class _ConjState(NamedTuple):
     u: jax.Array    # (B, n) Q (e_pi - e_pj): previous direction's Q-product
                     # (pass B's in-VMEM row difference k_i - k_j)
     ok: jax.Array   # (B,) direction valid (reset on clip / shrink events)
-
-
-def _take_lane(M, idx):
-    """Per-lane gather: M (B, l), idx (B,) -> (B,)."""
-    return jnp.take_along_axis(M, idx[:, None], axis=1)[:, 0]
 
 
 @partial(jax.jit, static_argnames=("cfg", "impl", "block_l", "doubled",

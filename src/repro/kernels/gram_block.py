@@ -16,13 +16,16 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.ref import HIGHEST
+
 
 def _kernel(g_ref, X1_ref, X2_ref, s1_ref, s2_ref, out_ref):
     gamma = g_ref[0, 0]
     x1 = X1_ref[...]                       # (BI, d)
     x2 = X2_ref[...]                       # (BJ, d)
-    prod = jax.lax.dot_general(x1, x2, (((1,), (1,)), ((), ())),
-                               preferred_element_type=jnp.promote_types(x1.dtype, jnp.float32))
+    prod = jax.lax.dot_general(
+        x1, x2, (((1,), (1,)), ((), ())), precision=HIGHEST,
+        preferred_element_type=jnp.promote_types(x1.dtype, jnp.float32))
     d2 = s1_ref[...].T + s2_ref[...] - 2.0 * prod   # (BI, BJ)
     out_ref[...] = jnp.exp(-gamma * jnp.maximum(d2, 0.0)).astype(
         out_ref.dtype)

@@ -1,4 +1,4 @@
-"""jit'd wrappers around the Pallas kernels: padding, dispatch, epilogues.
+"""jit'd wrappers around the Pallas kernels: padding, tiling, dispatch.
 
 ``impl`` selects the backend:
   * "pallas"    — compiled Pallas (TPU target),
@@ -9,7 +9,10 @@
 
 All wrappers pad the example dimension to the block multiple with *inert*
 rows (L = U = 0 so they can never be selected; see sharded.py for the same
-trick) and the feature dimension to a lane multiple for the MXU.
+trick) and the feature dimension to a lane multiple for the MXU.  The
+block sizes come from the shapes (:func:`plan_tiles`): the caller's
+``block_l`` is an upper bound that shrinks — and the lane batch splits
+into blocks — until one grid step's working set fits the VMEM budget.
 
 The batched wrappers dispatch over a row-source axis as well (see
 :mod:`repro.kernels.row_source`): rows recomputed from shared X tiles
@@ -22,23 +25,18 @@ dtype (a float32 round-trip is lossy beyond l = 2^24).
 
 from __future__ import annotations
 
-import functools
-from typing import Tuple
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.kernels import ref as ref_ops
 from repro.kernels.gram_block import gram_pallas
-from repro.kernels.rbf_row_wss import (rbf_row_wss_batched_pallas,
-                                       rbf_row_wss_pallas,
+from repro.kernels.rbf_row_wss import (LANES, rbf_row_wss_batched_pallas,
                                        row_wss_batched_rows_pallas)
 from repro.kernels.rbf_update_wss import (rbf_update_wss_batched_pallas,
-                                          rbf_update_wss_pallas,
                                           update_wss_batched_rows_pallas)
 from repro.kernels.row_source import RowSource
-
-NEG_INF = -jnp.inf
 
 
 def resolve_impl(impl: str) -> str:
@@ -63,10 +61,8 @@ def _pad_d(a, dpad):
     return jnp.pad(a, widths)
 
 
-def pad_dims(l: int, d: int, block_l: int) -> Tuple[int, int]:
-    lpad = ((l + block_l - 1) // block_l) * block_l
-    dpad = ((d + 127) // 128) * 128
-    return lpad, dpad
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
 
 
 def _iscal(i_idx, n: int):
@@ -79,6 +75,96 @@ def _iscal(i_idx, n: int):
     return jnp.asarray(i_idx, jnp.int32).reshape(n, 1)
 
 
+# ---------------------------------------------------------------------------
+# Tile planning: the VMEM footprint of one grid step, from shapes
+# ---------------------------------------------------------------------------
+#
+# Each grid step holds its input and output blocks twice (Pallas double-
+# buffers the HBM<->VMEM pipeline).  Calibrated against the v5e compiler:
+# the scoped allocation it reports is that double-buffered block total
+# (to within 0.5 MiB at B = 512..1024 for every variant) — the selection
+# and update algebra streams through vector registers.  The estimate adds
+# two (b, BL) tiles of slack, and the budget keeps a margin under the
+# 16 MiB scoped-VMEM default of v5e's compiler.  tests/test_tpu_compile.py
+# compiles every variant at its planned tiles.
+
+VMEM_BUDGET = 12 * 2**20
+_SUBLANES = 8                # lane batches pad to a sublane multiple
+
+
+class Tiles(NamedTuple):
+    block_b: int      # lanes per grid block (multiple of 8, divides bpad)
+    block_l: int      # examples per grid block
+    bpad: int         # padded lane count
+    lpad: int         # padded example count
+
+
+def vmem_bytes(bb: int, bl: int, *, H: int, stacks: int, rows: int,
+               dpad: int, itemsize: int = 4) -> int:
+    """Estimated VMEM bytes of one grid step of a batched pass.
+
+    ``stacks`` counts the (H, bb, bl) lane-state tiles in and out (G,
+    alpha, L, U, optional act, pass B's G_out); ``rows`` the (bb, bl)
+    row tiles (gathered kernel rows, the Conjugate-SMO direction in and
+    out); ``dpad`` is the padded feature width of the X tile and the
+    query rows (0 for the Gram-bank passes).
+    """
+    blocks = (stacks * H * bb * bl + rows * bb * bl
+              + bl * dpad + _SUBLANES * bl            # X tile, sqn row
+              + 2 * bb * dpad + 6 * bb * LANES)       # query rows, scalars
+    return itemsize * (2 * blocks + 2 * bb * bl)
+
+
+def plan_tiles(B: int, l: int, block_l: int, **footprint) -> Tiles:
+    """Tiles for a batched pass over ``B`` lanes and ``l`` examples.
+
+    The l block starts at the caller's ``block_l`` and halves (down to
+    128, the hardware lane width) until the step fits :data:`VMEM_BUDGET`
+    with every lane in one block; if even that overflows, the lanes split
+    into the fewest blocks that fit.  ``footprint`` are the per-pass
+    counts of :func:`vmem_bytes`.  A ``block_l`` below 128 (interpret-mode
+    tests) is taken as given.
+    """
+    bpad = _round_up(max(B, 1), _SUBLANES)
+    bl = block_l
+    while bl > 128 and vmem_bytes(bpad, bl, **footprint) > VMEM_BUDGET:
+        bl = max(128, bl // 2)
+    n_c = 1
+    bb = bpad
+    while bb > _SUBLANES and vmem_bytes(bb, bl, **footprint) > VMEM_BUDGET:
+        n_c += 1
+        bb = _round_up(-(-bpad // n_c), _SUBLANES)
+    return Tiles(bb, bl, bb * n_c, _round_up(l, bl))
+
+
+def _dpad(d: int) -> int:
+    return _round_up(d, 128)
+
+
+def pass_a_tiles(B, l, d, block_l, *, H=1, masked=False, rows=False):
+    """:func:`plan_tiles` for pass A (``rows``: the Gram-bank variant)."""
+    return plan_tiles(B, l, block_l, H=H, stacks=4 + masked,
+                      rows=int(rows), dpad=0 if rows else _dpad(d))
+
+
+def pass_b_tiles(B, l, d, block_l, *, H=1, masked=False, conj=False,
+                 rows=False):
+    """:func:`plan_tiles` for pass B (``rows``: the Gram-bank variant)."""
+    return plan_tiles(B, l, block_l, H=H, stacks=5 + masked,
+                      rows=2 * conj + 2 * rows,
+                      dpad=0 if rows else _dpad(d))
+
+
+# ---------------------------------------------------------------------------
+# Single-lane wrappers (the B = 1 instance of the batched kernels)
+# ---------------------------------------------------------------------------
+
+
+def _row1(a, lpad, value=0.0):
+    """(l,) vector -> (1, 1, lpad) single-lane state stack."""
+    return _pad_l(a, lpad, value).reshape(1, 1, lpad)
+
+
 def rbf_row_wss(X, sqn, G, alpha, L, U, xq, a_i, L_i, U_i, g_i, i_idx,
                 use_exact, gamma, *, impl: str = "auto",
                 block_l: int = 1024):
@@ -88,18 +174,18 @@ def rbf_row_wss(X, sqn, G, alpha, L, U, xq, a_i, L_i, U_i, g_i, i_idx,
     if impl == "jnp":
         return ref_ops.rbf_row_wss(X, sqn, G, alpha, L, U, xq, a_i, L_i,
                                    U_i, g_i, i_idx, use_exact, gamma)
-    lpad, dpad = pad_dims(l, d, block_l)
+    lpad, dpad = _round_up(l, block_l), _dpad(d)
     dtype = X.dtype
-    scal = jnp.stack([jnp.dot(xq, xq), a_i, L_i, U_i, g_i,
-                      jnp.asarray(gamma, dtype),
-                      use_exact.astype(dtype)]).reshape(1, 7).astype(dtype)
-    k, bmax, barg = rbf_row_wss_pallas(
-        _pad_d(_pad_l(X, lpad), dpad), _pad_l(sqn, lpad), _pad_l(G, lpad),
-        _pad_l(alpha, lpad), _pad_l(L, lpad), _pad_l(U, lpad),
-        _pad_d(xq, dpad), scal, _iscal(i_idx, 1),
-        block_l=block_l, interpret=(impl == "interpret"))
-    w = jax.lax.argmax(bmax, 0, jnp.int32)
-    return k[:l], jnp.take(barg, w), jnp.take(bmax, w)
+    scal = jnp.stack([jnp.dot(xq, xq, precision=ref_ops.HIGHEST),
+                      jnp.asarray(gamma, dtype), a_i, L_i,
+                      U_i, g_i, use_exact.astype(dtype)]).reshape(1, 7)
+    j, gain, k = rbf_row_wss_batched_pallas(
+        _pad_d(_pad_l(X, lpad), dpad), _pad_l(sqn, lpad), _row1(G, lpad),
+        _row1(alpha, lpad), _row1(L, lpad), _row1(U, lpad),
+        _pad_d(xq, dpad).reshape(1, dpad), scal.astype(dtype),
+        _iscal(i_idx, 1), block_l=block_l,
+        interpret=(impl == "interpret"), base_l=l, emit_k=True)
+    return k[0, :l], j[0], gain[0]
 
 
 def rbf_update_wss(X, sqn, G, k_i, alpha_new, L, U, xq_j, mu, gamma,
@@ -110,17 +196,19 @@ def rbf_update_wss(X, sqn, G, k_i, alpha_new, L, U, xq_j, mu, gamma,
     if impl == "jnp":
         return ref_ops.rbf_update_wss(X, sqn, G, k_i, xq_j, mu, alpha_new,
                                       L, U, gamma)
-    lpad, dpad = pad_dims(l, d, block_l)
+    lpad, dpad = _round_up(l, block_l), _dpad(d)
     dtype = X.dtype
-    scal = jnp.stack([jnp.dot(xq_j, xq_j), jnp.asarray(mu, dtype),
-                      jnp.asarray(gamma, dtype)]).reshape(1, 3).astype(dtype)
-    G_new, bmax, barg, bmin = rbf_update_wss_pallas(
-        _pad_d(_pad_l(X, lpad), dpad), _pad_l(sqn, lpad), _pad_l(G, lpad),
-        _pad_l(k_i, lpad), _pad_l(alpha_new, lpad), _pad_l(L, lpad),
-        _pad_l(U, lpad), _pad_d(xq_j, dpad), scal,
-        block_l=block_l, interpret=(impl == "interpret"))
-    w = jax.lax.argmax(bmax, 0, jnp.int32)
-    return (G_new[:l], jnp.take(barg, w), jnp.take(bmax, w), jnp.min(bmin))
+    xq = jnp.broadcast_to(_pad_d(xq_j, dpad), (2, dpad))   # (i, j) rows
+    scal = jnp.stack([jnp.zeros((), dtype),
+                      jnp.dot(xq_j, xq_j, precision=ref_ops.HIGHEST),
+                      jnp.asarray(mu, dtype),
+                      jnp.asarray(gamma, dtype)]).reshape(1, 4)
+    G_new, i_next, g_i_next, g_dn = rbf_update_wss_batched_pallas(
+        _pad_d(_pad_l(X, lpad), dpad), _pad_l(sqn, lpad), _row1(G, lpad),
+        _row1(alpha_new, lpad), _row1(L, lpad), _row1(U, lpad), xq,
+        scal.astype(dtype), ki=_pad_l(k_i, lpad).reshape(1, lpad),
+        block_l=block_l, interpret=(impl == "interpret"), base_l=l)
+    return G_new[0, 0, :l], i_next[0], g_i_next[0], g_dn[0]
 
 
 # ---------------------------------------------------------------------------
@@ -128,21 +216,15 @@ def rbf_update_wss(X, sqn, G, k_i, alpha_new, L, U, xq_j, mu, gamma,
 # ---------------------------------------------------------------------------
 #
 # The example dimension is padded exactly as above; the lane dimension is
-# padded to a sublane multiple (8) with *inert* lanes: L = U = alpha = 0
+# padded to the lane-block multiple with *inert* lanes: L = U = alpha = 0
 # rows can never be selected in pass A, and mu = 0 makes pass B a no-op, so
-# padded lanes never influence the epilogue reductions.
+# padded lanes never influence any lane's result.
 #
 # The doubled ε-SVR operator (dup=True, lane state n = 2l) is carried as an
 # (2, bpad, lpad) half stack: the kernels compute the base row tile once
 # per grid step and apply it to both halves via index arithmetic, so the
 # matmul width, the VMEM X tile, and the padded HBM traffic all stay those
-# of the base problem (the old launch path pre-tiled X to 2l).
-
-_LANE = 8
-
-
-def pad_lanes(B: int) -> int:
-    return ((B + _LANE - 1) // _LANE) * _LANE
+# of the base problem.
 
 
 def _pad_bl(a, bpad, lpad, value=0.0):
@@ -156,22 +238,6 @@ def _pad_b(a, bpad, value=0.0):
     return jnp.pad(a, widths, constant_values=value)
 
 
-def _first_max(bmax, barg):
-    """Cross-block reduction matching ``jnp.argmax`` tie-breaking.
-
-    Picks the LOWEST global index among blocks attaining the max.  A plain
-    argmax over blocks is only order-correct while per-block winners are
-    monotone in global index — the doubled half stack breaks that (half 1
-    of block b carries larger indices than half 0 of block b+1), so a
-    bitwise gain tie could otherwise select a different (valid but
-    oracle-divergent) coordinate.  Returns (idx (B,), max (B,)).
-    """
-    best = jnp.max(bmax, axis=1, keepdims=True)
-    sentinel = jnp.iinfo(jnp.int32).max
-    cand = jnp.where(bmax == best, barg, sentinel)
-    return jnp.min(cand, axis=1), best[:, 0]
-
-
 def _stack_halves(a, H: int, bpad: int, lpad: int, value=0.0):
     """(B, H*l) lane state -> (H, bpad, lpad) inert-padded half stack."""
     l = a.shape[1] // H
@@ -183,6 +249,15 @@ def _unstack_halves(a, B: int, l: int):
     """(H, bpad, lpad) kernel output -> (B, H*l) lane state."""
     return jnp.concatenate([a[h, :B, :l] for h in range(a.shape[0])],
                            axis=1)
+
+
+def _state_stacks(t: Tiles, H: int, act, *state):
+    """Half-stack the (B, n) lane-state leaves (and the optional act
+    mask, as 1.0/0.0 in the data dtype) at the planned padding."""
+    stacks = [_stack_halves(a, H, t.bpad, t.lpad) for a in state]
+    act_st = (None if act is None else
+              _stack_halves(act.astype(state[0].dtype), H, t.bpad, t.lpad))
+    return stacks, act_st
 
 
 def rbf_row_wss_batched(X, sqn, G, alpha, L, U, XQ, sqq, a_i, L_i, U_i,
@@ -208,23 +283,38 @@ def rbf_row_wss_batched(X, sqn, G, alpha, L, U, XQ, sqq, a_i, L_i, U_i,
     l, d = X.shape
     H = 2 if dup else 1
     B = G.shape[0]
-    lpad, dpad = pad_dims(l, d, block_l)
-    bpad = pad_lanes(B)
+    t = pass_a_tiles(B, l, d, block_l, H=H, masked=act is not None)
     dtype = X.dtype
     scal = jnp.stack([sqq, jnp.broadcast_to(gammas, (B,)),
                       a_i, L_i, U_i, g_i,
                       use_exact.astype(dtype)], axis=1).astype(dtype)
-    act_st = (None if act is None
-              else _stack_halves(act.astype(dtype), H, bpad, lpad))
-    bmax, barg = rbf_row_wss_batched_pallas(
-        _pad_d(_pad_l(X, lpad), dpad), _pad_l(sqn, lpad),
-        _stack_halves(G, H, bpad, lpad), _stack_halves(alpha, H, bpad, lpad),
-        _stack_halves(L, H, bpad, lpad), _stack_halves(U, H, bpad, lpad),
-        _pad_b(_pad_d(XQ, dpad), bpad), _pad_b(scal, bpad),
-        _pad_b(_iscal(i_idx, B), bpad), act_st,
-        block_l=block_l, interpret=(impl == "interpret"), base_l=l)
-    j, gain = _first_max(bmax, barg)
+    stacks, act_st = _state_stacks(t, H, act, G, alpha, L, U)
+    j, gain = rbf_row_wss_batched_pallas(
+        _pad_d(_pad_l(X, t.lpad), _dpad(d)), _pad_l(sqn, t.lpad), *stacks,
+        _pad_b(_pad_d(XQ, _dpad(d)), t.bpad), _pad_b(scal, t.bpad),
+        _pad_b(_iscal(i_idx, B), t.bpad), act_st, block_l=t.block_l,
+        block_b=t.block_b, interpret=(impl == "interpret"), base_l=l)
     return j[:B], gain[:B]
+
+
+def _stack_queries(XQi, XQj, t: Tiles):
+    """(B, d) i and j query rows -> the (2 bpad, d) pass B layout: per
+    lane block, its i rows then its j rows."""
+    n_c = t.bpad // t.block_b
+    qi, qj = (_pad_b(q, t.bpad).reshape(n_c, t.block_b, -1)
+              for q in (XQi, XQj))
+    return jnp.concatenate([qi, qj], axis=1).reshape(2 * t.bpad, -1)
+
+
+def _pass_b_result(out, B: int, l: int, dup: bool, conj: bool):
+    """Unpad a pass B launch: (G_new (B, n), i_next, g_i_next, g_dn), plus
+    the full-width direction row ``r`` under Conjugate-SMO."""
+    G_new, i_next, g_i_next, g_dn = out[:4]
+    res = (_unstack_halves(G_new, B, l), i_next[:B], g_i_next[:B], g_dn[:B])
+    if conj:
+        r = out[4][:B, :l]
+        return res + (ref_ops.tile_rows(r) if dup else r,)
+    return res
 
 
 def rbf_update_wss_batched(X, sqn, G, alpha_new, L, U, XQi, sqqi, XQj, sqqj,
@@ -252,41 +342,26 @@ def rbf_update_wss_batched(X, sqn, G, alpha_new, L, U, XQi, sqqi, XQj, sqqj,
     l, d = X.shape
     H = 2 if dup else 1
     B = G.shape[0]
-    lpad, dpad = pad_dims(l, d, block_l)
-    bpad = pad_lanes(B)
-    dtype = X.dtype
     conj = dirv is not None
+    t = pass_b_tiles(B, l, d, block_l, H=H, masked=act is not None,
+                     conj=conj)
+    dtype = X.dtype
+    cols = [sqqi, sqqj, jnp.broadcast_to(mu, (B,)),
+            jnp.broadcast_to(gammas, (B,))]
+    # the doubled operator's direction rows are half-symmetric (tiled base
+    # rows), so the kernels carry the base half only
+    dirv_row = None
     if conj:
-        scal = jnp.stack([sqqi, sqqj, jnp.broadcast_to(mu, (B,)),
-                          jnp.broadcast_to(gammas, (B,)),
-                          jnp.broadcast_to(mu2, (B,))],
-                         axis=1).astype(dtype)
-        # the doubled operator's direction rows are half-symmetric (tiled
-        # base rows), so the kernels carry the base half only
-        dirv_row = _pad_bl(dirv[:, :l].astype(dtype), bpad, lpad)
-    else:
-        scal = jnp.stack([sqqi, sqqj, jnp.broadcast_to(mu, (B,)),
-                          jnp.broadcast_to(gammas, (B,))],
-                         axis=1).astype(dtype)
-        dirv_row = None
-    act_st = (None if act is None
-              else _stack_halves(act.astype(dtype), H, bpad, lpad))
+        cols.append(jnp.broadcast_to(mu2, (B,)))
+        dirv_row = _pad_bl(dirv[:, :l].astype(dtype), t.bpad, t.lpad)
+    scal = jnp.stack(cols, axis=1).astype(dtype)
+    stacks, act_st = _state_stacks(t, H, act, G, alpha_new, L, U)
     out = rbf_update_wss_batched_pallas(
-        _pad_d(_pad_l(X, lpad), dpad), _pad_l(sqn, lpad),
-        _stack_halves(G, H, bpad, lpad),
-        _stack_halves(alpha_new, H, bpad, lpad),
-        _stack_halves(L, H, bpad, lpad), _stack_halves(U, H, bpad, lpad),
-        _pad_b(_pad_d(XQi, dpad), bpad), _pad_b(_pad_d(XQj, dpad), bpad),
-        _pad_b(scal, bpad), act_st, dirv_row,
-        block_l=block_l, interpret=(impl == "interpret"), base_l=l)
-    G_new, bmax, barg, bmin = out[:4]
-    i_next, g_i_next = _first_max(bmax, barg)
-    res = (_unstack_halves(G_new, B, l), i_next[:B], g_i_next[:B],
-           jnp.min(bmin, axis=1)[:B])
-    if conj:
-        r = out[4][:B, :l]
-        return res + (ref_ops.tile_rows(r) if dup else r,)
-    return res
+        _pad_d(_pad_l(X, t.lpad), _dpad(d)), _pad_l(sqn, t.lpad), *stacks,
+        _stack_queries(_pad_d(XQi, _dpad(d)), _pad_d(XQj, _dpad(d)), t),
+        _pad_b(scal, t.bpad), act_st, dirv_row, block_l=t.block_l, block_b=t.block_b,
+        interpret=(impl == "interpret"), base_l=l)
+    return _pass_b_result(out, B, l, dup, conj)
 
 
 def row_wss_batched_rows(KR, G, alpha, L, U, a_i, L_i, U_i, g_i, i_idx,
@@ -305,20 +380,16 @@ def row_wss_batched_rows(KR, G, alpha, L, U, a_i, L_i, U_i, g_i, i_idx,
                                               act=act)
     B, l = KR.shape
     H = 2 if dup else 1
-    lpad = pad_dims(l, 1, block_l)[0]
-    bpad = pad_lanes(B)
+    t = pass_a_tiles(B, l, 0, block_l, H=H, masked=act is not None,
+                     rows=True)
     dtype = KR.dtype
     scal = jnp.stack([a_i, L_i, U_i, g_i,
                       use_exact.astype(dtype)], axis=1).astype(dtype)
-    act_st = (None if act is None
-              else _stack_halves(act.astype(dtype), H, bpad, lpad))
-    bmax, barg = row_wss_batched_rows_pallas(
-        _pad_bl(KR, bpad, lpad), _stack_halves(G, H, bpad, lpad),
-        _stack_halves(alpha, H, bpad, lpad),
-        _stack_halves(L, H, bpad, lpad), _stack_halves(U, H, bpad, lpad),
-        _pad_b(scal, bpad), _pad_b(_iscal(i_idx, B), bpad), act_st,
-        block_l=block_l, interpret=(impl == "interpret"), base_l=l)
-    j, gain = _first_max(bmax, barg)
+    stacks, act_st = _state_stacks(t, H, act, G, alpha, L, U)
+    j, gain = row_wss_batched_rows_pallas(
+        _pad_bl(KR, t.bpad, t.lpad), *stacks, _pad_b(scal, t.bpad),
+        _pad_b(_iscal(i_idx, B), t.bpad), act_st, block_l=t.block_l,
+        block_b=t.block_b, interpret=(impl == "interpret"), base_l=l)
     return j[:B], gain[:B]
 
 
@@ -339,34 +410,22 @@ def update_wss_batched_rows(KRi, KRj, G, alpha_new, L, U, mu, *,
                                                     mu2=mu2)
     B, l = KRi.shape
     H = 2 if dup else 1
-    lpad = pad_dims(l, 1, block_l)[0]
-    bpad = pad_lanes(B)
-    dtype = KRi.dtype
     conj = dirv is not None
+    t = pass_b_tiles(B, l, 0, block_l, H=H, masked=act is not None,
+                     conj=conj, rows=True)
+    dtype = KRi.dtype
+    cols = [jnp.broadcast_to(mu, (B,))]
+    dirv_row = None
     if conj:
-        scal = jnp.stack([jnp.broadcast_to(mu, (B,)),
-                          jnp.broadcast_to(mu2, (B,))], axis=1).astype(dtype)
-        dirv_row = _pad_bl(dirv[:, :l].astype(dtype), bpad, lpad)
-    else:
-        scal = jnp.broadcast_to(mu, (B,)).astype(dtype)[:, None]
-        dirv_row = None
-    act_st = (None if act is None
-              else _stack_halves(act.astype(dtype), H, bpad, lpad))
+        cols.append(jnp.broadcast_to(mu2, (B,)))
+        dirv_row = _pad_bl(dirv[:, :l].astype(dtype), t.bpad, t.lpad)
+    scal = jnp.stack(cols, axis=1).astype(dtype)
+    stacks, act_st = _state_stacks(t, H, act, G, alpha_new, L, U)
     out = update_wss_batched_rows_pallas(
-        _pad_bl(KRi, bpad, lpad), _pad_bl(KRj, bpad, lpad),
-        _stack_halves(G, H, bpad, lpad),
-        _stack_halves(alpha_new, H, bpad, lpad),
-        _stack_halves(L, H, bpad, lpad), _stack_halves(U, H, bpad, lpad),
-        _pad_b(scal, bpad), act_st, dirv_row,
-        block_l=block_l, interpret=(impl == "interpret"), base_l=l)
-    G_new, bmax, barg, bmin = out[:4]
-    i_next, g_i_next = _first_max(bmax, barg)
-    res = (_unstack_halves(G_new, B, l), i_next[:B], g_i_next[:B],
-           jnp.min(bmin, axis=1)[:B])
-    if conj:
-        r = out[4][:B, :l]
-        return res + (ref_ops.tile_rows(r) if dup else r,)
-    return res
+        _pad_bl(KRi, t.bpad, t.lpad), _pad_bl(KRj, t.bpad, t.lpad), *stacks,
+        _pad_b(scal, t.bpad), act_st, dirv_row, block_l=t.block_l,
+        block_b=t.block_b, interpret=(impl == "interpret"), base_l=l)
+    return _pass_b_result(out, B, l, dup, conj)
 
 
 # ---------------------------------------------------------------------------
@@ -434,9 +493,9 @@ def gram(X1, X2=None, gamma=1.0, *, impl: str = "auto",
         return ref_ops.gram_cross(X1, X2, gamma)
     l1, d = X1.shape
     l2 = X2.shape[0]
-    l1p = ((l1 + block_i - 1) // block_i) * block_i
-    l2p = ((l2 + block_j - 1) // block_j) * block_j
-    dpad = ((d + 127) // 128) * 128
+    l1p = _round_up(l1, block_i)
+    l2p = _round_up(l2, block_j)
+    dpad = _dpad(d)
     s1 = jnp.sum(X1 * X1, axis=-1)
     s2 = jnp.sum(X2 * X2, axis=-1)
     out = gram_pallas(

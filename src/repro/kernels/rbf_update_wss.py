@@ -2,24 +2,26 @@
 
 The second row k_j is computed tile-by-tile in VMEM and is *never written to
 HBM* — it only feeds the update G <- G - mu (k_i - k_j) in-register.  The
-same pass emits the per-block first-order argmax over I_up(alpha_new) (the
-next iteration's i-selection) and both KKT gap endpoints, so the stopping
-rule costs no extra pass over G.
+same pass folds the first-order argmax over I_up(alpha_new) (the next
+iteration's i-selection) and both KKT gap endpoints into running per-lane
+results held in VMEM across the sequential l axis (the lane-dense output
+blocks of :mod:`repro.kernels.rbf_row_wss`), so the stopping rule costs no
+extra pass over G and no epilogue after the launch.
 
 HBM traffic per iteration for the whole solver (pass A + pass B):
-read X twice, read G twice, write G once, write k_i once, plus the (1, BL)
-mask vectors — i.e. ~2*l*d + 7*l elements, vs ~2*l*d + 12*l for the naive
-separate row/update/argmax graph.  For small d (the paper's datasets have
-d <= 60) the fusion saves ~40% of HBM bytes; the structural win is fewer
-kernel launches and no HBM round-trip for gains/k_j.
+read X twice, read G twice, write G once, plus the lane-state tiles
+(alpha, L, U, optional act) once per pass.  For small d (the paper's
+datasets have d <= 60) the lane state dominates; the structural win is
+two launches per iteration and no HBM round-trip for gains/k_j.
 
 Like pass A, the update/stopping algebra is dual-generic (arbitrary L/U
 boxes) and row-source-generic: the batched kernels take the lane state as
 an (H, B, lpad) stack of variable halves.  With H = 2 (the ε-SVR doubled
 operator) both base rows k_i / k_j are computed ONCE per grid step from
 the base (BL, d) X tile and applied to each half via index arithmetic —
-the matmuls stay l-wide, replacing the old pre-tiled-X launch.  The rows
-variant consumes pre-gathered base rows instead (Gram-bank mode).
+the matmuls stay l-wide.  The rows variant consumes pre-gathered base rows
+instead (Gram-bank mode).  The single-lane pass B is the rbf kernel at
+B = 1 with k_i read from HBM (``given_ki``) instead of recomputed.
 """
 
 from __future__ import annotations
@@ -30,34 +32,19 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.rbf_row_wss import LANES, compiler_params, fold_max
+from repro.kernels.ref import HIGHEST
 
-def _kernel(xq_ref, scal_ref, X_ref, sqn_ref, G_ref, ki_ref, alpha_ref,
-            L_ref, U_ref, G_out, bmax_out, barg_out, bmin_out,
-            *, block_l: int):
-    b = pl.program_id(0)
-    # scalars: [sqq_j, mu, gamma]
-    sqq = scal_ref[0, 0]
-    mu = scal_ref[0, 1]
-    gamma = scal_ref[0, 2]
 
-    x = X_ref[...]
-    qv = xq_ref[...]
-    prod = jax.lax.dot_general(x, qv, (((1,), (1,)), ((), ())),
-                               preferred_element_type=jnp.promote_types(x.dtype, jnp.float32))
-    d2 = sqq + sqn_ref[...] - 2.0 * prod.reshape(1, block_l)
-    k_j = jnp.exp(-gamma * jnp.maximum(d2, 0.0))
+def _fold_min(min_ref, m, first):
+    """Fold one block's per-lane minimum into the running (b, LANES)
+    output block (the lower KKT gap endpoint)."""
+    @pl.when(first)
+    def _():
+        min_ref[...] = jnp.full(min_ref.shape, jnp.inf, min_ref.dtype)
 
-    G_new = G_ref[...] - mu * (ki_ref[...] - k_j)
-    G_out[...] = G_new.astype(G_out.dtype)
-
-    alpha = alpha_ref[...]
-    up = alpha < U_ref[...]
-    dn = alpha > L_ref[...]
-    vals_up = jnp.where(up, G_new, -jnp.inf)
-    arg = jax.lax.argmax(vals_up[0], 0, jnp.int32)
-    bmax_out[0, 0] = vals_up[0, arg]
-    barg_out[0, 0] = b * block_l + arg
-    bmin_out[0, 0] = jnp.min(jnp.where(dn, G_new, jnp.inf))
+    min_ref[...] = jnp.broadcast_to(jnp.minimum(min_ref[:, 0:1], m),
+                                    min_ref.shape)
 
 
 def _update_from_rows(k_i, k_j, G, alpha, L, U, mu, b, *, block_l: int,
@@ -90,25 +77,36 @@ def _update_from_rows(k_i, k_j, G, alpha, L, U, mu, b, *, block_l: int,
             up = up & (act[h] > 0.5)
             dn = dn & (act[h] > 0.5)
         vals_up = jnp.where(up, G_new[h], -jnp.inf)
-        arg = jax.lax.argmax(vals_up, 1, jnp.int32)
-        m = jnp.max(vals_up, axis=1)
+        arg = jax.lax.argmax(vals_up, 1, jnp.int32)[:, None]
+        m = jnp.max(vals_up, axis=1, keepdims=True)
         g_arg = h * base_l + b * block_l + arg
-        mn = jnp.min(jnp.where(dn, G_new[h], jnp.inf), axis=1)
+        mn = jnp.min(jnp.where(dn, G_new[h], jnp.inf), axis=1, keepdims=True)
         if best is None:
             best, barg, bmin = m, g_arg, mn
         else:
             barg = jnp.where(m > best, g_arg, barg)
             best = jnp.maximum(m, best)
             bmin = jnp.minimum(bmin, mn)
-    return G_new, best[:, None], barg[:, None], bmin[:, None]
+    return G_new, best, barg, bmin
+
+
+def _emit(refs, G_new, bmax, barg, bmin, b):
+    """Write the updated state tile and fold the block's selection."""
+    G_out, bmax_out, barg_out, bmin_out = refs
+    G_out[...] = G_new.astype(G_out.dtype)
+    fold_max(bmax_out, barg_out, bmax, barg, b == 0)
+    _fold_min(bmin_out, bmin, b == 0)
 
 
 def _kernel_batched(*refs, block_l: int, base_l: int, masked: bool = False,
-                    conj: bool = False):
+                    conj: bool = False, given_ki: bool = False):
     """Lane-batched pass B (rbf source): recompute BOTH base rows k_i, k_j
-    against the shared X tile (two (B, d) x (d, BL) matmuls), update every
-    state half in-register, and emit the per-lane next-i argmax plus both
-    KKT gap endpoints.
+    against the shared X tile, update every state half in-register, and
+    fold the per-lane next-i argmax plus both KKT gap endpoints.
+
+    Both query blocks ride in ONE (2B, d) x (d, BL) matmul (the i rows
+    then the j rows of the lane block): two matmuls against the same tile
+    make the compiler hold several extra copies of it in VMEM.
 
     Neither row ever touches HBM.  A lane with ``mu == 0`` writes G back
     bitwise unchanged — that is the in-kernel lane freeze: converged lanes
@@ -120,17 +118,18 @@ def _kernel_batched(*refs, block_l: int, base_l: int, masked: bool = False,
     gains the axpy ``- mu2 dirv`` and the *base* row difference
     ``r = k_i - k_j`` — next iteration's direction — is emitted as a fifth
     output (base width: the doubled halves tile it outside the kernel).
+    With ``given_ki=True`` a (B, BL) k_i row tile rides next (the
+    single-lane engine carries it from pass A) and the i query rows are
+    ignored.
     """
     act_ref, refs = (refs[0], refs[1:]) if masked else (None, refs)
-    if conj:
-        (xqi_ref, xqj_ref, scal_ref, X_ref, sqn_ref, G_ref, alpha_ref,
-         L_ref, U_ref, dirv_ref, G_out, bmax_out, barg_out, bmin_out,
-         r_out) = refs
-    else:
-        (xqi_ref, xqj_ref, scal_ref, X_ref, sqn_ref, G_ref, alpha_ref,
-         L_ref, U_ref, G_out, bmax_out, barg_out, bmin_out) = refs
-        dirv_ref = r_out = None
-    b = pl.program_id(0)
+    ki_ref, refs = (refs[0], refs[1:]) if given_ki else (None, refs)
+    (xq_ref, scal_ref, X_ref, sqn_ref, G_ref, alpha_ref, L_ref,
+     U_ref) = refs[:8]
+    refs = refs[8:]
+    dirv_ref, refs = (refs[0], refs[1:]) if conj else (None, refs)
+    b = pl.program_id(1)
+    bb = scal_ref.shape[0]
     # per-lane scalars: [sqq_i, sqq_j, mu, gamma] (+ [mu2] when conj)
     sqq_i = scal_ref[:, 0:1]
     sqq_j = scal_ref[:, 1:2]
@@ -139,26 +138,25 @@ def _kernel_batched(*refs, block_l: int, base_l: int, masked: bool = False,
     mu2 = scal_ref[:, 4:5] if conj else None
 
     x = X_ref[...]                      # (BL, d) shared tile
-    acc = jnp.promote_types(x.dtype, jnp.float32)
-    prod_i = jax.lax.dot_general(xqi_ref[...], x, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=acc)
-    prod_j = jax.lax.dot_general(xqj_ref[...], x, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=acc)
+    prod = jax.lax.dot_general(
+        xq_ref[...], x, (((1,), (1,)), ((), ())), precision=HIGHEST,
+        preferred_element_type=jnp.promote_types(x.dtype, jnp.float32))
     sqn = sqn_ref[...]
-    k_i = jnp.exp(-gamma * jnp.maximum(sqq_i + sqn - 2.0 * prod_i, 0.0))
-    k_j = jnp.exp(-gamma * jnp.maximum(sqq_j + sqn - 2.0 * prod_j, 0.0))
+
+    def row(p, sqq):
+        return jnp.exp(-gamma * jnp.maximum(sqq + sqn - 2.0 * p, 0.0))
+
+    k_i = ki_ref[...] if given_ki else row(prod[:bb], sqq_i)
+    k_j = row(prod[bb:], sqq_j)
 
     G_new, bmax, barg, bmin = _update_from_rows(
         k_i, k_j, G_ref[...], alpha_ref[...], L_ref[...], U_ref[...], mu,
         b, block_l=block_l, base_l=base_l,
         act=None if act_ref is None else act_ref[...],
         dirv=None if dirv_ref is None else dirv_ref[...][None], mu2=mu2)
-    G_out[...] = G_new.astype(G_out.dtype)
-    bmax_out[...] = bmax
-    barg_out[...] = barg
-    bmin_out[...] = bmin
+    _emit(refs[:4], G_new, bmax, barg, bmin, b)
     if conj:
-        r_out[...] = (k_i - k_j).astype(r_out.dtype)
+        refs[4][...] = (k_i - k_j).astype(refs[4].dtype)
 
 
 def _kernel_batched_rows(*refs, block_l: int, base_l: int,
@@ -167,14 +165,10 @@ def _kernel_batched_rows(*refs, block_l: int, base_l: int,
     pre-gathered (Gram-bank mode) — same update algebra, no matmuls.
     ``conj`` as in :func:`_kernel_batched` (scalars become [mu, mu2])."""
     act_ref, refs = (refs[0], refs[1:]) if masked else (None, refs)
-    if conj:
-        (kri_ref, krj_ref, scal_ref, G_ref, alpha_ref, L_ref, U_ref,
-         dirv_ref, G_out, bmax_out, barg_out, bmin_out, r_out) = refs
-    else:
-        (kri_ref, krj_ref, scal_ref, G_ref, alpha_ref, L_ref, U_ref,
-         G_out, bmax_out, barg_out, bmin_out) = refs
-        dirv_ref = r_out = None
-    b = pl.program_id(0)
+    (kri_ref, krj_ref, scal_ref, G_ref, alpha_ref, L_ref, U_ref) = refs[:7]
+    refs = refs[7:]
+    dirv_ref, refs = (refs[0], refs[1:]) if conj else (None, refs)
+    b = pl.program_id(1)
     mu = scal_ref[:, 0:1]
     mu2 = scal_ref[:, 1:2] if conj else None
     k_i, k_j = kri_ref[...], krj_ref[...]
@@ -183,166 +177,121 @@ def _kernel_batched_rows(*refs, block_l: int, base_l: int,
         U_ref[...], mu, b, block_l=block_l, base_l=base_l,
         act=None if act_ref is None else act_ref[...],
         dirv=None if dirv_ref is None else dirv_ref[...][None], mu2=mu2)
-    G_out[...] = G_new.astype(G_out.dtype)
-    bmax_out[...] = bmax
-    barg_out[...] = barg
-    bmin_out[...] = bmin
+    _emit(refs[:4], G_new, bmax, barg, bmin, b)
     if conj:
-        r_out[...] = (k_i - k_j).astype(r_out.dtype)
+        refs[4][...] = (k_i - k_j).astype(refs[4].dtype)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("block_l", "interpret", "base_l"))
-def rbf_update_wss_batched_pallas(X, sqn, G, alpha_new, L, U, XQi, XQj,
-                                  scalars, act=None, dirv=None, *,
+def _launch(kernel, args, in_specs, *, H, B, bb, lpad, block_l, dtype, act,
+            dirv, interpret):
+    """Shared pass-B launch: lane-state / selection outputs, optional act
+    and Conjugate-SMO direction operands, and the result unpacking."""
+    lane_spec = pl.BlockSpec((H, bb, block_l), lambda c, b: (0, c, b))
+    row_spec = pl.BlockSpec((bb, block_l), lambda c, b: (c, b))
+    sel_spec = pl.BlockSpec((bb, LANES), lambda c, b: (c, 0))
+    in_specs = in_specs + [lane_spec] * 4
+    out_shapes = [
+        jax.ShapeDtypeStruct((H, B, lpad), dtype),
+        jax.ShapeDtypeStruct((B, LANES), dtype),
+        jax.ShapeDtypeStruct((B, LANES), jnp.int32),
+        jax.ShapeDtypeStruct((B, LANES), dtype),
+    ]
+    out_specs = [lane_spec, sel_spec, sel_spec, sel_spec]
+    if dirv is not None:
+        in_specs.append(row_spec)
+        args.append(dirv)
+        out_specs.append(row_spec)
+        out_shapes.append(jax.ShapeDtypeStruct((B, lpad), dtype))
+    if act is not None:
+        in_specs.insert(0, lane_spec)
+        args.insert(0, act)
+    out = pl.pallas_call(
+        kernel,
+        grid=(B // bb, lpad // block_l),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=tuple(out_shapes),
+        compiler_params=compiler_params(),
+        interpret=interpret,
+    )(*args)
+    G_new, bmax, barg, bmin = out[:4]
+    return (G_new, barg[:, 0], bmax[:, 0], bmin[:, 0]) + tuple(out[4:])
+
+
+@functools.partial(jax.jit, static_argnames=("block_l", "block_b",
+                                             "interpret", "base_l"))
+def rbf_update_wss_batched_pallas(X, sqn, G, alpha_new, L, U, XQ, scalars,
+                                  act=None, dirv=None, ki=None, *,
                                   block_l: int = 1024,
+                                  block_b: int | None = None,
                                   interpret: bool = False, base_l: int = 0):
     """Launch lane-batched pass B.  The state leaves are (H, B, lpad) half
-    stacks (H = 2 for the doubled ε-SVR operator); ``XQi``/``XQj`` are the
-    (B, d) *base* query rows and ``scalars`` the packed (B, 4) array
+    stacks (H = 2 for the doubled ε-SVR operator); ``XQ`` is (2B, d): per
+    lane block of ``block_b`` lanes, their *base* i query rows then their
+    j query rows; ``scalars`` is the packed (B, 4) array
     [sqq_i, sqq_j, mu, gamma] per lane.  ``act`` is an optional
-    (H, B, lpad) active-set stack (data dtype 1.0/0.0).  Returns
-    (G_new (H, B, lpad), bmax_up (B, nb), barg_up (B, nb), bmin_dn (B, nb)).
+    (H, B, lpad) active-set stack (data dtype 1.0/0.0); ``block_b``
+    (default: all B lanes) splits the lanes into independent grid blocks.
+    Returns (G_new (H, B, lpad), i_next (B,) int32, g_i_next (B,),
+    g_dn (B,)).
 
     ``dirv`` (Conjugate-SMO) is an optional (B, lpad) *base-width*
     previous-direction row (the doubled operator's direction is
     half-symmetric, so one base row serves both halves); with it,
     ``scalars`` is (B, 5) [..., mu2] and a fifth output ``r`` (B, lpad) —
-    the base row difference k_i - k_j — is returned."""
+    the base row difference k_i - k_j — is returned.  ``ki`` (B, lpad)
+    supplies the k_i rows instead of recomputing them from the i query
+    rows (which are then ignored)."""
     H, B, lpad = G.shape
     d = X.shape[1]
-    assert lpad % block_l == 0, (lpad, block_l)
-    nb = lpad // block_l
-    dtype = X.dtype
-
-    lane_spec = pl.BlockSpec((H, B, block_l), lambda b: (0, 0, b))
-    row_spec = pl.BlockSpec((B, block_l), lambda b: (0, b))
-    blk_spec = pl.BlockSpec((B, 1), lambda b: (0, b))
-    masked = act is not None
-    conj = dirv is not None
-    n_scal = 5 if conj else 4
-    out_shapes = [
-        jax.ShapeDtypeStruct((H, B, lpad), dtype),
-        jax.ShapeDtypeStruct((B, nb), dtype),
-        jax.ShapeDtypeStruct((B, nb), jnp.int32),
-        jax.ShapeDtypeStruct((B, nb), dtype),
-    ]
-    out_specs = [lane_spec, blk_spec, blk_spec, blk_spec]
+    bb = B if block_b is None else block_b
+    assert lpad % block_l == 0 and B % bb == 0, (lpad, block_l, B, bb)
+    given_ki = ki is not None
     in_specs = [
-        pl.BlockSpec((B, d), lambda b: (0, 0)),          # XQi
-        pl.BlockSpec((B, d), lambda b: (0, 0)),          # XQj
-        pl.BlockSpec((B, n_scal), lambda b: (0, 0)),     # scalars
-        pl.BlockSpec((block_l, d), lambda b: (b, 0)),    # X
-        pl.BlockSpec((1, block_l), lambda b: (0, b)),    # sqn
-        lane_spec, lane_spec, lane_spec, lane_spec,
+        pl.BlockSpec((2 * bb, d), lambda c, b: (c, 0)),        # XQ
+        pl.BlockSpec((bb, scalars.shape[1]), lambda c, b: (c, 0)),
+        pl.BlockSpec((block_l, d), lambda c, b: (b, 0)),       # X
+        pl.BlockSpec((1, block_l), lambda c, b: (0, b)),       # sqn
     ]
-    args = [XQi, XQj, scalars, X, sqn.reshape(1, lpad), G, alpha_new, L, U]
-    if conj:
-        in_specs.append(row_spec)
-        args.append(dirv)
-        out_specs.append(row_spec)
-        out_shapes.append(jax.ShapeDtypeStruct((B, lpad), dtype))
-    if masked:
-        in_specs.insert(0, lane_spec)
-        args.insert(0, act)
-    return pl.pallas_call(
-        functools.partial(_kernel_batched, block_l=block_l, base_l=base_l,
-                          masked=masked, conj=conj),
-        grid=(nb,),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=tuple(out_shapes),
-        interpret=interpret,
-    )(*args)
+    args = [XQ, scalars, X, sqn.reshape(1, lpad), G, alpha_new, L, U]
+    if given_ki:
+        in_specs.insert(0, pl.BlockSpec((bb, block_l), lambda c, b: (c, b)))
+        args.insert(0, ki)
+    kernel = functools.partial(_kernel_batched, block_l=block_l,
+                               base_l=base_l, masked=act is not None,
+                               conj=dirv is not None, given_ki=given_ki)
+    return _launch(kernel, args, in_specs, H=H, B=B, bb=bb, lpad=lpad,
+                   block_l=block_l, dtype=X.dtype, act=act, dirv=dirv,
+                   interpret=interpret)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("block_l", "interpret", "base_l"))
+@functools.partial(jax.jit, static_argnames=("block_l", "block_b",
+                                             "interpret", "base_l"))
 def update_wss_batched_rows_pallas(KRi, KRj, G, alpha_new, L, U, scalars,
                                    act=None, dirv=None, *,
                                    block_l: int = 1024,
+                                   block_b: int | None = None,
                                    interpret: bool = False, base_l: int = 0):
     """Launch lane-batched pass B from pre-gathered base rows ``KRi``/``KRj``
     (B, lpad) — the Gram-bank row source.  ``scalars`` is the packed (B, 1)
-    array [mu]; state stack, optional ``act`` stack and ``base_l`` as in
-    :func:`rbf_update_wss_batched_pallas`.  ``dirv`` (Conjugate-SMO) as
-    there: (B, lpad) base-width direction row, ``scalars`` becomes (B, 2)
-    [mu, mu2] and a fifth output ``r`` (B, lpad) is returned."""
+    array [mu]; state stack, optional ``act`` stack, ``base_l`` and
+    ``block_b`` as in :func:`rbf_update_wss_batched_pallas`.  ``dirv``
+    (Conjugate-SMO) as there: (B, lpad) base-width direction row,
+    ``scalars`` becomes (B, 2) [mu, mu2] and a fifth output ``r`` (B, lpad)
+    is returned."""
     H, B, lpad = G.shape
-    assert lpad % block_l == 0, (lpad, block_l)
-    nb = lpad // block_l
-    dtype = KRi.dtype
-
-    lane_spec = pl.BlockSpec((H, B, block_l), lambda b: (0, 0, b))
-    row_spec = pl.BlockSpec((B, block_l), lambda b: (0, b))
-    blk_spec = pl.BlockSpec((B, 1), lambda b: (0, b))
-    masked = act is not None
-    conj = dirv is not None
-    n_scal = 2 if conj else 1
-    out_shapes = [
-        jax.ShapeDtypeStruct((H, B, lpad), dtype),
-        jax.ShapeDtypeStruct((B, nb), dtype),
-        jax.ShapeDtypeStruct((B, nb), jnp.int32),
-        jax.ShapeDtypeStruct((B, nb), dtype),
-    ]
-    out_specs = [lane_spec, blk_spec, blk_spec, blk_spec]
+    bb = B if block_b is None else block_b
+    assert lpad % block_l == 0 and B % bb == 0, (lpad, block_l, B, bb)
+    row_spec = pl.BlockSpec((bb, block_l), lambda c, b: (c, b))
     in_specs = [
-        row_spec,                                        # KRi
-        row_spec,                                        # KRj
-        pl.BlockSpec((B, n_scal), lambda b: (0, 0)),     # scalars
-        lane_spec, lane_spec, lane_spec, lane_spec,
+        row_spec,                                                     # KRi
+        row_spec,                                                     # KRj
+        pl.BlockSpec((bb, scalars.shape[1]), lambda c, b: (c, 0)),    # scal
     ]
     args = [KRi, KRj, scalars, G, alpha_new, L, U]
-    if conj:
-        in_specs.append(row_spec)
-        args.append(dirv)
-        out_specs.append(row_spec)
-        out_shapes.append(jax.ShapeDtypeStruct((B, lpad), dtype))
-    if masked:
-        in_specs.insert(0, lane_spec)
-        args.insert(0, act)
-    return pl.pallas_call(
-        functools.partial(_kernel_batched_rows, block_l=block_l,
-                          base_l=base_l, masked=masked, conj=conj),
-        grid=(nb,),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=tuple(out_shapes),
-        interpret=interpret,
-    )(*args)
-
-
-@functools.partial(jax.jit, static_argnames=("block_l", "interpret"))
-def rbf_update_wss_pallas(X, sqn, G, k_i, alpha_new, L, U, xq_j, scalars,
-                          *, block_l: int = 1024, interpret: bool = False):
-    """Launch pass B.  ``scalars`` is the packed (1, 3) f32 array
-    [sqq_j, mu, gamma].  Returns (G_new, bmax_up, barg_up, bmin_dn)."""
-    lpad, d = X.shape
-    assert lpad % block_l == 0, (lpad, block_l)
-    nb = lpad // block_l
-    dtype = X.dtype
-
-    row2 = lambda a: a.reshape(1, lpad)
-    vec_spec = pl.BlockSpec((1, block_l), lambda b: (0, b))
-    blk_spec = pl.BlockSpec((1, 1), lambda b: (0, b))
-    out_shapes = (
-        jax.ShapeDtypeStruct((1, lpad), dtype),
-        jax.ShapeDtypeStruct((1, nb), dtype),
-        jax.ShapeDtypeStruct((1, nb), jnp.int32),
-        jax.ShapeDtypeStruct((1, nb), dtype),
-    )
-    G_new, bmax, barg, bmin = pl.pallas_call(
-        functools.partial(_kernel, block_l=block_l),
-        grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((1, d), lambda b: (0, 0)),
-            pl.BlockSpec((1, 3), lambda b: (0, 0)),
-            pl.BlockSpec((block_l, d), lambda b: (b, 0)),
-            vec_spec, vec_spec, vec_spec, vec_spec, vec_spec, vec_spec,
-        ],
-        out_specs=[vec_spec, blk_spec, blk_spec, blk_spec],
-        out_shape=out_shapes,
-        interpret=interpret,
-    )(xq_j.reshape(1, d), scalars, X, row2(sqn), row2(G), row2(k_i),
-      row2(alpha_new), row2(L), row2(U))
-    return G_new[0], bmax[0], barg[0], bmin[0]
+    kernel = functools.partial(_kernel_batched_rows, block_l=block_l,
+                               base_l=base_l, masked=act is not None,
+                               conj=dirv is not None)
+    return _launch(kernel, args, in_specs, H=H, B=B, bb=bb, lpad=lpad,
+                   block_l=block_l, dtype=KRi.dtype, act=act, dirv=dirv,
+                   interpret=interpret)

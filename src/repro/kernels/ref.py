@@ -6,6 +6,10 @@ import jax
 import jax.numpy as jnp
 
 TAU = 1e-12
+# Kernel distances expand |a|^2 + |b|^2 - 2 a.b, which cancels: the a.b
+# products need full float32.  TPUs run DEFAULT-precision f32 matmuls as
+# one bf16 pass, so every distance and decision matmul asks for HIGHEST.
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _act_bool(act):
@@ -19,9 +23,19 @@ def _act_bool(act):
     return act if act.dtype == jnp.bool_ else act > 0.5
 
 
+def take_lane(M, idx):
+    """Per-lane gather: M (B, l), idx (B,) -> (B,).
+
+    A vmapped scalar index keeps the int32 index channel;
+    ``jnp.take_along_axis`` widens its indices to int64 under x64.
+    """
+    return jax.vmap(lambda row, i: row[i])(M, idx)
+
+
 def rbf_row(X, sqn, xq, gamma):
     """k(x_q, X) for one query row."""
-    d2 = jnp.dot(xq, xq) + sqn - 2.0 * (X @ xq)
+    d2 = (jnp.dot(xq, xq, precision=HIGHEST) + sqn
+          - 2.0 * jnp.dot(X, xq, precision=HIGHEST))
     return jnp.exp(-gamma * jnp.maximum(d2, 0.0))
 
 
@@ -93,7 +107,8 @@ def rbf_rows_batched(X, sqn, XQ, sqq, gammas, dup: bool = False):
     against the base ``X`` only and the 2l half is a free broadcast —
     never a 2l-wide matmul, never a 2l x 2l Gram.
     """
-    d2 = sqq[:, None] + sqn[None, :] - 2.0 * (XQ @ X.T)
+    d2 = (sqq[:, None] + sqn[None, :]
+          - 2.0 * jnp.dot(XQ, X.T, precision=HIGHEST))
     k = jnp.exp(-gammas[:, None] * jnp.maximum(d2, 0.0))
     return tile_rows(k) if dup else k
 
@@ -123,7 +138,7 @@ def row_wss_batched_from_k(k, G, alpha, L, U, a_i, L_i, U_i, g_i, i_idx,
         mask = mask & _act_bool(act)
     vals = jnp.where(mask, gains, -jnp.inf)
     j = jax.lax.argmax(vals, 1, jnp.int32)
-    return j, jnp.take_along_axis(vals, j[:, None], axis=1)[:, 0]
+    return j, take_lane(vals, j)
 
 
 def rbf_row_wss_batched(X, sqn, G, alpha, L, U, XQ, sqq, a_i, L_i, U_i,
@@ -170,7 +185,7 @@ def update_wss_batched_from_rows(G, k_i, k_j, mu, alpha_new, L, U, act=None,
         dn = dn & _act_bool(act)
     vals_up = jnp.where(up, G_new, -jnp.inf)
     i_next = jax.lax.argmax(vals_up, 1, jnp.int32)
-    g_i_next = jnp.take_along_axis(vals_up, i_next[:, None], axis=1)[:, 0]
+    g_i_next = take_lane(vals_up, i_next)
     g_dn = jnp.min(jnp.where(dn, G_new, jnp.inf), axis=1)
     if dirv is not None:
         return G_new, i_next, g_i_next, g_dn, k_i - k_j
@@ -200,7 +215,7 @@ def rbf_update_wss_batched(X, sqn, G, alpha_new, L, U, XQi, sqqi, XQj, sqqj,
 def gram(X, gamma):
     """Full RBF Gram matrix."""
     sq = jnp.sum(X * X, axis=-1)
-    d2 = sq[:, None] + sq[None, :] - 2.0 * (X @ X.T)
+    d2 = sq[:, None] + sq[None, :] - 2.0 * jnp.dot(X, X.T, precision=HIGHEST)
     return jnp.exp(-gamma * jnp.maximum(d2, 0.0))
 
 
@@ -208,5 +223,6 @@ def gram_cross(X1, X2, gamma):
     """Cross Gram matrix k(X1, X2) -> (l1, l2)."""
     s1 = jnp.sum(X1 * X1, axis=-1)
     s2 = jnp.sum(X2 * X2, axis=-1)
-    d2 = s1[:, None] + s2[None, :] - 2.0 * (X1 @ X2.T)
+    d2 = (s1[:, None] + s2[None, :]
+          - 2.0 * jnp.dot(X1, X2.T, precision=HIGHEST))
     return jnp.exp(-gamma * jnp.maximum(d2, 0.0))

@@ -32,6 +32,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.ref import HIGHEST
+
 
 @functools.partial(
     jax.tree_util.register_dataclass,
@@ -116,7 +118,8 @@ class RowSource:
         if self.dup:
             v = v[:, :l] + v[:, l:]
         if self.is_bank:
-            mv = jnp.einsum("sij,bj->sbi", self.gram, v)
+            mv = jnp.einsum("sij,bj->sbi", self.gram, v,
+                            precision=HIGHEST)
             out = mv[self.gram_idx,
                      jnp.arange(v.shape[0], dtype=jnp.int32)]
         else:
@@ -128,10 +131,11 @@ class RowSource:
 
             def blk(args):
                 Xb, nb = args
-                d2 = nb[:, None] + sqn[None, :] - 2.0 * (Xb @ X.T)
+                d2 = (nb[:, None] + sqn[None, :]
+                      - 2.0 * jnp.dot(Xb, X.T, precision=HIGHEST))
                 k = jnp.exp(-self.gammas[:, None, None]
                             * jnp.maximum(d2, 0.0)[None])    # (B, block, l)
-                return jnp.einsum("bkl,bl->bk", k, v)
+                return jnp.einsum("bkl,bl->bk", k, v, precision=HIGHEST)
 
             out = jax.lax.map(blk, (Xp.reshape(-1, block, d),
                                     sp.reshape(-1, block)))

@@ -7,16 +7,10 @@ touches jax device state (smoke tests keep their single CPU device).
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5: explicit axis types
-    from jax.sharding import AxisType
-except ImportError:  # older jax: make_mesh has no axis_types kwarg
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _make_mesh(shape, axes):
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes,
                          axis_types=(AxisType.Auto,) * len(axes))
 

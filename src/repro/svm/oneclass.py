@@ -29,6 +29,7 @@ from repro.core.sharded_lanes import solve_fused_sharded_qp
 from repro.core.solver import solve_qp
 from repro.core.solver_fused import solve_fused_batched_qp
 from repro.kernels import ops
+from repro.kernels.ref import HIGHEST
 from repro.svm.base import SVMEstimatorBase
 
 
@@ -116,7 +117,7 @@ class OneClassSVM(SVMEstimatorBase):
         """Signed distance to the separating surface: >= 0 for inliers."""
         self._check_fitted()
         Kq, squeeze = self._query_gram(Xq)
-        df = Kq @ self.alpha_ + self.b_
+        df = jnp.dot(Kq, self.alpha_, precision=HIGHEST) + self.b_
         return df[0] if squeeze else df
 
     def predict(self, Xq) -> np.ndarray:

@@ -35,6 +35,7 @@ from repro.core import qp as qp_mod
 from repro.core.solver import SolveResult, solve
 from repro.core.solver_fused import FusedResult
 from repro.kernels import ops
+from repro.kernels.ref import HIGHEST
 from repro.svm.base import SVMEstimatorBase
 
 
@@ -200,7 +201,7 @@ class SVC(SVMEstimatorBase):
         self._check_fitted()
         Kq, squeeze = self._query_gram(Xq)
         if self.alpha_.ndim == 1:
-            df = Kq @ self.alpha_ + self.b_
+            df = jnp.dot(Kq, self.alpha_, precision=HIGHEST) + self.b_
         else:
             df = mc.ovr_decision(Kq, self.alpha_, self.b_)
         return df[0] if squeeze else df

@@ -32,6 +32,7 @@ from repro.core.solver import solve_qp
 from repro.core.sharded_lanes import solve_fused_sharded_qp
 from repro.core.solver_fused import solve_fused_batched_qp
 from repro.kernels import ops
+from repro.kernels.ref import HIGHEST
 from repro.svm.base import SVMEstimatorBase
 
 
@@ -122,7 +123,7 @@ class SVR(SVMEstimatorBase):
     def predict(self, Xq) -> jnp.ndarray:
         self._check_fitted()
         Kq, squeeze = self._query_gram(Xq)
-        f = Kq @ self.beta_ + self.b_
+        f = jnp.dot(Kq, self.beta_, precision=HIGHEST) + self.b_
         return f[0] if squeeze else f
 
     def score(self, Xq, yq) -> float:

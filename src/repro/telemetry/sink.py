@@ -37,21 +37,17 @@ def env_fingerprint() -> dict:
     """Runtime identity for benchmark records and telemetry artifacts.
 
     The hostname is hashed — records are committed to the repo and
-    uploaded as CI artifacts, so the raw name stays out of them.
+    uploaded as CI artifacts, so the raw name stays out of them.  A
+    backend that cannot start raises here: a record must never name a
+    device it did not run on.
     """
-    try:
-        devs = jax.devices()
-        backend = jax.default_backend()
-        kind = devs[0].device_kind if devs else "unknown"
-        count = len(devs)
-    except Exception:  # pragma: no cover - backend init failure
-        backend, kind, count = "unknown", "unknown", 0
+    devs = jax.devices()
     host = hashlib.sha256(socket.gethostname().encode()).hexdigest()[:12]
     return {
         "jax_version": jax.__version__,
-        "backend": backend,
-        "device_kind": kind,
-        "device_count": count,
+        "backend": devs[0].platform,
+        "device_kind": devs[0].device_kind,
+        "device_count": len(devs),
         "cpu_count": os.cpu_count() or 0,
         "host": host,
         "python": platform.python_version(),

@@ -22,25 +22,6 @@ if FUSED_IMPL != "jnp":
     FUSED_KW["block_l"] = int(os.environ.get("REPRO_BLOCK_L", "128"))
 
 
-def golden_fresh_capture(name: str) -> tuple:
-    """Hermetically re-render golden ``name``; return (version, body).
-
-    Delegates to ``tests/golden/regen.py --print`` in a FRESH interpreter —
-    the jaxpr pretty-printer's sub-jaxpr sharing depends on in-process
-    tracing-cache state, so an in-suite ``make_jaxpr`` can print different
-    bytes than the regen script did.  Spawning the regen script itself
-    makes test and golden agree on the recipe by construction.
-    """
-    import importlib.util
-    path = os.path.join(os.path.dirname(__file__), "golden", "regen.py")
-    spec = importlib.util.spec_from_file_location("golden_regen", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    out = mod.render_in_subprocess(name)
-    header, body = out.split("\n", 1)
-    return header.removeprefix("# jax ").strip(), body
-
-
 def run_multidevice(script: str, n_devices: int = 8, *,
                     timeout: int = 600) -> str:
     """Run ``script`` in a fresh interpreter with ``n_devices`` forced host

@@ -9,9 +9,8 @@ carried direction is invalid.  The contract under test:
 * strictly fewer iterations than PA-SMO on the chess-board problem,
 * the accept/reject machinery is bitwise-transparent on frozen lanes and
   composes with soft shrinking and warm-start resumes,
-* with ``step="plain"`` nothing changes — the conjugate goldens pin the
-  conjugate trace itself (recipe owned by ``tests/golden/regen.py``,
-  captured hermetically per golden in a fresh process).
+* with ``step="plain"`` nothing changes — the structural golden pins the
+  conjugate trace itself (``tests/golden/structural.json``).
 """
 
 import os
@@ -266,7 +265,7 @@ def test_facades_thread_the_step_knob():
 
 
 # ---------------------------------------------------------------------------
-# trace stability: conjugate goldens (recipe owned by tests/golden/regen.py)
+# trace stability: the conjugate entries of the structural golden
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("entry", [
@@ -275,8 +274,7 @@ def test_facades_thread_the_step_knob():
 ])
 def test_conjugate_jaxpr_structure_matches_golden(entry):
     # structural audit against tests/golden/structural.json (see
-    # test_telemetry.py; the conjugate .txt goldens stay as regen
-    # fixtures owned by tests/golden/regen.py)
+    # test_telemetry.py)
     jaxpr_audit.assert_structural(entry)
 
 

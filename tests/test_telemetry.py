@@ -46,11 +46,7 @@ def _rbf_problem(B=3, l=16, d=4, seed=0):
 
 
 def _capture_jaxpr(**kw) -> str:
-    """In-process jaxpr capture for structural (not byte-level) checks.
-
-    Byte-level golden comparisons go through ``golden_fresh_capture``
-    instead — printed bytes depend on in-process tracing-cache state.
-    """
+    """In-process jaxpr capture for structural (not byte-level) checks."""
     X, P, L, U, gam = _rbf_problem()
     cfg = SolverConfig(eps=1e-3, max_iter=500)
     return str(jax.make_jaxpr(
@@ -69,11 +65,8 @@ def _capture_jaxpr(**kw) -> str:
 ])
 def test_jaxpr_structure_matches_pretelemetry_golden(entry):
     # structural audit (eqn-primitive multiset + while-carry pytree)
-    # against tests/golden/structural.json — replaces the retired byte
-    # diff of fused_jaxpr_*.txt, which broke on every pretty-printer
-    # change; the carry check runs on EVERY jax version, the primitive
-    # multiset only on the pinned one (same scope the byte test had).
-    # The .txt goldens remain as regen fixtures (tests/golden/regen.py).
+    # against tests/golden/structural.json; the carry check runs on
+    # every jax version, the primitive multiset only on the pinned one.
     jaxpr_audit.assert_structural(entry)
 
 
@@ -413,6 +406,15 @@ def test_env_fingerprint_and_diff():
     lines = fingerprint_diff(fp, other)
     assert any("backend" in ln for ln in lines)
     assert any("device_count" in ln for ln in lines)
+
+
+def test_env_fingerprint_raises_when_the_backend_cannot_start(monkeypatch):
+    def no_backend():
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+
+    monkeypatch.setattr(jax, "devices", no_backend)
+    with pytest.raises(RuntimeError, match="initialize backend"):
+        env_fingerprint()
 
 
 def test_jsonl_sink_roundtrip(tmp_path):
