@@ -1,0 +1,11 @@
+"""``kernel.pass_a.us``: summed device time of the pass A launches over
+their count, in microseconds, over the chips used."""
+
+
+def read(ctx):
+    tr = ctx.trace
+    if tr is None:
+        return None
+    n = sum(d["n_pass_a"] for d in tr["per_device"])
+    t = sum(d["t_pass_a_s"] for d in tr["per_device"])
+    return 1e6 * t / n if n else None
