@@ -53,6 +53,7 @@ from repro.core.solver_fused import (FusedResult, solve_fused_batched,
 from repro.core.sharded_lanes import (resolve_lane_mesh, solve_fused_sharded,
                                       solve_fused_sharded_qp)
 from repro.kernels.ref import HIGHEST
+from repro.telemetry import span
 
 
 def sqdist(X: jax.Array) -> jax.Array:
@@ -224,6 +225,7 @@ def _solve_grid_fused(X, Y, Cs, gammas, cfg: SolverConfig,
     return res if ring_g is None else (res, ring_g)
 
 
+@span("solve_grid")
 def solve_grid(X, Y, Cs, gammas, cfg: SolverConfig = SolverConfig(), *,
                warm_start: bool = True, impl: str | None = None,
                block_l: int = 1024, precompute: bool | None = None,
@@ -317,14 +319,15 @@ def solve_grid(X, Y, Cs, gammas, cfg: SolverConfig = SolverConfig(), *,
     tel = None if diagnostics is None else diagnostics.ring_config
     ring = None
     if impl is None:
-        res = _solve_grid(X, Y, Cs_j, gammas_j,
-                          resolve_shrink_cfg(cfg, True) if shrinking
-                          else cfg, warm_start)
+        with span("fit.solve"):
+            res = _solve_grid(X, Y, Cs_j, gammas_j,
+                              resolve_shrink_cfg(cfg, True) if shrinking
+                              else cfg, warm_start)
     else:
         k = Y.shape[0]
         cm = (nullcontext() if diagnostics is None else diagnostics.scope(
             "solve_grid_fused", lanes=len(gammas_np) * k * len(Cs_np)))
-        with cm:
+        with span("fit.solve"), cm:
             res = _solve_grid_fused(X, Y, Cs_j, gammas_j, cfg, impl,
                                     block_l, precompute, shrinking, mesh,
                                     tel)
@@ -453,6 +456,7 @@ def _compacted_fused_flat(X, Y, Cs_np, gammas_np,
     return res
 
 
+@span("solve_grid_compacted")
 def solve_grid_compacted(X, Y, Cs, gammas,
                          cfg: SolverConfig = SolverConfig(), *,
                          chunk: int = 96, impl: str | None = None,
@@ -516,9 +520,10 @@ def solve_grid_compacted(X, Y, Cs, gammas,
                              "set impl (e.g. impl='jnp') with mesh/devices")
         mesh = resolve_lane_mesh(mesh, devices)
     if impl is not None:
-        return _compacted_fused_flat(X, Y, Cs_np, gammas_np, cfg, chunk,
-                                     impl, block_l, precompute, shrinking,
-                                     mesh, diagnostics)
+        with span("fit.solve"):
+            return _compacted_fused_flat(X, Y, Cs_np, gammas_np, cfg, chunk,
+                                         impl, block_l, precompute,
+                                         shrinking, mesh, diagnostics)
     if diagnostics is not None:
         raise ValueError("diagnostics rides the fused engine — set impl "
                          "(e.g. impl='jnp') with diagnostics")
@@ -611,6 +616,7 @@ def solve_grid_compacted(X, Y, Cs, gammas,
 # Gram bank exactly like the SVC grid.
 
 
+@span("solve_grid_svr")
 def solve_grid_svr(X, y, Cs, epsilons, gammas,
                    cfg: SolverConfig = SolverConfig(), *,
                    impl: str = "auto", block_l: int = 1024,
@@ -666,7 +672,7 @@ def solve_grid_svr(X, y, Cs, epsilons, gammas,
     tel = None if diagnostics is None else diagnostics.ring_config
     cm = (nullcontext() if diagnostics is None
           else diagnostics.scope("solve_grid_svr", lanes=nG * nE * nC))
-    with cm:
+    with span("fit.solve"), cm:
         if mesh is not None or devices is not None:
             out = solve_fused_sharded_qp(
                 X, Pf, Lf, Uf, gf, cfg, mesh=mesh, devices=devices,
@@ -692,6 +698,7 @@ def solve_grid_svr(X, y, Cs, epsilons, gammas,
         lambda leaf: leaf.reshape((nG, nE, nC) + leaf.shape[1:]), out)
 
 
+@span("solve_grid_oneclass")
 def solve_grid_oneclass(X, nus, gammas, cfg: SolverConfig = SolverConfig(),
                         *, impl: str = "auto", block_l: int = 1024,
                         precompute: bool | None = None,
@@ -742,7 +749,7 @@ def solve_grid_oneclass(X, nus, gammas, cfg: SolverConfig = SolverConfig(),
     tel = None if diagnostics is None else diagnostics.ring_config
     cm = (nullcontext() if diagnostics is None
           else diagnostics.scope("solve_grid_oneclass", lanes=nG * nN))
-    with cm:
+    with span("fit.solve"), cm:
         if mesh is not None or devices is not None:
             out = solve_fused_sharded_qp(
                 X, Pf, Lf, Uf, gf, cfg, mesh=mesh, devices=devices,

@@ -35,7 +35,6 @@ a bitwise no-op on G and the loop condition is simply "any lane active".
 from __future__ import annotations
 
 import dataclasses
-from contextlib import nullcontext
 from functools import partial
 from typing import NamedTuple
 
@@ -50,6 +49,7 @@ from repro.core.solver import DEFAULT_SHRINK_EVERY, SolverConfig
 from repro.kernels import ops
 from repro.kernels import row_source
 from repro.kernels.ref import take_lane as _take_lane
+from repro.telemetry import phase_scope
 from repro.telemetry.ring import (RingConfig, TelemetryRing, ring_init,
                                   ring_update)
 
@@ -419,13 +419,13 @@ def solve_fused_batched_qp(X, P, L, U, gamma,
     period = cfg.shrink_every if cfg.shrink_every > 0 else DEFAULT_SHRINK_EVERY
     lanes = jnp.arange(B, dtype=jnp.int32)
     # Flight recorder (static knob).  ``collect=False`` must leave the
-    # traced jaxpr byte-identical to the telemetry-free engine, so every
-    # telemetry hook below is a *Python-level* branch: no ring in the
-    # carry, no extra traced ops, and the named scopes collapse to
-    # nullcontext (jaxpr equations carry the name stack, so even scopes
-    # are gated).
+    # traced jaxpr structurally identical to the telemetry-free engine, so
+    # every telemetry hook below is a *Python-level* branch: no ring in the
+    # carry, no extra traced ops.  The named scopes (``fused_pass_a``,
+    # ``fused_pass_b``, ``fused_step``, ``fused_shrink``) are always on:
+    # they change op metadata only, so a profile of the engine as deployed
+    # splits the loop body by scope.
     collect = telemetry is not None
-    scope = jax.named_scope if collect else (lambda name: nullcontext())
     if bank:
         src = row_source.bank_source(gram, gram_idx, gamma, dup=doubled)
     else:
@@ -446,169 +446,173 @@ def solve_fused_batched_qp(X, P, L, U, gamma,
             s, ring = carry
         else:
             s = carry
-        alpha, G = s.alpha, s.G
-        idx2 = jnp.concatenate([lanes, lanes])
+        with jax.named_scope("fused_step"):
+            alpha, G = s.alpha, s.G
+            idx2 = jnp.concatenate([lanes, lanes])
 
-        def at_idx(idx):
-            """(alpha, G, L, U) at per-lane coordinate ``idx`` — four tiny
-            (B,) gathers (the general box is data, not a label formula)."""
-            return (_take_lane(alpha, idx), _take_lane(G, idx),
-                    _take_lane(L, idx), _take_lane(U, idx))
+            def at_idx(idx):
+                """(alpha, G, L, U) at per-lane coordinate ``idx`` — four tiny
+                (B,) gathers (the general box is data, not a label formula)."""
+                return (_take_lane(alpha, idx), _take_lane(G, idx),
+                        _take_lane(L, idx), _take_lane(U, idx))
 
-        active = ~s.done
-        use_exact = jnp.asarray(planning) & (~s.p_smo) & (~s.prev_ratio_ok)
-        act_kw = s.act if shrinking else None
+            active = ~s.done
+            use_exact = jnp.asarray(planning) & (~s.p_smo) & (~s.prev_ratio_ok)
+            act_kw = s.act if shrinking else None
 
-        # ---- pass A: j-selection (k_i stays in VMEM / the bank) ------------
-        a_i, _, L_i, U_i = at_idx(s.i)
-        with scope("fused_pass_a"):
+            # ---- pass A: j-selection (k_i stays in VMEM / the bank) --------
+            a_i, _, L_i, U_i = at_idx(s.i)
+        with jax.named_scope("fused_pass_a"):
             j0, gain0 = ops.source_row_wss(src, G, alpha, L, U, s.i, a_i,
                                            L_i, U_i, s.g_i, use_exact,
                                            impl=impl, block_l=block_l,
                                            act=act_kw)
-        a_j0, G_j0, L_j0, U_j0 = at_idx(j0)
+        with jax.named_scope("fused_step"):
+            a_j0, G_j0, L_j0, U_j0 = at_idx(j0)
 
-        # ---- Alg. 3 extra candidate B^(t-2) (O(B d)) -----------------------
-        if planning:
-            # both "historic" entries in one stacked lookup:
-            # K(qi, qj) for the candidate, K(pi, pj) for planning's Q22
-            e2 = src.entry_pairs(jnp.concatenate([s.qi, s.pi]),
-                                 jnp.concatenate([s.qj, s.pj]), 2)
-            K_qq, K_pp = e2[:B], e2[B:]
-            a_qi, G_qi, L_qi, U_qi = at_idx(s.qi)
-            a_qj, G_qj, L_qj, U_qj = at_idx(s.qj)
-            l_q = G_qi - G_qj
-            q_q = jnp.maximum(2.0 - 2.0 * K_qq, TAU)
-            sb_q = step_mod.step_bounds(a_qi, a_qj, L_qi, U_qi, L_qj, U_qj)
-            mu_q = step_mod.clip_step(l_q / q_q, sb_q)
-            cg_exact = step_mod.gain_of_step(mu_q, l_q, q_q)
-            cg_tilde = 0.5 * l_q * l_q / q_q
-            cg = jnp.where(use_exact, cg_exact, cg_tilde)
-            adm = ((a_qi < U_qi) & (a_qj > L_qj)
-                   & (l_q > 0) & (s.qi != s.qj) & (s.n_hist > 1))
-            take = (~s.p_smo) & adm & (cg > gain0)
-            # no relaunch needed: pass B recomputes the winning row anyway,
-            # and the candidate's scalars are selects of already-gathered
-            # values — no fresh gathers for (i_sel, j_sel)
-            i_sel = jnp.where(take, s.qi, s.i)
-            j_sel = jnp.where(take, s.qj, j0)
-            g_i_sel = jnp.where(take, G_qi, s.g_i)
-            a_isel = jnp.where(take, a_qi, a_i)
-            L_isel = jnp.where(take, L_qi, L_i)
-            U_isel = jnp.where(take, U_qi, U_i)
-            a_jsel = jnp.where(take, a_qj, a_j0)
-            G_jsel = jnp.where(take, G_qj, G_j0)
-            L_jsel = jnp.where(take, L_qj, L_j0)
-            U_jsel = jnp.where(take, U_qj, U_j0)
-        else:
-            i_sel, j_sel, g_i_sel = s.i, j0, s.g_i
-            a_isel, L_isel, U_isel = a_i, L_i, U_i
-            a_jsel, G_jsel, L_jsel, U_jsel = a_j0, G_j0, L_j0, U_j0
+            # ---- Alg. 3 extra candidate B^(t-2) (O(B d)) -------------------
+            if planning:
+                # both "historic" entries in one stacked lookup:
+                # K(qi, qj) for the candidate, K(pi, pj) for planning's Q22
+                e2 = src.entry_pairs(jnp.concatenate([s.qi, s.pi]),
+                                     jnp.concatenate([s.qj, s.pj]), 2)
+                K_qq, K_pp = e2[:B], e2[B:]
+                a_qi, G_qi, L_qi, U_qi = at_idx(s.qi)
+                a_qj, G_qj, L_qj, U_qj = at_idx(s.qj)
+                l_q = G_qi - G_qj
+                q_q = jnp.maximum(2.0 - 2.0 * K_qq, TAU)
+                sb_q = step_mod.step_bounds(a_qi, a_qj, L_qi, U_qi, L_qj, U_qj)
+                mu_q = step_mod.clip_step(l_q / q_q, sb_q)
+                cg_exact = step_mod.gain_of_step(mu_q, l_q, q_q)
+                cg_tilde = 0.5 * l_q * l_q / q_q
+                cg = jnp.where(use_exact, cg_exact, cg_tilde)
+                adm = ((a_qi < U_qi) & (a_qj > L_qj)
+                       & (l_q > 0) & (s.qi != s.qj) & (s.n_hist > 1))
+                take = (~s.p_smo) & adm & (cg > gain0)
+                # no relaunch needed: pass B recomputes the winning row anyway,
+                # and the candidate's scalars are selects of already-gathered
+                # values — no fresh gathers for (i_sel, j_sel)
+                i_sel = jnp.where(take, s.qi, s.i)
+                j_sel = jnp.where(take, s.qj, j0)
+                g_i_sel = jnp.where(take, G_qi, s.g_i)
+                a_isel = jnp.where(take, a_qi, a_i)
+                L_isel = jnp.where(take, L_qi, L_i)
+                U_isel = jnp.where(take, U_qi, U_i)
+                a_jsel = jnp.where(take, a_qj, a_j0)
+                G_jsel = jnp.where(take, G_qj, G_j0)
+                L_jsel = jnp.where(take, L_qj, L_j0)
+                U_jsel = jnp.where(take, U_qj, U_j0)
+            else:
+                i_sel, j_sel, g_i_sel = s.i, j0, s.g_i
+                a_isel, L_isel, U_isel = a_i, L_i, U_i
+                a_jsel, G_jsel, L_jsel, U_jsel = a_j0, G_j0, L_j0, U_j0
 
-        # ---- O(B) step computation ----------------------------------------
-        lw = g_i_sel - G_jsel
-        K_ij = src.entry_pairs(i_sel, j_sel, 1)
-        q11 = jnp.maximum(2.0 - 2.0 * K_ij, TAU)
-        sb = step_mod.step_bounds(a_isel, a_jsel, L_isel, U_isel,
-                                  L_jsel, U_jsel)
-        mu_star = lw / q11
-        mu_smo, free_smo = step_mod.smo_step(lw, q11, sb)
+            # ---- O(B) step computation ------------------------------------
+            lw = g_i_sel - G_jsel
+            K_ij = src.entry_pairs(i_sel, j_sel, 1)
+            q11 = jnp.maximum(2.0 - 2.0 * K_ij, TAU)
+            sb = step_mod.step_bounds(a_isel, a_jsel, L_isel, U_isel,
+                                      L_jsel, U_jsel)
+            mu_star = lw / q11
+            mu_smo, free_smo = step_mod.smo_step(lw, q11, sb)
 
-        do_plan = jnp.zeros((B,), bool)
-        mu_plan = mu_smo
-        ratio_ok = s.prev_ratio_ok
-        if planning:
-            a_pi, G_pi, L_pi, U_pi = at_idx(s.pi)
-            a_pj, G_pj, L_pj, U_pj = at_idx(s.pj)
-            w2 = G_pi - G_pj
-            q22 = jnp.maximum(2.0 - 2.0 * K_pp, TAU)
-            e4 = src.entry_pairs(
-                jnp.concatenate([i_sel, i_sel, j_sel, j_sel]),
-                jnp.concatenate([s.pi, s.pj, s.pi, s.pj]), 4)
-            q12 = e4[:B] - e4[B:2 * B] - e4[2 * B:3 * B] + e4[3 * B:]
-            terms = step_mod.PlanningTerms(w1=lw, w2=w2, Q11=q11, Q22=q22,
-                                           Q12=q12)
-            mu1, okdet = step_mod.planning_step(terms)
-            mu2 = step_mod.planned_second_step(mu1, terms)
-            interior1 = (sb.lo < mu1) & (mu1 < sb.hi)
-            d_pi = ((s.pi == i_sel).astype(dtype)
-                    - (s.pi == j_sel).astype(dtype))
-            d_pj = ((s.pj == i_sel).astype(dtype)
-                    - (s.pj == j_sel).astype(dtype))
-            sb2 = step_mod.step_bounds(a_pi + mu1 * d_pi, a_pj + mu1 * d_pj,
-                                       L_pi, U_pi, L_pj, U_pj)
-            interior2 = (sb2.lo < mu2) & (mu2 < sb2.hi)
-            feasible = okdet & interior1 & interior2 & (s.n_hist > 0)
-            do_plan = s.prev_free & feasible
-            mu_plan = jnp.where(do_plan, mu1, mu_smo)
-            ratio = mu1 / jnp.where(jnp.abs(mu_star) > 0, mu_star, 1.0)
-            ratio_ok = jnp.where(do_plan,
-                                 (ratio >= 1.0 - eta) & (ratio <= 1.0 + eta),
-                                 s.prev_ratio_ok)
+            do_plan = jnp.zeros((B,), bool)
+            mu_plan = mu_smo
+            ratio_ok = s.prev_ratio_ok
+            if planning:
+                a_pi, G_pi, L_pi, U_pi = at_idx(s.pi)
+                a_pj, G_pj, L_pj, U_pj = at_idx(s.pj)
+                w2 = G_pi - G_pj
+                q22 = jnp.maximum(2.0 - 2.0 * K_pp, TAU)
+                e4 = src.entry_pairs(
+                    jnp.concatenate([i_sel, i_sel, j_sel, j_sel]),
+                    jnp.concatenate([s.pi, s.pj, s.pi, s.pj]), 4)
+                q12 = e4[:B] - e4[B:2 * B] - e4[2 * B:3 * B] + e4[3 * B:]
+                terms = step_mod.PlanningTerms(w1=lw, w2=w2, Q11=q11, Q22=q22,
+                                               Q12=q12)
+                mu1, okdet = step_mod.planning_step(terms)
+                mu2 = step_mod.planned_second_step(mu1, terms)
+                interior1 = (sb.lo < mu1) & (mu1 < sb.hi)
+                d_pi = ((s.pi == i_sel).astype(dtype)
+                        - (s.pi == j_sel).astype(dtype))
+                d_pj = ((s.pj == i_sel).astype(dtype)
+                        - (s.pj == j_sel).astype(dtype))
+                sb2 = step_mod.step_bounds(a_pi + mu1 * d_pi, a_pj + mu1 * d_pj,
+                                           L_pi, U_pi, L_pj, U_pj)
+                interior2 = (sb2.lo < mu2) & (mu2 < sb2.hi)
+                feasible = okdet & interior1 & interior2 & (s.n_hist > 0)
+                do_plan = s.prev_free & feasible
+                mu_plan = jnp.where(do_plan, mu1, mu_smo)
+                ratio = mu1 / jnp.where(jnp.abs(mu_star) > 0, mu_star, 1.0)
+                ratio_ok = jnp.where(do_plan,
+                                     (ratio >= 1.0 - eta)
+                                     & (ratio <= 1.0 + eta),
+                                     s.prev_ratio_ok)
 
-        if conjugate:
-            # ---- Conjugate-SMO 2x2 step (O(B), no extra kernel rows) -------
-            # Directions: v1 = e_i - e_j (current WSS pair), v2 = e_pi - e_pj
-            # (previous pair).  Q v2 is carried in ``conj.u`` — pass B's
-            # in-VMEM row difference from last iteration — so every
-            # restriction term below is a per-lane gather.
-            a_pi, G_pi, L_pi, U_pi = at_idx(s.pi)
-            a_pj, G_pj, L_pj, U_pj = at_idx(s.pj)
-            w2 = G_pi - G_pj
-            q22 = _take_lane(conj.u, s.pi) - _take_lane(conj.u, s.pj)
-            q12 = _take_lane(conj.u, i_sel) - _take_lane(conj.u, j_sel)
-            terms = step_mod.PlanningTerms(w1=lw, w2=w2, Q11=q11, Q22=q22,
-                                           Q12=q12)
-            mu1c, mu2c, okdet = step_mod.conjugate_step(terms)
+            if conjugate:
+                # ---- Conjugate-SMO 2x2 step (O(B), no extra kernel rows) ---
+                # Directions: v1 = e_i - e_j (current WSS pair), v2 =
+                # e_pi - e_pj (previous pair).  Q v2 is carried in
+                # ``conj.u`` — pass B's in-VMEM row difference from last
+                # iteration — so every restriction term below is a per-lane
+                # gather.
+                a_pi, G_pi, L_pi, U_pi = at_idx(s.pi)
+                a_pj, G_pj, L_pj, U_pj = at_idx(s.pj)
+                w2 = G_pi - G_pj
+                q22 = _take_lane(conj.u, s.pi) - _take_lane(conj.u, s.pj)
+                q12 = _take_lane(conj.u, i_sel) - _take_lane(conj.u, j_sel)
+                terms = step_mod.PlanningTerms(w1=lw, w2=w2, Q11=q11, Q22=q22,
+                                               Q12=q12)
+                mu1c, mu2c, okdet = step_mod.conjugate_step(terms)
 
-            def moved(c):
-                # net displacement of coordinate c under mu1c v1 + mu2c v2;
-                # indicator arithmetic handles overlapping pairs exactly
-                return (mu1c * ((c == i_sel).astype(dtype)
-                                - (c == j_sel).astype(dtype))
-                        + mu2c * ((c == s.pi).astype(dtype)
-                                  - (c == s.pj).astype(dtype)))
+                def moved(c):
+                    # net displacement of coordinate c under mu1c v1 + mu2c v2;
+                    # indicator arithmetic handles overlapping pairs exactly
+                    return (mu1c * ((c == i_sel).astype(dtype)
+                                    - (c == j_sel).astype(dtype))
+                            + mu2c * ((c == s.pi).astype(dtype)
+                                      - (c == s.pj).astype(dtype)))
 
-            def interior(c, a_c, L_c, U_c):
-                a2 = a_c + moved(c)
-                return (L_c < a2) & (a2 < U_c)
+                def interior(c, a_c, L_c, U_c):
+                    a2 = a_c + moved(c)
+                    return (L_c < a2) & (a2 < U_c)
 
-            inter = (interior(i_sel, a_isel, L_isel, U_isel)
-                     & interior(j_sel, a_jsel, L_jsel, U_jsel)
-                     & interior(s.pi, a_pi, L_pi, U_pi)
-                     & interior(s.pj, a_pj, L_pj, U_pj))
-            # exact gain of the unconstrained 2-direction solve; it
-            # dominates the 1-D Newton gain along v1 for a PD minor, so
-            # the comparison guards near-degenerate numerics only
-            g2 = 0.5 * (lw * mu1c + w2 * mu2c)
-            g1 = step_mod.gain_newton(lw, q11)
-            do_plan = (conj.ok & (s.n_hist >= 1) & okdet & inter
-                       & (g2 + TAU >= g1))
-            mu_plan = jnp.where(do_plan, mu1c, mu_smo)
-            ratio = mu1c / jnp.where(jnp.abs(mu_star) > 0, mu_star, 1.0)
+                inter = (interior(i_sel, a_isel, L_isel, U_isel)
+                         & interior(j_sel, a_jsel, L_jsel, U_jsel)
+                         & interior(s.pi, a_pi, L_pi, U_pi)
+                         & interior(s.pj, a_pj, L_pj, U_pj))
+                # exact gain of the unconstrained 2-direction solve; it
+                # dominates the 1-D Newton gain along v1 for a PD minor, so
+                # the comparison guards near-degenerate numerics only
+                g2 = 0.5 * (lw * mu1c + w2 * mu2c)
+                g1 = step_mod.gain_newton(lw, q11)
+                do_plan = (conj.ok & (s.n_hist >= 1) & okdet & inter
+                           & (g2 + TAU >= g1))
+                mu_plan = jnp.where(do_plan, mu1c, mu_smo)
+                ratio = mu1c / jnp.where(jnp.abs(mu_star) > 0, mu_star, 1.0)
 
-        # lane freeze: converged lanes take a zero step — pass B becomes a
-        # bitwise no-op on their G, alpha is untouched.  Both working-set
-        # coordinates update through ONE stacked scatter.  The isfinite
-        # guard freezes a lane for one repair iteration when an unshrink
-        # event left it with a stale -inf g_i (empty masked I_up).
-        mu = jnp.where(active & jnp.isfinite(lw),
-                       jnp.where(do_plan, mu_plan, mu_smo), 0.0)
-        if conjugate:
-            # second-direction coefficient; 0 on rejected/frozen lanes, so
-            # both the extra scatter coordinates and pass B's axpy against
-            # ``conj.u`` are exact no-ops there (lane freeze stays bitwise)
-            mu2v = jnp.where(active & jnp.isfinite(lw) & do_plan, mu2c, 0.0)
-            idx4 = jnp.concatenate([idx2, idx2])
-            alpha_new = alpha.at[
-                idx4, jnp.concatenate([i_sel, j_sel, s.pi, s.pj])].add(
-                jnp.concatenate([mu, -mu, mu2v, -mu2v]))
-        else:
-            alpha_new = alpha.at[idx2, jnp.concatenate([i_sel, j_sel])].add(
-                jnp.concatenate([mu, -mu]))
+            # lane freeze: converged lanes take a zero step — pass B becomes a
+            # bitwise no-op on their G, alpha is untouched.  Both working-set
+            # coordinates update through ONE stacked scatter.  The isfinite
+            # guard freezes a lane for one repair iteration when an unshrink
+            # event left it with a stale -inf g_i (empty masked I_up).
+            mu = jnp.where(active & jnp.isfinite(lw),
+                           jnp.where(do_plan, mu_plan, mu_smo), 0.0)
+            if conjugate:
+                # second-direction coefficient; 0 on rejected/frozen lanes, so
+                # both the extra scatter coordinates and pass B's axpy against
+                # ``conj.u`` are exact no-ops there (lane freeze stays bitwise)
+                mu2v = jnp.where(active & jnp.isfinite(lw) & do_plan, mu2c, 0.0)
+                idx4 = jnp.concatenate([idx2, idx2])
+                alpha_new = alpha.at[
+                    idx4, jnp.concatenate([i_sel, j_sel, s.pi, s.pj])].add(
+                    jnp.concatenate([mu, -mu, mu2v, -mu2v]))
+            else:
+                alpha_new = alpha.at[idx2, jnp.concatenate([i_sel, j_sel])].add(
+                    jnp.concatenate([mu, -mu]))
 
         # ---- pass B: k_i/k_j + update + next i + gap -----------------------
-        with scope("fused_pass_b"):
+        with jax.named_scope("fused_pass_b"):
             if conjugate:
                 G_new, i_next, g_i_next, g_dn, r_new = ops.source_update_wss(
                     src, G, alpha_new, L, U, i_sel, j_sel, mu, impl=impl,
@@ -617,63 +621,66 @@ def solve_fused_batched_qp(X, P, L, U, gamma,
                 G_new, i_next, g_i_next, g_dn = ops.source_update_wss(
                     src, G, alpha_new, L, U, i_sel, j_sel, mu, impl=impl,
                     block_l=block_l, act=act_kw)
-        gap_new = qp_mod.finite_gap(g_i_next - g_dn)
-        if shrinking:
-            # a lane only counts as converged when its mask was FULL at the
-            # scan that produced the gap; a partial-mask "solved" lane is
-            # unshrunk in place and keeps iterating (G is exact everywhere,
-            # so reactivation costs nothing).
-            full_now = jnp.all(s.act, axis=1)
-            locally_done = gap_new <= eps
-            done = s.done | (active & locally_done & full_now)
-            refresh = (s.t % period) == (period - 1)
-            act2 = jax.lax.cond(
-                refresh,
-                lambda: qp_mod.shrink_mask(G_new, alpha_new, L, U),
-                lambda: s.act)
-            act2 = act2 | (locally_done & ~full_now)[:, None]
-            act_new = jnp.where((active & ~done)[:, None], act2, s.act)
-            n_unshrink = s.n_unshrink + (
-                active & locally_done & ~full_now).astype(jnp.int32)
-        else:
-            done = s.done | (gap_new <= eps)
-            act_new = s.act
-            n_unshrink = s.n_unshrink
-        gap = jnp.where(active, gap_new, s.gap)
-
-        if conjugate:
-            # next iteration's carried direction: Q (e_i - e_j) is exactly
-            # pass B's in-VMEM row difference, returned for free.  The
-            # direction is reset (ok = False) whenever the step clipped
-            # (plain SMO hit the box), the shrink mask refreshed, or the
-            # lane unshrunk — per Conjugate-SMO's reset-on-clip rule.
-            cu_new = jnp.where(active[:, None], r_new, conj.u)
-            c_ok = do_plan | free_smo
+        with jax.named_scope("fused_step"):
+            gap_new = qp_mod.finite_gap(g_i_next - g_dn)
             if shrinking:
-                c_ok = c_ok & ~refresh & ~(locally_done & ~full_now)
-            c_ok = jnp.where(active, c_ok, conj.ok)
-            conj_new = _ConjState(u=cu_new, ok=c_ok)
+                # a lane only counts as converged when its mask was FULL at the
+                # scan that produced the gap; a partial-mask "solved" lane is
+                # unshrunk in place and keeps iterating (G is exact everywhere,
+                # so reactivation costs nothing).
+                full_now = jnp.all(s.act, axis=1)
+                locally_done = gap_new <= eps
+                done = s.done | (active & locally_done & full_now)
+                with jax.named_scope("fused_shrink"):
+                    refresh = (s.t % period) == (period - 1)
+                    act2 = jax.lax.cond(
+                        refresh,
+                        lambda: qp_mod.shrink_mask(G_new, alpha_new, L, U),
+                        lambda: s.act)
+                    act2 = act2 | (locally_done & ~full_now)[:, None]
+                    act_new = jnp.where((active & ~done)[:, None], act2, s.act)
+                n_unshrink = s.n_unshrink + (
+                    active & locally_done & ~full_now).astype(jnp.int32)
+            else:
+                done = s.done | (gap_new <= eps)
+                act_new = s.act
+                n_unshrink = s.n_unshrink
+            gap = jnp.where(active, gap_new, s.gap)
 
-        new_s = _BatchState(
-            alpha=alpha_new, G=G_new,
-            i=jnp.where(active, i_next.astype(jnp.int32), s.i),
-            g_i=jnp.where(active, g_i_next, s.g_i),
-            gap=gap, t=s.t + 1, iters=s.iters + active.astype(jnp.int32),
-            done=done,
-            pi=jnp.where(active, i_sel, s.pi).astype(jnp.int32),
-            pj=jnp.where(active, j_sel, s.pj).astype(jnp.int32),
-            qi=jnp.where(active, s.pi, s.qi),
-            qj=jnp.where(active, s.pj, s.qj),
-            n_hist=jnp.where(active, jnp.minimum(s.n_hist + 1, 2), s.n_hist),
-            p_smo=jnp.where(active, ~do_plan, s.p_smo),
-            prev_free=jnp.where(active, (~do_plan) & free_smo, s.prev_free),
-            prev_ratio_ok=jnp.where(active, ratio_ok, s.prev_ratio_ok),
-            n_planning=s.n_planning + (do_plan & active).astype(jnp.int32),
-            act=act_new, n_unshrink=n_unshrink)
+            if conjugate:
+                # next iteration's carried direction: Q (e_i - e_j) is exactly
+                # pass B's in-VMEM row difference, returned for free.  The
+                # direction is reset (ok = False) whenever the step clipped
+                # (plain SMO hit the box), the shrink mask refreshed, or the
+                # lane unshrunk — per Conjugate-SMO's reset-on-clip rule.
+                cu_new = jnp.where(active[:, None], r_new, conj.u)
+                c_ok = do_plan | free_smo
+                if shrinking:
+                    c_ok = c_ok & ~refresh & ~(locally_done & ~full_now)
+                c_ok = jnp.where(active, c_ok, conj.ok)
+                conj_new = _ConjState(u=cu_new, ok=c_ok)
+
+            new_s = _BatchState(
+                alpha=alpha_new, G=G_new,
+                i=jnp.where(active, i_next.astype(jnp.int32), s.i),
+                g_i=jnp.where(active, g_i_next, s.g_i),
+                gap=gap, t=s.t + 1, iters=s.iters + active.astype(jnp.int32),
+                done=done,
+                pi=jnp.where(active, i_sel, s.pi).astype(jnp.int32),
+                pj=jnp.where(active, j_sel, s.pj).astype(jnp.int32),
+                qi=jnp.where(active, s.pi, s.qi),
+                qj=jnp.where(active, s.pj, s.qj),
+                n_hist=jnp.where(active, jnp.minimum(s.n_hist + 1, 2),
+                                 s.n_hist),
+                p_smo=jnp.where(active, ~do_plan, s.p_smo),
+                prev_free=jnp.where(active, (~do_plan) & free_smo, s.prev_free),
+                prev_ratio_ok=jnp.where(active, ratio_ok, s.prev_ratio_ok),
+                n_planning=s.n_planning + (do_plan & active).astype(jnp.int32),
+                act=act_new, n_unshrink=n_unshrink)
         if not collect:
             return (new_s, conj_new) if conjugate else new_s
         # ---- flight recorder (O(B) only; see repro.telemetry.ring) ---------
-        with scope("telemetry_ring"):
+        with jax.named_scope("telemetry_ring"):
             if shrinking:
                 n_act = jnp.sum(act_new, axis=1).astype(jnp.int32)
             else:
@@ -871,10 +878,14 @@ def solve_fused_chunked_qp(X, P, L, U, gamma,
     ``n_unshrink`` accumulate across chunks and whose ``G`` is exact on
     every coordinate for every lane.
 
-    ``diagnostics`` (a :class:`repro.telemetry.Diagnostics`) turns on
-    the flight recorder at this host level: each chunk solve runs under
-    a phase scope (``chunk_solve`` events with wall seconds / live lane
-    and row counts), a :class:`repro.runtime.fault.StepMonitor` EWMA
+    Every round runs under a ``chunk_solve`` span
+    (:mod:`repro.telemetry.spans`: the round, live lanes and rows as
+    attributes), from the chunk's dispatch until its state is back on
+    the host.  ``diagnostics`` (a :class:`repro.telemetry.Diagnostics`)
+    turns on the flight recorder at this host level: each round's span
+    also lands in the sink as a ``chunk_solve`` phase event with its wall
+    seconds / live lane and row counts, a
+    :class:`repro.runtime.fault.StepMonitor` EWMA
     over chunk wall-times emits ``straggler_warning`` events when a
     chunk breaches the deadline factor, and — when
     ``diagnostics.ring_config`` is set — the per-chunk device rings are
@@ -928,14 +939,12 @@ def solve_fused_chunked_qp(X, P, L, U, gamma,
     live = np.arange(B)
     keep = np.arange(lb)
 
-    # ---- flight recorder (host tier) — zero work when diagnostics=None ----
-    rc = None if diagnostics is None else diagnostics.ring_config
-    monitor = None
-    tel = None
+    # ---- flight recorder (host tier): a span per round, always on; the
+    # ``phase`` events, straggler monitor and rings need ``diagnostics`` ----
+    sink = rc = monitor = tel = None
     if diagnostics is not None:
-        import time as _time
-
         from repro.runtime.fault import StepMonitor
+        sink, rc = diagnostics.sink, diagnostics.ring_config
         monitor = StepMonitor(warmup_steps=1)
     if rc is not None:
         tel = (np.zeros((B, rc.cap), np.int32), np.zeros((B, rc.cap)),
@@ -1002,36 +1011,32 @@ def solve_fused_chunked_qp(X, P, L, U, gamma,
 
         if rc is not None:
             bank_kw["telemetry"] = rc
-        t0 = 0.0 if diagnostics is None else _time.perf_counter()
-        res = chunk_solver(
-            X_sub, jnp.asarray(gather(P_np), dtype),
-            jnp.asarray(gather(L_np), dtype),
-            jnp.asarray(gather(U_np), dtype),
-            jnp.asarray(gam_np[lanes], dtype), ccfg, impl=impl,
-            block_l=block_l, alpha0=jnp.asarray(gather(alpha), dtype),
-            G0=jnp.asarray(gather(G), dtype), doubled=doubled,
-            shrinking=shrinking, **bank_kw)
-        ring = None
-        if rc is not None:
-            res, ring = res
-        if diagnostics is not None:
-            jax.block_until_ready(res.alpha)
-            dt = _time.perf_counter() - t0
-            diagnostics.event("phase", name="chunk_solve", seconds=dt,
-                              round=rnd, lanes=m_live, rows=m)
-            # EWMA straggler deadline over chunk wall-times — the same
-            # monitor the resilient LM step loop uses (runtime/fault.py)
-            if monitor.record(dt):
-                diagnostics.event(
-                    "straggler_warning", round=rnd, seconds=dt,
-                    deadline=monitor.deadline, lanes=live.tolist(),
-                    rows=m)
+        # the round's span ends once its state is back on the host
+        with phase_scope("chunk_solve", sink, round=rnd, lanes=m_live,
+                         rows=m) as sp:
+            res = chunk_solver(
+                X_sub, jnp.asarray(gather(P_np), dtype),
+                jnp.asarray(gather(L_np), dtype),
+                jnp.asarray(gather(U_np), dtype),
+                jnp.asarray(gam_np[lanes], dtype), ccfg, impl=impl,
+                block_l=block_l, alpha0=jnp.asarray(gather(alpha), dtype),
+                G0=jnp.asarray(gather(G), dtype), doubled=doubled,
+                shrinking=shrinking, **bank_kw)
+            ring = None
+            if rc is not None:
+                res, ring = res
+            ra = np.asarray(res.alpha, np.float64)[:m_live]
+            rg = np.asarray(res.G, np.float64)[:m_live]
+        # EWMA straggler deadline over chunk wall-times — the same monitor
+        # the resilient LM step loop uses (runtime/fault.py)
+        if monitor is not None and monitor.record(sp.seconds):
+            diagnostics.event(
+                "straggler_warning", round=rnd, seconds=sp.seconds,
+                deadline=monitor.deadline, lanes=live.tolist(), rows=m)
         if ring is not None:
             _merge_chunk_ring(rc, ring, live, out_iter[live],
                               out_unshrink[live], tel)
 
-        ra = np.asarray(res.alpha, np.float64)[:m_live]
-        rg = np.asarray(res.G, np.float64)[:m_live]
         alpha[np.ix_(live, keep)] = ra[:, :m]
         G[np.ix_(live, keep)] = rg[:, :m]
         if doubled:

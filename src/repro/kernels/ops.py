@@ -254,10 +254,19 @@ def _unstack_halves(a, B: int, l: int):
 def _state_stacks(t: Tiles, H: int, act, *state):
     """Half-stack the (B, n) lane-state leaves (and the optional act
     mask, as 1.0/0.0 in the data dtype) at the planned padding."""
-    stacks = [_stack_halves(a, H, t.bpad, t.lpad) for a in state]
-    act_st = (None if act is None else
-              _stack_halves(act.astype(state[0].dtype), H, t.bpad, t.lpad))
+    with jax.named_scope("lane_state"):
+        stacks = [_stack_halves(a, H, t.bpad, t.lpad) for a in state]
+        act_st = (None if act is None else
+                  _stack_halves(act.astype(state[0].dtype), H, t.bpad,
+                                t.lpad))
     return stacks, act_st
+
+
+def _pad_x(X, sqn, t: Tiles):
+    """The shared X and its row norms, padded to the planned tiles."""
+    with jax.named_scope("x_pad"):
+        return (_pad_d(_pad_l(X, t.lpad), _dpad(X.shape[1])),
+                _pad_l(sqn, t.lpad))
 
 
 def rbf_row_wss_batched(X, sqn, G, alpha, L, U, XQ, sqq, a_i, L_i, U_i,
@@ -289,8 +298,9 @@ def rbf_row_wss_batched(X, sqn, G, alpha, L, U, XQ, sqq, a_i, L_i, U_i,
                       a_i, L_i, U_i, g_i,
                       use_exact.astype(dtype)], axis=1).astype(dtype)
     stacks, act_st = _state_stacks(t, H, act, G, alpha, L, U)
+    Xp, sqnp = _pad_x(X, sqn, t)
     j, gain = rbf_row_wss_batched_pallas(
-        _pad_d(_pad_l(X, t.lpad), _dpad(d)), _pad_l(sqn, t.lpad), *stacks,
+        Xp, sqnp, *stacks,
         _pad_b(_pad_d(XQ, _dpad(d)), t.bpad), _pad_b(scal, t.bpad),
         _pad_b(_iscal(i_idx, B), t.bpad), act_st, block_l=t.block_l,
         block_b=t.block_b, interpret=(impl == "interpret"), base_l=l)
@@ -310,10 +320,12 @@ def _pass_b_result(out, B: int, l: int, dup: bool, conj: bool):
     """Unpad a pass B launch: (G_new (B, n), i_next, g_i_next, g_dn), plus
     the full-width direction row ``r`` under Conjugate-SMO."""
     G_new, i_next, g_i_next, g_dn = out[:4]
-    res = (_unstack_halves(G_new, B, l), i_next[:B], g_i_next[:B], g_dn[:B])
-    if conj:
-        r = out[4][:B, :l]
-        return res + (ref_ops.tile_rows(r) if dup else r,)
+    with jax.named_scope("lane_state"):
+        res = (_unstack_halves(G_new, B, l), i_next[:B], g_i_next[:B],
+               g_dn[:B])
+        if conj:
+            r = out[4][:B, :l]
+            res += (ref_ops.tile_rows(r) if dup else r,)
     return res
 
 
@@ -356,8 +368,9 @@ def rbf_update_wss_batched(X, sqn, G, alpha_new, L, U, XQi, sqqi, XQj, sqqj,
         dirv_row = _pad_bl(dirv[:, :l].astype(dtype), t.bpad, t.lpad)
     scal = jnp.stack(cols, axis=1).astype(dtype)
     stacks, act_st = _state_stacks(t, H, act, G, alpha_new, L, U)
+    Xp, sqnp = _pad_x(X, sqn, t)
     out = rbf_update_wss_batched_pallas(
-        _pad_d(_pad_l(X, t.lpad), _dpad(d)), _pad_l(sqn, t.lpad), *stacks,
+        Xp, sqnp, *stacks,
         _stack_queries(_pad_d(XQi, _dpad(d)), _pad_d(XQj, _dpad(d)), t),
         _pad_b(scal, t.bpad), act_st, dirv_row, block_l=t.block_l, block_b=t.block_b,
         interpret=(impl == "interpret"), base_l=l)

@@ -252,6 +252,7 @@ def rbf_row_wss_batched_pallas(X, sqn, G, alpha, L, U, XQ, scalars,
         out_shape=tuple(out_shapes),
         compiler_params=compiler_params(),
         interpret=interpret,
+        name="rbf_row_wss_batched_pallas",
     )(*args)
     sel = (out[1][:, 0], out[0][:, 0])
     return sel + (out[2],) if emit_k else sel
@@ -296,5 +297,6 @@ def row_wss_batched_rows_pallas(KR, G, alpha, L, U, scalars, iscalars,
         out_shape=tuple(out_shapes),
         compiler_params=compiler_params(),
         interpret=interpret,
+        name="row_wss_batched_rows_pallas",
     )(*args)
     return barg[:, 0], bmax[:, 0]

@@ -183,9 +183,10 @@ def _kernel_batched_rows(*refs, block_l: int, base_l: int,
 
 
 def _launch(kernel, args, in_specs, *, H, B, bb, lpad, block_l, dtype, act,
-            dirv, interpret):
+            dirv, interpret, name):
     """Shared pass-B launch: lane-state / selection outputs, optional act
-    and Conjugate-SMO direction operands, and the result unpacking."""
+    and Conjugate-SMO direction operands, and the result unpacking.
+    ``name`` names the ``pallas_call`` (the profiler's kernel name)."""
     lane_spec = pl.BlockSpec((H, bb, block_l), lambda c, b: (0, c, b))
     row_spec = pl.BlockSpec((bb, block_l), lambda c, b: (c, b))
     sel_spec = pl.BlockSpec((bb, LANES), lambda c, b: (c, 0))
@@ -213,6 +214,7 @@ def _launch(kernel, args, in_specs, *, H, B, bb, lpad, block_l, dtype, act,
         out_shape=tuple(out_shapes),
         compiler_params=compiler_params(),
         interpret=interpret,
+        name=name,
     )(*args)
     G_new, bmax, barg, bmin = out[:4]
     return (G_new, barg[:, 0], bmax[:, 0], bmin[:, 0]) + tuple(out[4:])
@@ -262,7 +264,7 @@ def rbf_update_wss_batched_pallas(X, sqn, G, alpha_new, L, U, XQ, scalars,
                                conj=dirv is not None, given_ki=given_ki)
     return _launch(kernel, args, in_specs, H=H, B=B, bb=bb, lpad=lpad,
                    block_l=block_l, dtype=X.dtype, act=act, dirv=dirv,
-                   interpret=interpret)
+                   interpret=interpret, name="rbf_update_wss_batched_pallas")
 
 
 @functools.partial(jax.jit, static_argnames=("block_l", "block_b",
@@ -294,4 +296,4 @@ def update_wss_batched_rows_pallas(KRi, KRj, G, alpha_new, L, U, scalars,
                                conj=dirv is not None)
     return _launch(kernel, args, in_specs, H=H, B=B, bb=bb, lpad=lpad,
                    block_l=block_l, dtype=KRi.dtype, act=act, dirv=dirv,
-                   interpret=interpret)
+                   interpret=interpret, name="update_wss_batched_rows_pallas")

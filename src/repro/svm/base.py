@@ -14,6 +14,7 @@ import numpy as np
 
 from repro.core.solver import SolverConfig
 from repro.kernels import ops
+from repro.telemetry import phase_scope, span
 
 
 class SVMEstimatorBase:
@@ -66,18 +67,24 @@ class SVMEstimatorBase:
             return None
         return self.diagnostics.ring_config
 
-    def _fit_scope(self, name: str, **meta):
-        """Host-tier phase scope around a fit, or a no-op without one."""
-        from contextlib import nullcontext
-        if self.diagnostics is None:
-            return nullcontext()
-        return self.diagnostics.scope(name, **meta)
+    def _fit_scope(self, name: str):
+        """The fit's root span (:mod:`repro.telemetry.spans`), always on;
+        with a ``diagnostics`` handle it also emits the ``phase`` event."""
+        return phase_scope(name, None if self.diagnostics is None
+                           else self.diagnostics.sink)
+
+    @staticmethod
+    def _hold_counters(sp, res) -> None:
+        """Keep the engine's counters on the fit's root span, unread."""
+        sp.hold(iterations=res.iterations, n_planning=res.n_planning,
+                converged=res.converged)
 
     def _config(self) -> SolverConfig:
         return SolverConfig(algorithm=self.algorithm, step=self.step,
                             eps=self.eps, max_iter=self.max_iter,
                             plan_candidates=self.plan_candidates)
 
+    @span("fit.gamma")
     def _resolve_gamma(self, X) -> float:
         if self.gamma == "scale":
             var = float(np.asarray(X).var())

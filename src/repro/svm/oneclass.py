@@ -31,6 +31,7 @@ from repro.core.solver_fused import solve_fused_batched_qp
 from repro.kernels import ops
 from repro.kernels.ref import HIGHEST
 from repro.svm.base import SVMEstimatorBase
+from repro.telemetry import span
 
 
 class OneClassSVM(SVMEstimatorBase):
@@ -59,6 +60,11 @@ class OneClassSVM(SVMEstimatorBase):
                           diagnostics=diagnostics)
 
     def fit(self, X, y=None) -> "OneClassSVM":
+        with self._fit_scope("oneclass_fit") as sp:
+            self._fit(X, sp)
+        return self
+
+    def _fit(self, X, sp) -> None:
         X = jnp.asarray(X, self.dtype)
         l = X.shape[0]
         self.gamma_ = self._resolve_gamma(X)
@@ -67,11 +73,11 @@ class OneClassSVM(SVMEstimatorBase):
         engine = self._resolve_engine()
         qp = qp_mod.oneclass_qp(l, self.nu, self.dtype)
         a0 = qp_mod.oneclass_alpha0(l, self.nu, self.dtype)
+        sp.attrs.update(engine=engine, rows=int(X.shape[0]))
 
         tel = self._ring_config()
         ring = None
-        with self._fit_scope("oneclass_fit", engine=engine,
-                             rows=int(X.shape[0])):
+        with span("fit.solve"):
             if engine in ("fused", "sharded"):
                 bank_kw = {}
                 if self.precompute and ops.resolve_impl(self.impl) == "jnp":
@@ -101,8 +107,9 @@ class OneClassSVM(SVMEstimatorBase):
                 else:
                     kern = qp_mod.make_rbf(X, self.gamma_)
                 res = solve_qp(kern, qp, cfg, alpha0=a0)
-            if self.diagnostics is not None:
-                jax.block_until_ready(res.alpha)
+        self._hold_counters(sp, res)
+        if self.diagnostics is not None:
+            jax.block_until_ready(res.alpha)
         if ring is not None:
             self.diagnostics.drain_ring(
                 ring, [{"gamma": self.gamma_, "nu": float(self.nu)}], out)
@@ -111,7 +118,6 @@ class OneClassSVM(SVMEstimatorBase):
         self.alpha_ = res.alpha
         self.b_ = res.b
         self.rho_ = float(-res.b)
-        return self
 
     def decision_function(self, Xq) -> jnp.ndarray:
         """Signed distance to the separating surface: >= 0 for inliers."""

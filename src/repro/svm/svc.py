@@ -37,6 +37,7 @@ from repro.core.solver_fused import FusedResult
 from repro.kernels import ops
 from repro.kernels.ref import HIGHEST
 from repro.svm.base import SVMEstimatorBase
+from repro.telemetry import span
 
 
 class SVC(SVMEstimatorBase):
@@ -108,6 +109,11 @@ class SVC(SVMEstimatorBase):
         return w[y_idx]
 
     def fit(self, X, y) -> "SVC":
+        with self._fit_scope("svc_fit") as sp:
+            self._fit(X, y, sp)
+        return self
+
+    def _fit(self, X, y, sp) -> None:
         X = jnp.asarray(X, self.dtype)
         self.classes_, y_idx = mc.class_index(y)
         k = len(self.classes_)
@@ -117,6 +123,7 @@ class SVC(SVMEstimatorBase):
         self.X_ = X
         cfg = self._config()
         engine = self._resolve_engine(n_lanes=1 if k == 2 else k)
+        sp.attrs.update(engine=engine, n_class=k, rows=int(X.shape[0]))
 
         if k == 2 and np.asarray(self.C).size != 1:
             raise ValueError("per-class C requires more than two "
@@ -141,8 +148,7 @@ class SVC(SVMEstimatorBase):
 
         tel = self._ring_config()
         ring = None
-        with self._fit_scope("svc_fit", engine=engine, n_class=k,
-                             rows=int(X.shape[0])):
+        with span("fit.solve"):
             if engine in ("fused", "sharded"):
                 shard_kw = {}
                 if engine == "sharded":
@@ -175,8 +181,9 @@ class SVC(SVMEstimatorBase):
                     res = solve(kern, yb, C_bin, cfg)
                 else:
                     res = mc.solve_ovr(kern, Y, C_ovr, cfg)
-            if self.diagnostics is not None:
-                jax.block_until_ready(res.alpha)
+        self._hold_counters(sp, res)
+        if self.diagnostics is not None:
+            jax.block_until_ready(res.alpha)
         if ring is not None:
             # one lane per class head (the lone head of a binary fit is the
             # "classes_[1] vs rest" problem, label index 1)
@@ -191,7 +198,6 @@ class SVC(SVMEstimatorBase):
         self.engine_ = engine
         self.alpha_ = res.alpha          # (l,) binary, (k, l) one-vs-rest
         self.b_ = res.b
-        return self
 
     # -- inference ----------------------------------------------------------
 

@@ -34,6 +34,7 @@ from repro.core.solver_fused import solve_fused_batched_qp
 from repro.kernels import ops
 from repro.kernels.ref import HIGHEST
 from repro.svm.base import SVMEstimatorBase
+from repro.telemetry import span
 
 
 class SVR(SVMEstimatorBase):
@@ -71,17 +72,23 @@ class SVR(SVMEstimatorBase):
                           diagnostics=diagnostics)
 
     def fit(self, X, y) -> "SVR":
+        with self._fit_scope("svr_fit") as sp:
+            self._fit(X, y, sp)
+        return self
+
+    def _fit(self, X, y, sp) -> None:
         X = jnp.asarray(X, self.dtype)
         y = jnp.asarray(y, self.dtype)
         self.gamma_ = self._resolve_gamma(X)
         self.X_ = X
         cfg = self._config()
         engine = self._resolve_engine()
+        sp.attrs.update(engine=engine, rows=int(X.shape[0]))
         qp = qp_mod.svr_qp(y, float(self.C), float(self.epsilon))
 
         tel = self._ring_config()
         ring = None
-        with self._fit_scope("svr_fit", engine=engine, rows=int(X.shape[0])):
+        with span("fit.solve"):
             if engine in ("fused", "sharded"):
                 bank_kw = {}
                 if self.precompute and ops.resolve_impl(self.impl) == "jnp":
@@ -107,8 +114,9 @@ class SVR(SVMEstimatorBase):
                 else:
                     base = qp_mod.make_rbf(X, self.gamma_)
                 res = solve_qp(qp_mod.DoubledKernel(base), qp, cfg)
-            if self.diagnostics is not None:
-                jax.block_until_ready(res.alpha)
+        self._hold_counters(sp, res)
+        if self.diagnostics is not None:
+            jax.block_until_ready(res.alpha)
         if ring is not None:
             self.diagnostics.drain_ring(
                 ring, [{"gamma": self.gamma_, "C": float(self.C),
@@ -118,7 +126,6 @@ class SVR(SVMEstimatorBase):
         self.alpha_ = res.alpha                    # (2l,) doubled dual
         self.beta_ = qp_mod.svr_fold(res.alpha)    # (l,) coefficients
         self.b_ = res.b
-        return self
 
     def predict(self, Xq) -> jnp.ndarray:
         self._check_fitted()

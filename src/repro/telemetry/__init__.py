@@ -5,8 +5,11 @@ Three tiers (see ISSUE 8 / the README "Observability" section):
 * **device** — :class:`~repro.telemetry.ring.TelemetryRing`, a bounded
   per-lane ring-buffer pytree carried through the fused while_loop
   (:mod:`repro.telemetry.ring`);
-* **host** — JSONL event sink, phase timers / profiler scopes, and the
-  environment fingerprint (:mod:`repro.telemetry.sink`);
+* **host** — always-on spans and compile counters
+  (:mod:`repro.telemetry.spans`: :func:`span`, :func:`recent`,
+  :func:`export`), the JSONL event sink, phase scopes (a span plus a
+  ``phase`` event), and the environment fingerprint
+  (:mod:`repro.telemetry.sink`);
 * **report** — ``python -m repro.launch.telemetry_report`` renders
   convergence tables and a straggler diagnosis from the JSONL artifact.
 
@@ -26,11 +29,14 @@ from repro.telemetry.ring import (RingConfig, TelemetryRing, ring_init,
                                   ring_slice, ring_update)
 from repro.telemetry.sink import (JsonlSink, _to_plain, env_fingerprint,
                                   fingerprint_diff, phase_scope, read_jsonl)
+from repro.telemetry.spans import (Span, children, clear, export, recent,
+                                   span)
 
 __all__ = [
     "Diagnostics", "RingConfig", "TelemetryRing", "ring_init",
     "ring_update", "ring_slice", "JsonlSink", "env_fingerprint",
-    "fingerprint_diff", "phase_scope", "read_jsonl",
+    "fingerprint_diff", "phase_scope", "read_jsonl", "Span", "span",
+    "recent", "children", "export", "clear",
 ]
 
 
@@ -55,7 +61,8 @@ class Diagnostics:
     # -- host tier ---------------------------------------------------------
 
     def scope(self, name: str, **meta):
-        """Wall-clock + profiler scope; emits a ``phase`` event."""
+        """A span (:func:`~repro.telemetry.spans.span`) that also emits a
+        ``phase`` event into this handle's sink."""
         return phase_scope(name, self.sink, **meta)
 
     def event(self, event: str, **payload):

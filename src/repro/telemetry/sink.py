@@ -11,9 +11,9 @@ the host around it:
 * :class:`JsonlSink` — an append-only structured event stream (one JSON
   object per line) that also keeps the events in memory for in-process
   consumers (the report CLI reads either);
-* :func:`phase_scope` — wall-clock timer + ``jax.profiler``
-  ``TraceAnnotation`` named scope, so solver phases show up both in the
-  JSONL stream and in profiler traces when one is being captured.
+* :func:`phase_scope` — a :func:`~repro.telemetry.spans.span` that
+  also lands in the JSONL stream as a ``phase`` event, so solver phases
+  show up in the stream, in the span buffer and in profiler traces.
 """
 
 from __future__ import annotations
@@ -28,6 +28,8 @@ import time
 
 import jax
 import numpy as np
+
+from repro.telemetry.spans import span
 
 FINGERPRINT_KEYS = ("jax_version", "backend", "device_kind",
                     "device_count", "cpu_count", "host")
@@ -126,34 +128,23 @@ class JsonlSink:
         self.close()
 
 
-def _trace_annotation(name: str):
-    """Profiler named scope, tolerant of jax versions/backends without it."""
-    ann = getattr(jax.profiler, "TraceAnnotation", None)
-    if ann is None:  # pragma: no cover - very old jax
-        return contextlib.nullcontext()
-    try:
-        return ann(name)
-    except Exception:  # pragma: no cover - profiler backend quirk
-        return contextlib.nullcontext()
-
-
 @contextlib.contextmanager
 def phase_scope(name: str, sink: JsonlSink | None = None, **meta):
-    """Wall-clock + profiler scope around a solver phase.
+    """A :func:`~repro.telemetry.spans.span` around a solver phase that
+    also emits a ``phase`` event into ``sink``.
 
-    Emits a ``phase`` event with the measured ``seconds`` on exit; the
-    ``TraceAnnotation`` makes the same span visible in a profiler trace
-    when one is active.  Usable with ``sink=None`` as a pure profiler
-    scope.
+    Yields the span; the event carries the span's ``seconds`` and its
+    attributes (``meta`` plus whatever the caller added while it was
+    open), and is emitted on exit, exceptions included.  With
+    ``sink=None`` it is the span alone.
     """
-    t0 = time.perf_counter()
-    with _trace_annotation(name):
-        try:
-            yield
-        finally:
-            dt = time.perf_counter() - t0
-            if sink is not None:
-                sink.emit("phase", name=name, seconds=dt, **meta)
+    sp = span(name, **meta)
+    try:
+        with sp:
+            yield sp
+    finally:
+        if sink is not None:
+            sink.emit("phase", name=name, seconds=sp.seconds, **sp.attrs)
 
 
 def read_jsonl(path) -> list[dict]:
