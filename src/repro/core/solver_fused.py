@@ -430,6 +430,10 @@ def solve_fused_batched_qp(X, P, L, U, gamma,
         src = row_source.bank_source(gram, gram_idx, gamma, dup=doubled)
     else:
         src = row_source.rbf_source(X, gamma, B, dup=doubled)
+        if ops.resolve_impl(impl) != "jnp":
+            # X is loop-invariant: pad it once here, not in every trip
+            src = ops.pad_source(src, B, block_l, masked=shrinking,
+                                 conj=conjugate)
 
     # The loop body is dispatch-bound on CPU (dozens of O(B) ops between the
     # two passes), so the per-lane scalar algebra below leans on two

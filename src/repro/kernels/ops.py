@@ -7,10 +7,14 @@
                   the fast path on CPU and the numerical oracle in tests).
   * "auto"      — pallas on TPU, jnp elsewhere.
 
-All wrappers pad the example dimension to the block multiple with *inert*
-rows (L = U = 0 so they can never be selected; see sharded.py for the same
-trick) and the feature dimension to a lane multiple for the MXU.  The
-block sizes come from the shapes (:func:`plan_tiles`): the caller's
+All wrappers pad the lane state's example dimension to the block
+multiple with *inert* rows (L = U = 0 so they can never be selected; see
+sharded.py for the same trick).  The shared X and its row norms are padded
+to the block multiple and X's feature dimension to a lane multiple for the
+MXU — by the fused engine, once per call before its while loop
+(:func:`pad_source`), so the batched wrappers get X already padded and
+pass it through; a caller that hands them raw X has it padded per call.
+The block sizes come from the shapes (:func:`plan_tiles`): the caller's
 ``block_l`` is an upper bound that shrinks — and the lane batch splits
 into blocks — until one grid step's working set fits the VMEM budget.
 
@@ -25,6 +29,7 @@ dtype (a float32 round-trip is lossy beyond l = 2^24).
 
 from __future__ import annotations
 
+import dataclasses
 from typing import NamedTuple
 
 import jax
@@ -262,11 +267,38 @@ def _state_stacks(t: Tiles, H: int, act, *state):
     return stacks, act_st
 
 
-def _pad_x(X, sqn, t: Tiles):
-    """The shared X and its row norms, padded to the planned tiles."""
+def _pad_x(X, sqn, lpad: int):
+    """The shared X and its row norms at ``lpad`` rows, X lane-padded.
+
+    An X that already has at least ``lpad`` rows and a lane-multiple width
+    (:func:`pad_source`) passes through untouched: the kernels' grid reads
+    ``lpad`` rows of it.  The norms are cut to exactly ``lpad``.
+    """
     with jax.named_scope("x_pad"):
-        return (_pad_d(_pad_l(X, t.lpad), _dpad(X.shape[1])),
-                _pad_l(sqn, t.lpad))
+        if X.shape[0] < lpad:
+            X = _pad_l(X, lpad)
+        X = _pad_d(X, _dpad(X.shape[1]))
+        sqn = sqn[:lpad] if sqn.shape[0] > lpad else _pad_l(sqn, lpad)
+        return X, sqn
+
+
+def pad_source(src: RowSource, B: int, block_l: int, *,
+               masked: bool = False, conj: bool = False) -> RowSource:
+    """The rbf row source with X and its row norms padded once.
+
+    Rows go to the larger ``lpad`` that pass A and pass B plan for ``B``
+    lanes (``masked``: shrinking's active mask, ``conj``: Conjugate-SMO),
+    columns to a lane multiple; the true example count rides along as
+    ``src.l``.  Called before the engine's while loop, it leaves the loop
+    body a loop-invariant padded X, so neither batched pass pads it again.
+    """
+    l, d = src.X.shape
+    H = 2 if src.dup else 1
+    lpad = max(pass_a_tiles(B, l, d, block_l, H=H, masked=masked).lpad,
+               pass_b_tiles(B, l, d, block_l, H=H, masked=masked,
+                            conj=conj).lpad)
+    X, sqn = _pad_x(src.X, src.sqn, lpad)
+    return dataclasses.replace(src, X=X, sqn=sqn, l=l)
 
 
 def rbf_row_wss_batched(X, sqn, G, alpha, L, U, XQ, sqq, a_i, L_i, U_i,
@@ -274,13 +306,14 @@ def rbf_row_wss_batched(X, sqn, G, alpha, L, U, XQ, sqq, a_i, L_i, U_i,
                         block_l: int = 1024, dup: bool = False, act=None):
     """Batched pass A: per-lane WSS2 selection, returns (j (B,), gain (B,)).
 
-    ``X``/``sqn`` are shared; ``G``/``alpha``/``L``/``U`` are (B, n); ``XQ``
-    is the (B, d) gathered *base* query rows; the rest are (B,) per-lane
-    scalars.  ``dup=True`` runs the doubled ε-SVR operator (n = 2l over
-    base ``X``/``sqn``): the jnp oracle computes the base (B, l) row and
-    tiles it; the Pallas path stacks the lane state into (2, B, lpad)
-    halves and the kernel reads the base row tile twice — the matmul never
-    widens past l.  ``act`` is an optional (B, n) active-set mask (soft
+    ``X``/``sqn`` are shared, raw or already padded (:func:`pad_source`;
+    the true example count is read off the (B, n) lane state);
+    ``G``/``alpha``/``L``/``U`` are (B, n); ``XQ`` is the (B, d) gathered
+    *base* query rows; the rest are (B,) per-lane scalars.  ``dup=True``
+    runs the doubled ε-SVR operator (n = 2l over base ``X``/``sqn``): the
+    jnp oracle computes the base (B, l) row and tiles it; the Pallas path
+    stacks the lane state into (2, B, lpad) halves and the kernel reads
+    the base row tile twice — the matmul never widens past l.  ``act`` is an optional (B, n) active-set mask (soft
     shrinking: restricts the j-scan only).
     """
     impl = resolve_impl(impl)
@@ -289,16 +322,15 @@ def rbf_row_wss_batched(X, sqn, G, alpha, L, U, XQ, sqq, a_i, L_i, U_i,
                                            a_i, L_i, U_i, g_i, i_idx,
                                            use_exact, gammas, dup=dup,
                                            act=act)
-    l, d = X.shape
     H = 2 if dup else 1
-    B = G.shape[0]
+    B, l, d = G.shape[0], G.shape[1] // H, X.shape[1]
     t = pass_a_tiles(B, l, d, block_l, H=H, masked=act is not None)
     dtype = X.dtype
     scal = jnp.stack([sqq, jnp.broadcast_to(gammas, (B,)),
                       a_i, L_i, U_i, g_i,
                       use_exact.astype(dtype)], axis=1).astype(dtype)
     stacks, act_st = _state_stacks(t, H, act, G, alpha, L, U)
-    Xp, sqnp = _pad_x(X, sqn, t)
+    Xp, sqnp = _pad_x(X, sqn, t.lpad)
     j, gain = rbf_row_wss_batched_pallas(
         Xp, sqnp, *stacks,
         _pad_b(_pad_d(XQ, _dpad(d)), t.bpad), _pad_b(scal, t.bpad),
@@ -337,8 +369,9 @@ def rbf_update_wss_batched(X, sqn, G, alpha_new, L, U, XQi, sqqi, XQj, sqqj,
 
     Recomputes both *base* rows k_i/k_j against the shared X (no HBM
     round-trip for either); a lane with ``mu == 0`` leaves G bitwise
-    unchanged.  ``dup`` selects the doubled ε-SVR operator exactly as in
-    :func:`rbf_row_wss_batched` (in-kernel half reads, l-wide matmuls).
+    unchanged.  ``X``/``sqn`` and ``dup`` (the doubled ε-SVR operator) are
+    as in :func:`rbf_row_wss_batched` (in-kernel half reads, l-wide
+    matmuls).
     ``act`` optionally restricts the next-i scan and gap endpoints (the
     gradient update is never masked).  ``dirv``/``mu2`` engage the
     Conjugate-SMO second-direction axpy and grow the return by
@@ -351,9 +384,8 @@ def rbf_update_wss_batched(X, sqn, G, alpha_new, L, U, XQi, sqqi, XQj, sqqj,
                                               XQi, sqqi, XQj, sqqj, mu,
                                               gammas, dup=dup, act=act,
                                               dirv=dirv, mu2=mu2)
-    l, d = X.shape
     H = 2 if dup else 1
-    B = G.shape[0]
+    B, l, d = G.shape[0], G.shape[1] // H, X.shape[1]
     conj = dirv is not None
     t = pass_b_tiles(B, l, d, block_l, H=H, masked=act is not None,
                      conj=conj)
@@ -368,7 +400,7 @@ def rbf_update_wss_batched(X, sqn, G, alpha_new, L, U, XQi, sqqi, XQj, sqqj,
         dirv_row = _pad_bl(dirv[:, :l].astype(dtype), t.bpad, t.lpad)
     scal = jnp.stack(cols, axis=1).astype(dtype)
     stacks, act_st = _state_stacks(t, H, act, G, alpha_new, L, U)
-    Xp, sqnp = _pad_x(X, sqn, t)
+    Xp, sqnp = _pad_x(X, sqn, t.lpad)
     out = rbf_update_wss_batched_pallas(
         Xp, sqnp, *stacks,
         _stack_queries(_pad_d(XQi, _dpad(d)), _pad_d(XQj, _dpad(d)), t),

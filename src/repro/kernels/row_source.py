@@ -38,7 +38,7 @@ from repro.kernels.ref import HIGHEST
 @functools.partial(
     jax.tree_util.register_dataclass,
     data_fields=("X", "sqn", "gammas", "gram", "gram_idx"),
-    meta_fields=("dup",))
+    meta_fields=("dup", "l"))
 @dataclasses.dataclass(frozen=True)
 class RowSource:
     """Where pass A/B kernel rows come from (see module docstring).
@@ -47,7 +47,10 @@ class RowSource:
     rows; ``gammas`` is the (B,) per-lane RBF width (used by the rbf
     supplier and by :meth:`entry_pairs`).  ``dup`` marks the doubled ε-SVR
     operator: lane state indices live in [0, 2l) and fold onto the base
-    example axis through :meth:`base_idx`.
+    example axis through :meth:`base_idx`.  ``l`` is the true example
+    count where ``X``/``sqn`` carry zero padding rows and columns
+    (:func:`repro.kernels.ops.pad_source`); ``None`` means ``X`` is
+    unpadded.
     """
 
     X: Optional[jax.Array] = None          # (l, d) base inputs
@@ -56,6 +59,7 @@ class RowSource:
     gram: Optional[jax.Array] = None       # (n_stack, l, l) base Gram bank
     gram_idx: Optional[jax.Array] = None   # (B,) lane -> stack entry
     dup: bool = False
+    l: Optional[int] = None                # true example count if X is padded
 
     # -- static structure ---------------------------------------------------
 
@@ -66,7 +70,9 @@ class RowSource:
     @property
     def base_l(self) -> int:
         """True base example count (never the padded or doubled length)."""
-        return (self.gram.shape[-1] if self.is_bank else self.X.shape[0])
+        if self.is_bank:
+            return self.gram.shape[-1]
+        return self.X.shape[0] if self.l is None else self.l
 
     @property
     def n(self) -> int:
@@ -83,7 +89,8 @@ class RowSource:
         """Per-lane pass inputs at (stacked) coordinate indices ``idx``.
 
         Bank: the gathered (m, l) *base* rows.  Rbf: the (m, d) base query
-        rows plus their squared norms.  Tiling for the doubled operator
+        rows (zero-padded columns included, if ``X`` carries them) plus
+        their squared norms.  Tiling for the doubled operator
         happens downstream (in-kernel, or in the jnp oracle) — never here.
         """
         b = self.base_idx(idx)
@@ -124,6 +131,8 @@ class RowSource:
                      jnp.arange(v.shape[0], dtype=jnp.int32)]
         else:
             X, sqn = self.X, self.sqn
+            if X.shape[0] != l:              # padded rows are not examples
+                X, sqn = X[:l], sqn[:l]
             d = X.shape[1]
             pad = (-l) % block
             Xp = jnp.pad(X, ((0, pad), (0, 0)))
