@@ -29,7 +29,9 @@ against the shared X tile.  That removes the k_i HBM round-trip and —
 crucially — the data-dependent pass-A relaunch when Alg. 3's B^(t-2)
 candidate wins, which has no batched equivalent.  Converged lanes are
 frozen *in kernel*: their step size is forced to 0, so pass B's update is
-a bitwise no-op on G and the loop condition is simply "any lane active".
+a bitwise no-op on G.  A lane converges only through the exit check,
+which reads its gap from exact gradient rows (see
+:func:`solve_fused_batched_qp`).
 """
 
 from __future__ import annotations
@@ -48,7 +50,7 @@ from repro.core.qp import TAU
 from repro.core.solver import DEFAULT_SHRINK_EVERY, SolverConfig
 from repro.kernels import ops
 from repro.kernels import row_source
-from repro.kernels.ref import take_lane as _take_lane
+from repro.kernels.ref import rbf_exp, take_lane as _take_lane
 from repro.telemetry import phase_scope
 from repro.telemetry.ring import (RingConfig, TelemetryRing, ring_init,
                                   ring_update)
@@ -65,9 +67,11 @@ class FusedResult:
     kkt_gap: jax.Array
     converged: jax.Array
     n_planning: jax.Array
-    # number of unshrink events (a lane's masked problem looked solved but
-    # the full KKT check failed, forcing reactivation); 0 when shrinking is
-    # off or never triggered a reconstruction
+    # number of reactivations: a lane looked solved, the full exact check
+    # failed, and the lane was reactivated.  Counts both shrinking's
+    # unshrink events (the masked problem looked solved) and the batched
+    # engine's exit-check reopenings (the carried gradient read a gap of
+    # eps, the gap from exact rows did not)
     n_unshrink: jax.Array
 
 
@@ -118,7 +122,7 @@ def solve_fused(X, y, C, gamma, cfg: SolverConfig = SolverConfig(),
         """O(d) single RBF kernel entry."""
         d2 = (jnp.take(sqn, a) + jnp.take(sqn, b)
               - 2.0 * jnp.dot(jnp.take(X, b, axis=0), jnp.take(X, a, axis=0)))
-        return jnp.exp(-gamma * jnp.maximum(d2, 0.0))
+        return rbf_exp(-gamma * jnp.maximum(d2, 0.0))
 
     def pass_a(G, alpha, i, g_i, use_exact):
         return ops.rbf_row_wss(
@@ -278,7 +282,9 @@ class _BatchState(NamedTuple):
     n_planning: jax.Array     # (B,)
     act: jax.Array            # (B, n) bool active set ((B, 1) dummy when
                               # shrinking is off)
-    n_unshrink: jax.Array     # (B,) unshrink (reactivation) events
+    n_unshrink: jax.Array     # (B,) reactivation events (FusedResult)
+    pend: jax.Array           # (B,) carried gap reached eps: exit check due
+    b: jax.Array              # (B,) bias from the last exit check's exact ends
 
 
 class _ConjState(NamedTuple):
@@ -293,6 +299,78 @@ class _ConjState(NamedTuple):
     u: jax.Array    # (B, n) Q (e_pi - e_pj): previous direction's Q-product
                     # (pass B's in-VMEM row difference k_i - k_j)
     ok: jax.Array   # (B,) direction valid (reset on clip / shrink events)
+
+
+VERIFY_ROWS = 64      # rows checked exactly at each end of a lane's gap
+VERIFY_ROUNDS = 3     # rounds of trips, each ended by an exit check
+
+
+def _end_rows(G, up, dn, r: int):
+    """(B, 2r) rows nearest each end of every lane's gap by ``G``: the r
+    largest over ``up``, then the r smallest over ``dn``.  Picked one by
+    one in (value, index) order, each pick the first after the last;
+    a sort of an l-long row costs the TPU compiler tens of seconds, and
+    a loop that rewrote G-sized arrays would claim fast memory the trips
+    need."""
+    inf = jnp.asarray(jnp.inf, G.dtype)
+    v = jnp.stack([jnp.where(up, G, -inf), jnp.where(dn, -G, -inf)])
+    idx = jnp.arange(G.shape[1], dtype=jnp.int32)
+
+    def pick(t, c):
+        last_v, last_i, rows = c                       # (2, B) each
+        after = ((v < last_v[..., None])
+                 | ((v == last_v[..., None]) & (idx > last_i[..., None])))
+        w = jnp.where(after, v, -inf)
+        i = jax.lax.argmax(w, 2, jnp.int32)
+        val = jax.vmap(_take_lane)(w, i)
+        rows = jax.lax.dynamic_update_slice_in_dim(rows, i[..., None], t, 2)
+        return val, i, rows
+
+    B = G.shape[0]
+    c0 = (jnp.full((2, B), inf, G.dtype), jnp.full((2, B), -1, jnp.int32),
+          jnp.zeros((2, B, r), jnp.int32))
+    rows = jax.lax.fori_loop(jnp.int32(0), jnp.int32(r), pick, c0)[2]
+    return jnp.concatenate([rows[0], rows[1]], axis=1)
+
+
+def _set_lane(M, idx, val):
+    """Per-lane scatter ``M[b, idx[b]] = val[b]`` (idx (B,) or (B, m)),
+    on the int32 index channel like :func:`_take_lane`."""
+    return jax.vmap(lambda row, i, v: row.at[i].set(v))(
+        M, idx, jnp.broadcast_to(val, idx.shape).astype(M.dtype))
+
+
+def _exit_check(src, P, L, U, alpha, G, eps):
+    """LIBSVM's stopping test for every lane, on exact gradient rows.
+
+    ``G`` is the carried gradient, which drifts in float32 over many
+    rank-2 updates.  The :data:`VERIFY_ROWS` rows nearest each end of a
+    lane's gap by carried value (the largest ``G`` over ``alpha < U``, the
+    smallest over ``alpha > L``) are recomputed as ``p - Q alpha``
+    (:meth:`~repro.kernels.row_source.RowSource.exact_rows`).  The largest
+    correction found bounds the drift of the rows left unchecked, whose
+    carried values count widened by twice it (as the benchmark's float64
+    check widens its float32 pass).  Returns the gradient with the checked
+    rows exact, that bounded gap, the bias (the midpoint of the checked
+    exact ends) and whether the gap is at most ``eps``, per lane.
+    """
+    B, n = G.shape
+    up, dn = alpha < U, alpha > L
+    inf = jnp.asarray(jnp.inf, G.dtype)
+    rows = _end_rows(G, up, dn, min(VERIFY_ROWS, n))
+    take = lambda M: _take_lane(M, rows)
+    g_ex = take(P) - src.exact_rows(rows, alpha)
+    widen = 2.0 * jnp.max(jnp.abs(g_ex - take(G)), axis=1)
+    rest = _set_lane(jnp.ones((B, n), bool), rows, False)
+    e_up = jnp.max(jnp.where(take(up), g_ex, -inf), axis=1)
+    e_dn = jnp.min(jnp.where(take(dn), g_ex, inf), axis=1)
+    g_up = jnp.maximum(e_up, jnp.max(jnp.where(up & rest, G, -inf), axis=1)
+                       + widen)
+    g_dn = jnp.minimum(e_dn, jnp.min(jnp.where(dn & rest, G, inf), axis=1)
+                       - widen)
+    gap = qp_mod.finite_gap(g_up - g_dn)
+    return (_set_lane(G, rows, g_ex), gap, qp_mod.safe_bias(e_up, e_dn),
+            gap <= eps)
 
 
 @partial(jax.jit, static_argnames=("cfg", "impl", "block_l", "doubled",
@@ -328,6 +406,30 @@ def solve_fused_batched_qp(X, P, L, U, gamma,
     leading lane axis; ``iterations`` counts per-lane iterations *until
     that lane converged*.
 
+    **The exit check** holds every lane to LIBSVM's guarantee, a true KKT
+    gap of at most ``cfg.eps``.  The carried gradient G drifts in float32
+    over tens of thousands of rank-2 updates, so a lane whose carried gap
+    reaches eps is only *pending*: it freezes like a done lane, and once
+    every lane is pending or done the trips stop and the pending lanes'
+    gaps are recomputed on exact rows (:func:`_exit_check`: the
+    :data:`VERIFY_ROWS` rows nearest each end by carried G, recomputed as
+    ``p - Q alpha`` by
+    :meth:`~repro.kernels.row_source.RowSource.exact_rows`; unchecked rows
+    count widened by twice the largest correction found).  At most eps,
+    the lane is done, with ``kkt_gap`` that checked gap and ``b`` the
+    midpoint of its exact ends.  Otherwise the exact rows are written into
+    G and the lane *reopens* (counted in ``FusedResult.n_unshrink``): the
+    reopened lanes run another round of trips and are checked again, for
+    at most :data:`VERIFY_ROUNDS` rounds; a lane that fails its last check
+    returns unconverged, with that gap.  The check runs under the named
+    scope ``fused_verify``, on X unpadded, and the rounds follow one
+    another in the program, none inside a loop: the trip loop of the first
+    round, which holds nearly all of the trips, then keeps the padded X
+    and the lane state in the TPU's fast memory, as the engine without the
+    check does (in any loop that also holds a check, the compiler places
+    them in HBM; AOT for v5e).  A cold start's gradient ``P`` is exact, so
+    its gap decides at once; a warm start's ``G0`` is checked.
+
     Two row sources (:mod:`repro.kernels.row_source`):
 
     * default — rows are recomputed from ``X`` inside the kernels (the
@@ -350,10 +452,10 @@ def solve_fused_batched_qp(X, P, L, U, gamma,
     variables that cannot belong to any violating pair are masked out of
     the pass A/B scans via a per-lane (B, n) active mask threaded through
     the kernels.  The gradient update itself is never masked, so G stays
-    exact everywhere and unshrinking is free: a lane whose *masked* gap
+    current everywhere and unshrinking is free: a lane whose *masked* gap
     reaches ``eps`` with a partial mask is reactivated in-loop (counted in
-    ``FusedResult.n_unshrink``) and only declared converged once the gap
-    over the FULL active set passes the check — objectives are identical
+    ``FusedResult.n_unshrink``) and only goes to the exit check once the
+    gap over the FULL active set reaches eps — objectives are identical
     to the unshrunk engine up to selection-order float reassociation.
     Soft shrinking keeps the scans O(n) (masked lanes still ride through
     the kernels); the wall-clock win on CPU/host comes from
@@ -427,9 +529,13 @@ def solve_fused_batched_qp(X, P, L, U, gamma,
     # splits the loop body by scope.
     collect = telemetry is not None
     if bank:
-        src = row_source.bank_source(gram, gram_idx, gamma, dup=doubled)
+        src = vsrc = row_source.bank_source(gram, gram_idx, gamma,
+                                            dup=doubled)
     else:
-        src = row_source.rbf_source(X, gamma, B, dup=doubled)
+        # the exit check reads X unpadded (vsrc): the padded copy then
+        # serves the trips alone, and the TPU compiler keeps it, with the
+        # lane state, in fast memory through their loop
+        src = vsrc = row_source.rbf_source(X, gamma, B, dup=doubled)
         if ops.resolve_impl(impl) != "jnp":
             # X is loop-invariant: pad it once here, not in every trip
             src = ops.pad_source(src, B, block_l, masked=shrinking,
@@ -441,15 +547,7 @@ def solve_fused_batched_qp(X, P, L, U, gamma,
     # gather once, and (b) the two alpha scatters merge into one.
 
     def body(carry):
-        conj = ring = None
-        if collect and conjugate:
-            s, conj, ring = carry
-        elif conjugate:
-            s, conj = carry
-        elif collect:
-            s, ring = carry
-        else:
-            s = carry
+        s, conj, ring = carry
         with jax.named_scope("fused_step"):
             alpha, G = s.alpha, s.G
             idx2 = jnp.concatenate([lanes, lanes])
@@ -460,7 +558,7 @@ def solve_fused_batched_qp(X, P, L, U, gamma,
                 return (_take_lane(alpha, idx), _take_lane(G, idx),
                         _take_lane(L, idx), _take_lane(U, idx))
 
-            active = ~s.done
+            active = ~s.done & ~s.pend
             use_exact = jnp.asarray(planning) & (~s.p_smo) & (~s.prev_ratio_ok)
             act_kw = s.act if shrinking else None
 
@@ -627,14 +725,16 @@ def solve_fused_batched_qp(X, P, L, U, gamma,
                     block_l=block_l, act=act_kw)
         with jax.named_scope("fused_step"):
             gap_new = qp_mod.finite_gap(g_i_next - g_dn)
+            # a lane whose carried gap reaches eps is not done yet: it is
+            # pending, frozen until the round's exit check (see below)
             if shrinking:
-                # a lane only counts as converged when its mask was FULL at the
+                # a lane only goes to the check when its mask was FULL at the
                 # scan that produced the gap; a partial-mask "solved" lane is
-                # unshrunk in place and keeps iterating (G is exact everywhere,
-                # so reactivation costs nothing).
+                # unshrunk in place and keeps iterating (G is updated
+                # everywhere, so reactivation costs nothing).
                 full_now = jnp.all(s.act, axis=1)
                 locally_done = gap_new <= eps
-                done = s.done | (active & locally_done & full_now)
+                newly = active & locally_done & full_now
                 with jax.named_scope("fused_shrink"):
                     refresh = (s.t % period) == (period - 1)
                     act2 = jax.lax.cond(
@@ -642,11 +742,12 @@ def solve_fused_batched_qp(X, P, L, U, gamma,
                         lambda: qp_mod.shrink_mask(G_new, alpha_new, L, U),
                         lambda: s.act)
                     act2 = act2 | (locally_done & ~full_now)[:, None]
-                    act_new = jnp.where((active & ~done)[:, None], act2, s.act)
+                    act_new = jnp.where((active & ~newly)[:, None], act2,
+                                        s.act)
                 n_unshrink = s.n_unshrink + (
                     active & locally_done & ~full_now).astype(jnp.int32)
             else:
-                done = s.done | (gap_new <= eps)
+                newly = active & (gap_new <= eps)
                 act_new = s.act
                 n_unshrink = s.n_unshrink
             gap = jnp.where(active, gap_new, s.gap)
@@ -669,7 +770,7 @@ def solve_fused_batched_qp(X, P, L, U, gamma,
                 i=jnp.where(active, i_next.astype(jnp.int32), s.i),
                 g_i=jnp.where(active, g_i_next, s.g_i),
                 gap=gap, t=s.t + 1, iters=s.iters + active.astype(jnp.int32),
-                done=done,
+                done=s.done,
                 pi=jnp.where(active, i_sel, s.pi).astype(jnp.int32),
                 pj=jnp.where(active, j_sel, s.pj).astype(jnp.int32),
                 qi=jnp.where(active, s.pi, s.qi),
@@ -680,9 +781,10 @@ def solve_fused_batched_qp(X, P, L, U, gamma,
                 prev_free=jnp.where(active, (~do_plan) & free_smo, s.prev_free),
                 prev_ratio_ok=jnp.where(active, ratio_ok, s.prev_ratio_ok),
                 n_planning=s.n_planning + (do_plan & active).astype(jnp.int32),
-                act=act_new, n_unshrink=n_unshrink)
+                act=act_new, n_unshrink=n_unshrink, pend=s.pend | newly,
+                b=s.b)
         if not collect:
-            return (new_s, conj_new) if conjugate else new_s
+            return new_s, (conj_new if conjugate else None), None
         # ---- flight recorder (O(B) only; see repro.telemetry.ring) ---------
         with jax.named_scope("telemetry_ring"):
             if shrinking:
@@ -696,13 +798,16 @@ def solve_fused_batched_qp(X, P, L, U, gamma,
                        else jnp.zeros_like(mu_smo))
             ring = ring_update(
                 ring, telemetry, t=s.t, active=active,
-                newly_done=active & done, gap=gap, n_active=n_act,
+                newly_done=newly, gap=gap, n_active=n_act,
                 n_unshrink=n_unshrink, plan_event=do_plan & active,
                 ratio=ratio_v)
-        return (new_s, conj_new, ring) if conjugate else (new_s, ring)
+        return new_s, (conj_new if conjugate else None), ring
 
     # ---- init ---------------------------------------------------------------
-    if alpha0 is None:
+    # A cold start's gradient is P itself, exact, so its gap decides at
+    # once; a warm start's G0 may have drifted, so its gap is checked.
+    cold = alpha0 is None
+    if cold:
         # grad f(0) = P; alpha = 0 must be feasible (classification, SVR —
         # NOT one-class, whose drivers always pass (alpha0, G0))
         alpha0 = jnp.zeros_like(P)
@@ -715,42 +820,72 @@ def solve_fused_batched_qp(X, P, L, U, gamma,
     v_up = jnp.where(up0, G0, -jnp.inf)
     i0 = jax.lax.argmax(v_up, 1, jnp.int32)
     g_i0 = _take_lane(v_up, i0)
-    gap0 = qp_mod.finite_gap(
-        g_i0 - jnp.min(jnp.where(dn0, G0, jnp.inf), axis=1))
+    g_dn0 = jnp.min(jnp.where(dn0, G0, jnp.inf), axis=1)
+    gap0 = qp_mod.finite_gap(g_i0 - g_dn0)
     zB = jnp.zeros((B,), jnp.int32)
     fB = jnp.zeros((B,), bool)
     act0 = jnp.ones((B, n) if shrinking else (B, 1), bool)
     s0 = _BatchState(alpha=alpha0, G=G0, i=i0, g_i=g_i0, gap=gap0,
                      t=jnp.asarray(0, jnp.int32), iters=zB,
-                     done=gap0 <= eps, pi=zB, pj=zB, qi=zB, qj=zB,
-                     n_hist=zB, p_smo=~fB, prev_free=fB,
+                     done=(gap0 <= eps) if cold else fB, pi=zB, pj=zB,
+                     qi=zB, qj=zB, n_hist=zB, p_smo=~fB, prev_free=fB,
                      prev_ratio_ok=~fB, n_planning=zB,
-                     act=act0, n_unshrink=zB)
+                     act=act0, n_unshrink=zB,
+                     pend=fB if cold else (gap0 <= eps),
+                     b=qp_mod.safe_bias(g_i0, g_dn0))
 
+    def verify(carry):
+        """Exit check of the pending lanes: done where the gap holds on
+        exact rows, else reopened from the corrected gradient."""
+        s, conj, ring = carry
+        with jax.named_scope("fused_verify"):
+            G, gap, b, ok = _exit_check(vsrc, P, L, U, s.alpha, s.G, eps)
+            due, reopen = s.pend, s.pend & ~ok
+            v_up = jnp.where(s.alpha < U, G, -jnp.inf)
+            i = jax.lax.argmax(v_up, 1, jnp.int32)
+            s = s._replace(
+                G=jnp.where(due[:, None], G, s.G),
+                gap=jnp.where(due, gap, s.gap), b=jnp.where(due, b, s.b),
+                done=s.done | (due & ok), pend=fB,
+                i=jnp.where(reopen, i, s.i),
+                g_i=jnp.where(reopen, _take_lane(v_up, i), s.g_i),
+                n_unshrink=s.n_unshrink + reopen.astype(jnp.int32))
+            if conjugate:
+                conj = conj._replace(ok=conj.ok & ~reopen)
+        return s, conj, ring
+
+    def run(carry):
+        """Trips until every lane is pending or done, then the check of
+        the pending lanes (made whether or not one is: behind a
+        ``lax.cond`` the TPU compiler takes alpha out of fast memory for
+        the trips)."""
+        return verify(jax.lax.while_loop(
+            lambda c: (jnp.any(~c[0].done & ~c[0].pend)
+                       & (c[0].t < cfg.max_iter)), body, carry))
+
+    conj0 = ring0 = None
     if conjugate:
         conj0 = _ConjState(u=jnp.zeros((B, n), dtype),
                            ok=jnp.zeros((B,), bool))
-        cond = lambda c: jnp.any(~c[0].done) & (c[0].t < cfg.max_iter)
-        if collect:
-            ring0 = ring_init(telemetry, B, dtype)
-            s, _, ring = jax.lax.while_loop(cond, body, (s0, conj0, ring0))
-        else:
-            s, _ = jax.lax.while_loop(cond, body, (s0, conj0))
-    elif collect:
+    if collect:
         ring0 = ring_init(telemetry, B, dtype)
-        s, ring = jax.lax.while_loop(
-            lambda c: jnp.any(~c[0].done) & (c[0].t < cfg.max_iter),
-            body, (s0, ring0))
-    else:
-        s = jax.lax.while_loop(
-            lambda s: jnp.any(~s.done) & (s.t < cfg.max_iter), body, s0)
+    # The rounds follow one another at the top of the program, never
+    # inside a loop: a trip loop and a check inside one outer loop would
+    # cost the trips the compiler's fast-memory placement of their carry.
+    # Lanes that reopen go round again; a round with no live lane costs
+    # its check alone.
+    carry = (s0, conj0, ring0)
+    for _ in range(VERIFY_ROUNDS):
+        carry = run(carry)
+    s, _, ring = carry
 
     up = s.alpha < U
     dn = s.alpha > L
     g_up = jnp.max(jnp.where(up, s.G, -jnp.inf), axis=1)
     g_dn = jnp.min(jnp.where(dn, s.G, jnp.inf), axis=1)
     res = FusedResult(
-        alpha=s.alpha, b=qp_mod.safe_bias(g_up, g_dn), G=s.G,
+        alpha=s.alpha,
+        b=jnp.where(s.done, s.b, qp_mod.safe_bias(g_up, g_dn)), G=s.G,
         iterations=s.iters,
         objective=0.5 * (jnp.sum(P * s.alpha, axis=1)
                          + jnp.sum(s.G * s.alpha, axis=1)),

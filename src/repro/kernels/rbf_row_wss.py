@@ -49,7 +49,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.ref import HIGHEST
+from repro.kernels.ref import HIGHEST, rbf_exp
 
 TAU = 1e-12
 # lane-dense width of the per-lane running-selection outputs: TPU blocks
@@ -163,7 +163,9 @@ def _kernel_batched(*refs, block_l: int, base_l: int, masked: bool = False,
         q, x, (((1,), (1,)), ((), ())), precision=HIGHEST,
         preferred_element_type=jnp.promote_types(x.dtype, jnp.float32))
     d2 = sqq + sqn_ref[...] - 2.0 * prod                    # (B, BL)
-    k = jnp.exp(-gamma * jnp.maximum(d2, 0.0))
+    # a row that only ranks candidates keeps the hardware exp; the row the
+    # single-lane engine updates G with takes the accurate one
+    k = (rbf_exp if emit_k else jnp.exp)(-gamma * jnp.maximum(d2, 0.0))
     if emit_k:
         refs[11][...] = k.astype(refs[11].dtype)
 

@@ -33,7 +33,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.rbf_row_wss import LANES, compiler_params, fold_max
-from repro.kernels.ref import HIGHEST
+from repro.kernels.ref import HIGHEST, rbf_exp
 
 
 def _fold_min(min_ref, m, first):
@@ -144,7 +144,7 @@ def _kernel_batched(*refs, block_l: int, base_l: int, masked: bool = False,
     sqn = sqn_ref[...]
 
     def row(p, sqq):
-        return jnp.exp(-gamma * jnp.maximum(sqq + sqn - 2.0 * p, 0.0))
+        return rbf_exp(-gamma * jnp.maximum(sqq + sqn - 2.0 * p, 0.0))
 
     k_i = ki_ref[...] if given_ki else row(prod[:bb], sqq_i)
     k_j = row(prod[bb:], sqq_j)

@@ -11,6 +11,35 @@ TAU = 1e-12
 # one bf16 pass, so every distance and decision matmul asks for HIGHEST.
 HIGHEST = jax.lax.Precision.HIGHEST
 
+# The TPU's float32 exp (XLA and Mosaic alike) errs by up to 5e-6 relative
+# and reads low by 8e-7 on average (TPU v5e, against float64).  Summed over
+# the support vectors of K alpha that bias moves G by ~1e-4, a tenth of
+# LIBSVM's eps, so every kernel entry of the fused engine that updates G
+# takes rbf_exp: Cody-Waite reduction z = k ln2 + r, then Cephes' expf
+# polynomial on |r| <= ln2 / 2, about one ulp on every backend.
+_LOG2E = 1.4426950408889634
+_LN2_HI = 0.693145751953125          # 16 bits: k * _LN2_HI is exact
+_LN2_LO = 1.428606765330187e-06
+_EXP_POLY = (1.9875691500e-4, 1.3981999507e-3, 8.3334519073e-3,
+             4.1665795894e-2, 1.6666665459e-1, 5.0000001201e-1)
+
+
+def rbf_exp(z):
+    """``exp(z)`` of RBF exponents ``z <= 0``, to float32's own accuracy
+    on every backend (other dtypes take ``jnp.exp``).  Below -87, where
+    float32 leaves its normal range, it returns exp(-87)."""
+    if z.dtype != jnp.float32:
+        return jnp.exp(z)
+    z = jnp.maximum(z, -87.0)
+    k = jnp.floor(z * _LOG2E + 0.5)
+    r = (z - k * _LN2_HI) - k * _LN2_LO
+    p = r * _EXP_POLY[0] + _EXP_POLY[1]
+    for c in _EXP_POLY[2:]:
+        p = p * r + c
+    scale = jax.lax.bitcast_convert_type(
+        (k.astype(jnp.int32) + 127) << 23, jnp.float32)
+    return ((p * r * r + r) + 1.0) * scale
+
 
 def _act_bool(act):
     """Boolean view of an active-set mask.
@@ -36,7 +65,7 @@ def rbf_row(X, sqn, xq, gamma):
     """k(x_q, X) for one query row."""
     d2 = (jnp.dot(xq, xq, precision=HIGHEST) + sqn
           - 2.0 * jnp.dot(X, xq, precision=HIGHEST))
-    return jnp.exp(-gamma * jnp.maximum(d2, 0.0))
+    return rbf_exp(-gamma * jnp.maximum(d2, 0.0))
 
 
 def rbf_row_wss(X, sqn, G, alpha, L, U, xq, a_i, L_i, U_i, g_i, i_idx,
@@ -99,17 +128,20 @@ def tile_rows(k):
     return jnp.concatenate([k, k], axis=1)
 
 
-def rbf_rows_batched(X, sqn, XQ, sqq, gammas, dup: bool = False):
+def rbf_rows_batched(X, sqn, XQ, sqq, gammas, dup: bool = False,
+                     exp=rbf_exp):
     """k(x_q^b, X) for a batch of query rows -> (B, l).
 
     ``dup=True`` returns the *doubled-operator* rows (B, 2l) used by the
     ε-SVR dual (:func:`tile_rows`): the O(B l d) distance matmul runs
     against the base ``X`` only and the 2l half is a free broadcast —
-    never a 2l-wide matmul, never a 2l x 2l Gram.
+    never a 2l-wide matmul, never a 2l x 2l Gram.  ``exp`` is
+    :func:`rbf_exp` for rows that update G, ``jnp.exp`` for rows that
+    only rank candidates (the batched pass A).
     """
     d2 = (sqq[:, None] + sqn[None, :]
           - 2.0 * jnp.dot(XQ, X.T, precision=HIGHEST))
-    k = jnp.exp(-gammas[:, None] * jnp.maximum(d2, 0.0))
+    k = exp(-gammas[:, None] * jnp.maximum(d2, 0.0))
     return tile_rows(k) if dup else k
 
 
@@ -152,7 +184,7 @@ def rbf_row_wss_batched(X, sqn, G, alpha, L, U, XQ, sqq, a_i, L_i, U_i,
     — the selection algebra is box-general, so the only structural change
     is the tiled row.  Returns (j (B,) int32, gain_j (B,)).
     """
-    k = rbf_rows_batched(X, sqn, XQ, sqq, gammas, dup=dup)
+    k = rbf_rows_batched(X, sqn, XQ, sqq, gammas, dup=dup, exp=jnp.exp)
     return row_wss_batched_from_k(k, G, alpha, L, U, a_i, L_i, U_i, g_i,
                                   i_idx, use_exact, act=act)
 

@@ -32,7 +32,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.ref import HIGHEST
+from repro.kernels.ref import HIGHEST, rbf_exp
 
 
 @functools.partial(
@@ -108,7 +108,7 @@ class RowSource:
         d2 = (jnp.take(self.sqn, a) + jnp.take(self.sqn, b)
               - 2.0 * jnp.sum(jnp.take(self.X, a, axis=0)
                               * jnp.take(self.X, b, axis=0), axis=-1))
-        return jnp.exp(-jnp.tile(self.gammas, reps) * jnp.maximum(d2, 0.0))
+        return rbf_exp(-jnp.tile(self.gammas, reps) * jnp.maximum(d2, 0.0))
 
     def matvec(self, v, block: int = 256):
         """Per-lane operator matvec ``Q_b v_b`` for a (B, n) stack.
@@ -142,7 +142,7 @@ class RowSource:
                 Xb, nb = args
                 d2 = (nb[:, None] + sqn[None, :]
                       - 2.0 * jnp.dot(Xb, X.T, precision=HIGHEST))
-                k = jnp.exp(-self.gammas[:, None, None]
+                k = rbf_exp(-self.gammas[:, None, None]
                             * jnp.maximum(d2, 0.0)[None])    # (B, block, l)
                 return jnp.einsum("bkl,bl->bk", k, v, precision=HIGHEST)
 
@@ -150,6 +150,79 @@ class RowSource:
                                     sp.reshape(-1, block)))
             out = jnp.moveaxis(out, 0, 1).reshape(v.shape[0], -1)[:, :l]
         return jnp.concatenate([out, out], axis=1) if self.dup else out
+
+    def exact_rows(self, rows, v):
+        """Entries ``rows`` (B, m) of each lane's ``Q v`` for a (B, n)
+        stack ``v``, accurate to well below the solver's eps in float32.
+
+        Backs the fused engine's exit check, which recomputes the gradient
+        ``G = p - Q alpha`` of the rows that decide a lane's gap.  The
+        kernel entries take :func:`~repro.kernels.ref.rbf_exp`, and their
+        distances are taken about the column mean of X, which the kernel
+        does not see, so their rounding scales with ``|x - mean|^2``
+        rather than with the raw squared norms.  Each block of
+        :data:`EXACT_BLOCK` columns is contracted at ``HIGHEST`` and the
+        block sums are added with a compensated (two-sum) accumulation, so
+        the error does not grow with l and at most ``m x EXACT_BLOCK``
+        entries of a lane exist at once.  The doubled operator folds the
+        halves of ``v`` as :meth:`matvec` does; a Gram bank reads the rows.
+        """
+        l = self.base_l
+        if self.dup:
+            v = v[:, :l] + v[:, l:]
+        r = self.base_idx(rows)
+        m = r.shape[1]
+        if self.is_bank:
+            def lane_rows(K, v):
+                return _block_sum(
+                    lambda c, w: jax.lax.dynamic_slice_in_dim(K, c, w, 1),
+                    v, m)
+            return jax.vmap(lambda k, r, v: lane_rows(self.gram[k][r], v))(
+                self.gram_idx, r, v)
+        mean = jnp.sum(self.X, axis=0) / l    # padded rows are zero rows
+
+        def lane_rows(r, v, gamma):
+            xq = jnp.take(self.X, r, axis=0) - mean
+            sqq = jnp.sum(xq * xq, axis=-1)
+
+            def kblock(c, w):
+                xb = jax.lax.dynamic_slice_in_dim(self.X, c, w, 0) - mean
+                d2 = (sqq[:, None] + jnp.sum(xb * xb, axis=-1)[None, :]
+                      - 2.0 * jnp.dot(xq, xb.T, precision=HIGHEST))
+                return rbf_exp(-gamma * jnp.maximum(d2, 0.0))
+
+            return _block_sum(kblock, v, m)
+
+        return jax.vmap(lane_rows)(r, v, self.gammas)
+
+
+EXACT_BLOCK = 1024     # columns per block of an exact row sum
+
+
+def _block_sum(kblock, v, m: int):
+    """``sum_j kblock_j * v_j`` for each of ``m`` rows: blocks of
+    :data:`EXACT_BLOCK` columns (``kblock(c, w)`` gives the (m, w) entries
+    of columns ``[c, c + w)``, ``w`` static), each contracted at
+    ``HIGHEST``, their sums added with Knuth's two-sum and the rounding
+    errors carried apart."""
+    w = min(EXACT_BLOCK, v.shape[0])
+    nb, tail = divmod(v.shape[0], w)
+
+    def add(c, w_, acc):
+        s, e = acc
+        part = jnp.dot(kblock(c, w_), jax.lax.dynamic_slice_in_dim(v, c, w_),
+                       precision=HIGHEST)
+        t = s + part
+        bb = t - s
+        return t, e + ((s - (t - bb)) + (part - bb))
+
+    zero = jnp.zeros((m,), v.dtype)
+    acc = jax.lax.fori_loop(
+        jnp.int32(0), jnp.int32(nb),
+        lambda b, acc: add(b * jnp.int32(w), w, acc), (zero, zero))
+    if tail:
+        acc = add(nb * w, tail, acc)
+    return acc[0] + acc[1]
 
 
 def rbf_source(X, gammas, B: int, *, dup: bool = False) -> RowSource:

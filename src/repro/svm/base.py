@@ -75,9 +75,13 @@ class SVMEstimatorBase:
 
     @staticmethod
     def _hold_counters(sp, res) -> None:
-        """Keep the engine's counters on the fit's root span, unread."""
+        """Keep the engine's counters on the fit's root span, unread:
+        ``n_unshrink`` (reactivations, exit-check reopenings among them)
+        where the engine reports it."""
+        extra = ({} if getattr(res, "n_unshrink", None) is None
+                 else {"n_unshrink": res.n_unshrink})
         sp.hold(iterations=res.iterations, n_planning=res.n_planning,
-                converged=res.converged)
+                converged=res.converged, **extra)
 
     def _config(self) -> SolverConfig:
         return SolverConfig(algorithm=self.algorithm, step=self.step,

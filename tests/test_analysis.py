@@ -14,6 +14,7 @@ import subprocess
 import sys
 
 from repro.analysis import jaxpr_audit, lint_rules, recompile_guard
+from repro.core.solver_fused import VERIFY_ROUNDS
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -62,7 +63,9 @@ def test_census_artifact_schema(tmp_path):
     with open(paths[0]) as fh:
         payload = json.load(fh)
     assert payload["entry"] == "plain_jnp"
-    assert payload["primitives"].get("while") == 1
+    # each round of the engine: its trip loop, and the exit check's row
+    # pick and blocked row sum
+    assert payload["primitives"].get("while") == 3 * VERIFY_ROUNDS
     assert payload["carries"] and payload["dtypes"]
 
 

@@ -126,7 +126,8 @@ def test_engine_counters_stay_unread_until_asked():
     clf = _fit(X, y)
     root = recent(roots=True)[-1]
     raw = root._held
-    assert set(raw) == {"iterations", "n_planning", "converged"}
+    assert set(raw) == {"iterations", "n_planning", "converged",
+                        "n_unshrink"}
     # references to the engine's own device arrays, not host copies
     assert all(isinstance(v, jax.Array) for v in raw.values())
     assert raw["iterations"] is clf.fit_result_.iterations
@@ -137,6 +138,8 @@ def test_engine_counters_stay_unread_until_asked():
     np.testing.assert_array_equal(held["n_planning"],
                                   np.asarray(clf.fit_result_.n_planning))
     assert held["n_planning"].sum() > 0       # pasmo plans on this problem
+    np.testing.assert_array_equal(held["n_unshrink"],
+                                  np.asarray(clf.fit_result_.n_unshrink))
 
 
 def test_phase_scope_is_a_span_plus_the_phase_event():
