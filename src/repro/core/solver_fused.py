@@ -422,12 +422,12 @@ def solve_fused_batched_qp(X, P, L, U, gamma,
     reopened lanes run another round of trips and are checked again, for
     at most :data:`VERIFY_ROUNDS` rounds; a lane that fails its last check
     returns unconverged, with that gap.  The check runs under the named
-    scope ``fused_verify``, on X unpadded, and the rounds follow one
+    scope ``fused_verify``, on the row-major X, and the rounds follow one
     another in the program, none inside a loop: the trip loop of the first
-    round, which holds nearly all of the trips, then keeps the padded X
-    and the lane state in the TPU's fast memory, as the engine without the
+    round, which holds nearly all of the trips, then keeps the lane state
+    in the TPU's fast memory, as the engine without the
     check does (in any loop that also holds a check, the compiler places
-    them in HBM; AOT for v5e).  A cold start's gradient ``P`` is exact, so
+    it in HBM; AOT for v5e).  A cold start's gradient ``P`` is exact, so
     its gap decides at once; a warm start's ``G0`` is checked.
 
     Two row sources (:mod:`repro.kernels.row_source`):
@@ -529,15 +529,12 @@ def solve_fused_batched_qp(X, P, L, U, gamma,
     # splits the loop body by scope.
     collect = telemetry is not None
     if bank:
-        src = vsrc = row_source.bank_source(gram, gram_idx, gamma,
-                                            dup=doubled)
+        src = row_source.bank_source(gram, gram_idx, gamma, dup=doubled)
     else:
-        # the exit check reads X unpadded (vsrc): the padded copy then
-        # serves the trips alone, and the TPU compiler keeps it, with the
-        # lane state, in fast memory through their loop
-        src = vsrc = row_source.rbf_source(X, gamma, B, dup=doubled)
+        src = row_source.rbf_source(X, gamma, B, dup=doubled)
         if ops.resolve_impl(impl) != "jnp":
-            # X is loop-invariant: pad it once here, not in every trip
+            # X is loop-invariant: build the passes' feature-major stream
+            # once here, not in every trip
             src = ops.pad_source(src, B, block_l, masked=shrinking,
                                  conj=conjugate)
 
@@ -839,7 +836,7 @@ def solve_fused_batched_qp(X, P, L, U, gamma,
         exact rows, else reopened from the corrected gradient."""
         s, conj, ring = carry
         with jax.named_scope("fused_verify"):
-            G, gap, b, ok = _exit_check(vsrc, P, L, U, s.alpha, s.G, eps)
+            G, gap, b, ok = _exit_check(src, P, L, U, s.alpha, s.G, eps)
             due, reopen = s.pend, s.pend & ~ok
             v_up = jnp.where(s.alpha < U, G, -jnp.inf)
             i = jax.lax.argmax(v_up, 1, jnp.int32)

@@ -9,11 +9,12 @@
 
 All wrappers pad the lane state's example dimension to the block
 multiple with *inert* rows (L = U = 0 so they can never be selected; see
-sharded.py for the same trick).  The shared X and its row norms are padded
-to the block multiple and X's feature dimension to a lane multiple for the
-MXU — by the fused engine, once per call before its while loop
-(:func:`pad_source`), so the batched wrappers get X already padded and
-pass it through; a caller that hands them raw X has it padded per call.
+sharded.py for the same trick).  The passes read the shared X
+*feature-major*: :func:`pad_source` is the one builder of that stream,
+X padded to the block multiple of examples and a sublane multiple (8) of
+features, then transposed to (d8, lpad), with its row norms padded
+alike.  The fused engine builds it once per call, before its while loop;
+a wrapper handed raw X builds it per call through the same function.
 The block sizes come from the shapes (:func:`plan_tiles`): the caller's
 ``block_l`` is an upper bound that shrinks — and the lane batch splits
 into blocks — until one grid step's working set fits the VMEM budget.
@@ -105,18 +106,18 @@ class Tiles(NamedTuple):
 
 
 def vmem_bytes(bb: int, bl: int, *, H: int, stacks: int, rows: int,
-               dpad: int, itemsize: int = 4) -> int:
+               d: int, itemsize: int = 4) -> int:
     """Estimated VMEM bytes of one grid step of a batched pass.
 
     ``stacks`` counts the (H, bb, bl) lane-state tiles in and out (G,
     alpha, L, U, optional act, pass B's G_out); ``rows`` the (bb, bl)
     row tiles (gathered kernel rows, the Conjugate-SMO direction in and
-    out); ``dpad`` is the padded feature width of the X tile and the
-    query rows (0 for the Gram-bank passes).
+    out); ``d`` is the feature count (0 for the Gram-bank passes): the X
+    tile is (d8, bl), the query rows (bb, d8) take whole 128-lane rows.
     """
     blocks = (stacks * H * bb * bl + rows * bb * bl
-              + bl * dpad + _SUBLANES * bl            # X tile, sqn row
-              + 2 * bb * dpad + 6 * bb * LANES)       # query rows, scalars
+              + _d8(d) * bl + _SUBLANES * bl          # X tile, sqn row
+              + 2 * bb * _dpad(d) + 6 * bb * LANES)   # query rows, scalars
     return itemsize * (2 * blocks + 2 * bb * bl)
 
 
@@ -143,21 +144,48 @@ def plan_tiles(B: int, l: int, block_l: int, **footprint) -> Tiles:
 
 
 def _dpad(d: int) -> int:
-    return _round_up(d, 128)
+    return _round_up(d, LANES)
+
+
+def _d8(d: int) -> int:
+    return _round_up(d, _SUBLANES)
 
 
 def pass_a_tiles(B, l, d, block_l, *, H=1, masked=False, rows=False):
     """:func:`plan_tiles` for pass A (``rows``: the Gram-bank variant)."""
     return plan_tiles(B, l, block_l, H=H, stacks=4 + masked,
-                      rows=int(rows), dpad=0 if rows else _dpad(d))
+                      rows=int(rows), d=0 if rows else d)
 
 
 def pass_b_tiles(B, l, d, block_l, *, H=1, masked=False, conj=False,
                  rows=False):
     """:func:`plan_tiles` for pass B (``rows``: the Gram-bank variant)."""
     return plan_tiles(B, l, block_l, H=H, stacks=5 + masked,
-                      rows=2 * conj + 2 * rows,
-                      dpad=0 if rows else _dpad(d))
+                      rows=2 * conj + 2 * rows, d=0 if rows else d)
+
+
+def pad_source(src: RowSource, B: int, block_l: int, *,
+               masked: bool = False, conj: bool = False) -> RowSource:
+    """The rbf row source with the stream both passes read.
+
+    ``src.xt`` is X feature-major, (d8, lpad): X's rows padded to the
+    larger ``lpad`` that pass A and pass B plan for ``B`` lanes
+    (``masked``: shrinking's active mask, ``conj``: Conjugate-SMO), its
+    features to a sublane multiple, zeros in both, then transposed;
+    ``src.xt_sqn`` holds the norms padded to ``lpad``.  Zero features add
+    nothing to a distance.  ``src.X``/``src.sqn`` stay as they are.
+    Called before the engine's while loop, it leaves the loop body a
+    loop-invariant stream, so no trip pads or transposes X.
+    """
+    l, d = src.X.shape
+    H = 2 if src.dup else 1
+    lpad = max(pass_a_tiles(B, l, d, block_l, H=H, masked=masked).lpad,
+               pass_b_tiles(B, l, d, block_l, H=H, masked=masked,
+                            conj=conj).lpad)
+    with jax.named_scope("x_pad"):
+        xt = _pad_d(_pad_l(src.X, lpad), _d8(d)).T
+        xt_sqn = _pad_l(src.sqn, lpad)
+    return dataclasses.replace(src, xt=xt, xt_sqn=xt_sqn)
 
 
 # ---------------------------------------------------------------------------
@@ -179,16 +207,17 @@ def rbf_row_wss(X, sqn, G, alpha, L, U, xq, a_i, L_i, U_i, g_i, i_idx,
     if impl == "jnp":
         return ref_ops.rbf_row_wss(X, sqn, G, alpha, L, U, xq, a_i, L_i,
                                    U_i, g_i, i_idx, use_exact, gamma)
-    lpad, dpad = _round_up(l, block_l), _dpad(d)
+    src = pad_source(RowSource(X=X, sqn=sqn), 1, block_l)
+    t = pass_a_tiles(1, l, d, block_l)
     dtype = X.dtype
     scal = jnp.stack([jnp.dot(xq, xq, precision=ref_ops.HIGHEST),
                       jnp.asarray(gamma, dtype), a_i, L_i,
                       U_i, g_i, use_exact.astype(dtype)]).reshape(1, 7)
     j, gain, k = rbf_row_wss_batched_pallas(
-        _pad_d(_pad_l(X, lpad), dpad), _pad_l(sqn, lpad), _row1(G, lpad),
-        _row1(alpha, lpad), _row1(L, lpad), _row1(U, lpad),
-        _pad_d(xq, dpad).reshape(1, dpad), scal.astype(dtype),
-        _iscal(i_idx, 1), block_l=block_l,
+        src.xt, src.xt_sqn, _row1(G, t.lpad), _row1(alpha, t.lpad),
+        _row1(L, t.lpad), _row1(U, t.lpad),
+        _pad_d(xq, _d8(d)).reshape(1, -1), scal.astype(dtype),
+        _iscal(i_idx, 1), block_l=t.block_l,
         interpret=(impl == "interpret"), base_l=l, emit_k=True)
     return k[0, :l], j[0], gain[0]
 
@@ -201,18 +230,19 @@ def rbf_update_wss(X, sqn, G, k_i, alpha_new, L, U, xq_j, mu, gamma,
     if impl == "jnp":
         return ref_ops.rbf_update_wss(X, sqn, G, k_i, xq_j, mu, alpha_new,
                                       L, U, gamma)
-    lpad, dpad = _round_up(l, block_l), _dpad(d)
+    src = pad_source(RowSource(X=X, sqn=sqn), 1, block_l)
+    t = pass_b_tiles(1, l, d, block_l)
     dtype = X.dtype
-    xq = jnp.broadcast_to(_pad_d(xq_j, dpad), (2, dpad))   # (i, j) rows
+    xq = jnp.broadcast_to(_pad_d(xq_j, _d8(d)), (2, _d8(d)))  # (i, j) rows
     scal = jnp.stack([jnp.zeros((), dtype),
                       jnp.dot(xq_j, xq_j, precision=ref_ops.HIGHEST),
                       jnp.asarray(mu, dtype),
                       jnp.asarray(gamma, dtype)]).reshape(1, 4)
     G_new, i_next, g_i_next, g_dn = rbf_update_wss_batched_pallas(
-        _pad_d(_pad_l(X, lpad), dpad), _pad_l(sqn, lpad), _row1(G, lpad),
-        _row1(alpha_new, lpad), _row1(L, lpad), _row1(U, lpad), xq,
-        scal.astype(dtype), ki=_pad_l(k_i, lpad).reshape(1, lpad),
-        block_l=block_l, interpret=(impl == "interpret"), base_l=l)
+        src.xt, src.xt_sqn, _row1(G, t.lpad), _row1(alpha_new, t.lpad),
+        _row1(L, t.lpad), _row1(U, t.lpad), xq, scal.astype(dtype),
+        ki=_pad_l(k_i, t.lpad).reshape(1, t.lpad), block_l=t.block_l,
+        interpret=(impl == "interpret"), base_l=l)
     return G_new[0, 0, :l], i_next[0], g_i_next[0], g_dn[0]
 
 
@@ -267,83 +297,69 @@ def _state_stacks(t: Tiles, H: int, act, *state):
     return stacks, act_st
 
 
-def _pad_x(X, sqn, lpad: int):
-    """The shared X and its row norms at ``lpad`` rows, X lane-padded.
-
-    An X that already has at least ``lpad`` rows and a lane-multiple width
-    (:func:`pad_source`) passes through untouched: the kernels' grid reads
-    ``lpad`` rows of it.  The norms are cut to exactly ``lpad``.
-    """
-    with jax.named_scope("x_pad"):
-        if X.shape[0] < lpad:
-            X = _pad_l(X, lpad)
-        X = _pad_d(X, _dpad(X.shape[1]))
-        sqn = sqn[:lpad] if sqn.shape[0] > lpad else _pad_l(sqn, lpad)
-        return X, sqn
-
-
-def pad_source(src: RowSource, B: int, block_l: int, *,
-               masked: bool = False, conj: bool = False) -> RowSource:
-    """The rbf row source with X and its row norms padded once.
-
-    Rows go to the larger ``lpad`` that pass A and pass B plan for ``B``
-    lanes (``masked``: shrinking's active mask, ``conj``: Conjugate-SMO),
-    columns to a lane multiple; the true example count rides along as
-    ``src.l``.  Called before the engine's while loop, it leaves the loop
-    body a loop-invariant padded X, so neither batched pass pads it again.
-    """
-    l, d = src.X.shape
-    H = 2 if src.dup else 1
-    lpad = max(pass_a_tiles(B, l, d, block_l, H=H, masked=masked).lpad,
-               pass_b_tiles(B, l, d, block_l, H=H, masked=masked,
-                            conj=conj).lpad)
-    X, sqn = _pad_x(src.X, src.sqn, lpad)
-    return dataclasses.replace(src, X=X, sqn=sqn, l=l)
-
-
 def rbf_row_wss_batched(X, sqn, G, alpha, L, U, XQ, sqq, a_i, L_i, U_i,
                         g_i, i_idx, use_exact, gammas, *, impl: str = "auto",
                         block_l: int = 1024, dup: bool = False, act=None):
     """Batched pass A: per-lane WSS2 selection, returns (j (B,), gain (B,)).
 
-    ``X``/``sqn`` are shared, raw or already padded (:func:`pad_source`;
-    the true example count is read off the (B, n) lane state);
-    ``G``/``alpha``/``L``/``U`` are (B, n); ``XQ`` is the (B, d) gathered
-    *base* query rows; the rest are (B,) per-lane scalars.  ``dup=True``
-    runs the doubled ε-SVR operator (n = 2l over base ``X``/``sqn``): the
-    jnp oracle computes the base (B, l) row and tiles it; the Pallas path
-    stacks the lane state into (2, B, lpad) halves and the kernel reads
-    the base row tile twice — the matmul never widens past l.  ``act`` is an optional (B, n) active-set mask (soft
-    shrinking: restricts the j-scan only).
+    ``X``/``sqn`` are the shared (l, d) examples and their norms, which
+    the Pallas path streams through :func:`pad_source` per call (the
+    fused engine builds that stream once and calls
+    :func:`source_row_wss`); ``G``/``alpha``/``L``/``U`` are (B, n);
+    ``XQ`` is the (B, d) gathered *base* query rows; the rest are (B,)
+    per-lane scalars.  ``dup=True`` runs the doubled ε-SVR operator
+    (n = 2l over base ``X``/``sqn``): the jnp oracle computes the base
+    (B, l) row and tiles it; the Pallas path stacks the lane state into
+    (2, B, lpad) halves and the kernel reads the base row tile twice —
+    the matmul never widens past l.  ``act`` is an optional (B, n)
+    active-set mask (soft shrinking: restricts the j-scan only).
     """
+    return _rbf_row_wss(RowSource(X=X, sqn=sqn, gammas=gammas, dup=dup),
+                        G, alpha, L, U, XQ, sqq, a_i, L_i, U_i, g_i, i_idx,
+                        use_exact, impl=impl, block_l=block_l, act=act)
+
+
+def _rbf_row_wss(src, G, alpha, L, U, XQ, sqq, a_i, L_i, U_i, g_i, i_idx,
+                 use_exact, *, impl, block_l, act):
+    """Batched pass A against an rbf row source: the jnp oracle on
+    ``src.X``, or the Pallas kernel on its stream (built here if ``src``
+    comes without one)."""
     impl = resolve_impl(impl)
     if impl == "jnp":
-        return ref_ops.rbf_row_wss_batched(X, sqn, G, alpha, L, U, XQ, sqq,
-                                           a_i, L_i, U_i, g_i, i_idx,
-                                           use_exact, gammas, dup=dup,
-                                           act=act)
-    H = 2 if dup else 1
-    B, l, d = G.shape[0], G.shape[1] // H, X.shape[1]
+        return ref_ops.rbf_row_wss_batched(src.X, src.sqn, G, alpha, L, U,
+                                           XQ, sqq, a_i, L_i, U_i, g_i,
+                                           i_idx, use_exact, src.gammas,
+                                           dup=src.dup, act=act)
+    H = 2 if src.dup else 1
+    B, (l, d) = G.shape[0], src.X.shape
+    if src.xt is None:
+        src = pad_source(src, B, block_l, masked=act is not None)
     t = pass_a_tiles(B, l, d, block_l, H=H, masked=act is not None)
-    dtype = X.dtype
-    scal = jnp.stack([sqq, jnp.broadcast_to(gammas, (B,)),
+    dtype = src.xt.dtype
+    scal = jnp.stack([sqq, jnp.broadcast_to(src.gammas, (B,)),
                       a_i, L_i, U_i, g_i,
                       use_exact.astype(dtype)], axis=1).astype(dtype)
     stacks, act_st = _state_stacks(t, H, act, G, alpha, L, U)
-    Xp, sqnp = _pad_x(X, sqn, t.lpad)
     j, gain = rbf_row_wss_batched_pallas(
-        Xp, sqnp, *stacks,
-        _pad_b(_pad_d(XQ, _dpad(d)), t.bpad), _pad_b(scal, t.bpad),
+        src.xt, src.xt_sqn, *stacks,
+        _pad_queries(XQ, t.bpad), _pad_b(scal, t.bpad),
         _pad_b(_iscal(i_idx, B), t.bpad), act_st, block_l=t.block_l,
         block_b=t.block_b, interpret=(impl == "interpret"), base_l=l)
     return j[:B], gain[:B]
 
 
+def _pad_queries(XQ, bpad: int):
+    """(B, d) query rows -> (bpad, d8), zeros in the new rows and columns
+    (one pad)."""
+    B, d = XQ.shape
+    return jnp.pad(XQ, ((0, bpad - B), (0, _d8(d) - d)))
+
+
 def _stack_queries(XQi, XQj, t: Tiles):
-    """(B, d) i and j query rows -> the (2 bpad, d) pass B layout: per
+    """(B, d) i and j query rows -> the (2 bpad, d8) pass B layout: per
     lane block, its i rows then its j rows."""
     n_c = t.bpad // t.block_b
-    qi, qj = (_pad_b(q, t.bpad).reshape(n_c, t.block_b, -1)
+    qi, qj = (_pad_queries(q, t.bpad).reshape(n_c, t.block_b, -1)
               for q in (XQi, XQj))
     return jnp.concatenate([qi, qj], axis=1).reshape(2 * t.bpad, -1)
 
@@ -378,20 +394,31 @@ def rbf_update_wss_batched(X, sqn, G, alpha_new, L, U, XQi, sqqi, XQj, sqqj,
     ``r = k_i - k_j`` (at full lane-state width) — see
     :func:`repro.kernels.ref.update_wss_batched_from_rows`.
     """
+    return _rbf_update_wss(RowSource(X=X, sqn=sqn, gammas=gammas, dup=dup),
+                           G, alpha_new, L, U, XQi, sqqi, XQj, sqqj, mu,
+                           impl=impl, block_l=block_l, act=act, dirv=dirv,
+                           mu2=mu2)
+
+
+def _rbf_update_wss(src, G, alpha_new, L, U, XQi, sqqi, XQj, sqqj, mu, *,
+                    impl, block_l, act, dirv, mu2):
+    """Batched pass B against an rbf row source, as :func:`_rbf_row_wss`."""
     impl = resolve_impl(impl)
     if impl == "jnp":
-        return ref_ops.rbf_update_wss_batched(X, sqn, G, alpha_new, L, U,
-                                              XQi, sqqi, XQj, sqqj, mu,
-                                              gammas, dup=dup, act=act,
-                                              dirv=dirv, mu2=mu2)
-    H = 2 if dup else 1
-    B, l, d = G.shape[0], G.shape[1] // H, X.shape[1]
+        return ref_ops.rbf_update_wss_batched(src.X, src.sqn, G, alpha_new,
+                                              L, U, XQi, sqqi, XQj, sqqj,
+                                              mu, src.gammas, dup=src.dup,
+                                              act=act, dirv=dirv, mu2=mu2)
+    H = 2 if src.dup else 1
+    B, (l, d) = G.shape[0], src.X.shape
     conj = dirv is not None
+    if src.xt is None:
+        src = pad_source(src, B, block_l, masked=act is not None, conj=conj)
     t = pass_b_tiles(B, l, d, block_l, H=H, masked=act is not None,
                      conj=conj)
-    dtype = X.dtype
+    dtype = src.xt.dtype
     cols = [sqqi, sqqj, jnp.broadcast_to(mu, (B,)),
-            jnp.broadcast_to(gammas, (B,))]
+            jnp.broadcast_to(src.gammas, (B,))]
     # the doubled operator's direction rows are half-symmetric (tiled base
     # rows), so the kernels carry the base half only
     dirv_row = None
@@ -400,13 +427,11 @@ def rbf_update_wss_batched(X, sqn, G, alpha_new, L, U, XQi, sqqi, XQj, sqqj,
         dirv_row = _pad_bl(dirv[:, :l].astype(dtype), t.bpad, t.lpad)
     scal = jnp.stack(cols, axis=1).astype(dtype)
     stacks, act_st = _state_stacks(t, H, act, G, alpha_new, L, U)
-    Xp, sqnp = _pad_x(X, sqn, t.lpad)
     out = rbf_update_wss_batched_pallas(
-        Xp, sqnp, *stacks,
-        _stack_queries(_pad_d(XQi, _dpad(d)), _pad_d(XQj, _dpad(d)), t),
-        _pad_b(scal, t.bpad), act_st, dirv_row, block_l=t.block_l, block_b=t.block_b,
-        interpret=(impl == "interpret"), base_l=l)
-    return _pass_b_result(out, B, l, dup, conj)
+        src.xt, src.xt_sqn, *stacks, _stack_queries(XQi, XQj, t),
+        _pad_b(scal, t.bpad), act_st, dirv_row, block_l=t.block_l,
+        block_b=t.block_b, interpret=(impl == "interpret"), base_l=l)
+    return _pass_b_result(out, B, l, src.dup, conj)
 
 
 def row_wss_batched_rows(KR, G, alpha, L, U, a_i, L_i, U_i, g_i, i_idx,
@@ -484,7 +509,10 @@ def source_row_wss(src: RowSource, G, alpha, L, U, i_idx, a_i, L_i, U_i,
     """Batched pass A against any :class:`~repro.kernels.row_source.RowSource`.
 
     ``act`` is an optional (B, n) active-set mask (soft shrinking).
-    Returns (j (B,), gain (B,)) — the per-lane WSS2 selection.
+    Returns (j (B,), gain (B,)) — the per-lane WSS2 selection.  An rbf
+    source read by a Pallas backend streams ``src.xt``
+    (:func:`pad_source`, which the fused engine calls once, before its
+    loop); a source without it has the stream built for this call.
     """
     if src.is_bank:
         KR = src.query(i_idx).astype(G.dtype)
@@ -492,10 +520,9 @@ def source_row_wss(src: RowSource, G, alpha, L, U, i_idx, a_i, L_i, U_i,
                                     i_idx, use_exact, impl=impl,
                                     block_l=block_l, dup=src.dup, act=act)
     XQ, sqq = src.query(i_idx)
-    return rbf_row_wss_batched(src.X, src.sqn, G, alpha, L, U, XQ, sqq,
-                               a_i, L_i, U_i, g_i, i_idx, use_exact,
-                               src.gammas, impl=impl, block_l=block_l,
-                               dup=src.dup, act=act)
+    return _rbf_row_wss(src, G, alpha, L, U, XQ, sqq, a_i, L_i, U_i, g_i,
+                        i_idx, use_exact, impl=impl, block_l=block_l,
+                        act=act)
 
 
 def source_update_wss(src: RowSource, G, alpha_new, L, U, i_idx, j_idx, mu,
@@ -522,10 +549,9 @@ def source_update_wss(src: RowSource, G, alpha_new, L, U, i_idx, j_idx, mu,
                                        block_l=block_l, dup=src.dup,
                                        act=act, dirv=dirv, mu2=mu2)
     XQ, sqq = src.query(stacked)
-    return rbf_update_wss_batched(src.X, src.sqn, G, alpha_new, L, U,
-                                  XQ[:B], sqq[:B], XQ[B:], sqq[B:], mu,
-                                  src.gammas, impl=impl, block_l=block_l,
-                                  dup=src.dup, act=act, dirv=dirv, mu2=mu2)
+    return _rbf_update_wss(src, G, alpha_new, L, U, XQ[:B], sqq[:B], XQ[B:],
+                           sqq[B:], mu, impl=impl, block_l=block_l, act=act,
+                           dirv=dirv, mu2=mu2)
 
 
 def gram(X1, X2=None, gamma=1.0, *, impl: str = "auto",

@@ -5,12 +5,15 @@ dimension in blocks of BL (a multiple of 128 so the lane dimension is
 hardware-aligned); the outer axis splits the lane batch into blocks of
 ``block_b`` lanes when one VMEM working set cannot hold them all (the ops
 wrapper sizes both from the shapes, see :func:`repro.kernels.ops.plan_tiles`).
-Per grid step the VMEM working set is one (BL, d) tile of X plus the
+Per grid step the VMEM working set is one (d8, BL) tile of X plus the
 (H, block_b, BL) lane-state tiles.
 
-The (block_b, d) x (d, BL) matmul runs on the MXU (d padded to a multiple
-of 128 by the ops wrapper); the gain algebra and the masked argmax run on
-the VPU in the same pass, so G, alpha, L, U are read from HBM exactly once
+X streams *feature-major*: the kernels read ``Xt``, X transposed to
+(d8, lpad) with d padded to a sublane multiple (8), which is all the
+(8, 128) HBM tiling pads a major axis to; a row-major (l, d) X would be
+stored, and read, lane-padded to a multiple of 128 columns.  The
+(block_b, d8) x (d8, BL) matmul runs on the MXU; the gain algebra and
+the masked argmax run on the VPU in the same pass, so G, alpha, L, U are read from HBM exactly once
 and the gains are never materialized to HBM.  The per-lane selection is
 reduced *in the kernel*: a running (max, argmax) pair lives in a
 lane-dense (block_b, 128) output block that stays resident in VMEM across
@@ -22,7 +25,7 @@ boxes (classification, class-weighted, ε-SVR doubled, one-class lanes all
 look identical from here); only the RBF ``diag == 1`` identity is
 specialized.  Row sources (see :mod:`repro.kernels.row_source`):
 
-* **rbf** — the (B, d) x (d, BL) matmul against the shared X tile;
+* **rbf** — the (B, d8) x (d8, BL) matmul against the shared X tile;
 * **doubled rbf** (``H = 2`` state halves) — the ε-SVR operator: the lane
   state arrives as an (2, B, lpad) stack of the two variable halves, the
   base row tile is computed ONCE per grid step and the selection algebra
@@ -138,10 +141,10 @@ def _select_from_k(k, G, alpha, L, U, scal, i_idx, b, *, block_l: int,
 
 def _kernel_batched(*refs, block_l: int, base_l: int, masked: bool = False,
                     emit_k: bool = False):
-    """Lane-batched pass A (rbf row source): every lane shares the (BL, d)
-    X tile.
+    """Lane-batched pass A (rbf row source): every lane shares the
+    (d8, BL) tile of the feature-major X.
 
-    The B query rows hit the tile as ONE (B, d) x (d, BL) MXU matmul; the
+    The B query rows hit the tile as ONE (B, d8) x (d8, BL) MXU matmul; the
     per-lane gain algebra and masked argmax run on the VPU over (B, BL)
     registers.  The batched engine writes no k-row back — its pass B
     recomputes it, trading one extra matmul for an HBM round-trip of
@@ -151,17 +154,17 @@ def _kernel_batched(*refs, block_l: int, base_l: int, masked: bool = False,
     the j-scan (soft shrinking).
     """
     act_ref, refs = (refs[0], refs[1:]) if masked else (None, refs)
-    (xq_ref, scal_ref, iscal_ref, X_ref, sqn_ref, G_ref, alpha_ref,
+    (xq_ref, scal_ref, iscal_ref, Xt_ref, sqn_ref, G_ref, alpha_ref,
      L_ref, U_ref, bmax_out, barg_out) = refs[:11]
     b = pl.program_id(1)
     sqq = scal_ref[:, 0:1]
     gamma = scal_ref[:, 1:2]
 
-    x = X_ref[...]                      # (BL, d) shared tile
-    q = xq_ref[...]                     # (B, d) per-lane query rows
-    prod = jax.lax.dot_general(
-        q, x, (((1,), (1,)), ((), ())), precision=HIGHEST,
-        preferred_element_type=jnp.promote_types(x.dtype, jnp.float32))
+    x = Xt_ref[...]                     # (d8, BL) shared tile
+    q = xq_ref[...]                     # (B, d8) per-lane query rows
+    prod = jnp.dot(q, x, precision=HIGHEST,
+                   preferred_element_type=jnp.promote_types(x.dtype,
+                                                            jnp.float32))
     d2 = sqq + sqn_ref[...] - 2.0 * prod                    # (B, BL)
     # a row that only ranks candidates keeps the hardware exp; the row the
     # single-lane engine updates G with takes the accurate one
@@ -201,15 +204,18 @@ def _selection_out(B: int, block_b: int, dtype):
 
 @functools.partial(jax.jit, static_argnames=("block_l", "block_b",
                                              "interpret", "base_l", "emit_k"))
-def rbf_row_wss_batched_pallas(X, sqn, G, alpha, L, U, XQ, scalars,
+def rbf_row_wss_batched_pallas(Xt, sqn, G, alpha, L, U, XQ, scalars,
                                iscalars, act=None, *, block_l: int = 1024,
                                block_b: int | None = None,
                                interpret: bool = False, base_l: int = 0,
                                emit_k: bool = False):
-    """Launch lane-batched pass A.  ``G``/``alpha``/``L``/``U`` are
+    """Launch lane-batched pass A.  ``Xt`` is the feature-major X,
+    (d8, >= lpad) with zero padding (:func:`repro.kernels.ops.pad_source`),
+    and ``sqn`` its column norms, (>= lpad,); the grid reads their first
+    lpad columns.  ``G``/``alpha``/``L``/``U`` are
     (H, B, lpad) stacks of the variable halves (H = 1 plain, H = 2 the
     doubled ε-SVR operator) with both trailing dims padded by the ops
-    wrapper; ``XQ`` is (B, d) *base* query rows; ``scalars`` is the packed
+    wrapper; ``XQ`` is (B, d8) *base* query rows; ``scalars`` is the packed
     (B, 7) float array [sqq, gamma, a_i, L_i, U_i, g_i, use_exact] and
     ``iscalars`` the (B, 1) int32 channel [i_idx] (global doubled index).
     ``base_l`` is the true base example count (half-1 coordinates are
@@ -221,10 +227,11 @@ def rbf_row_wss_batched_pallas(X, sqn, G, alpha, L, U, XQ, scalars,
     (B, lpad) when ``emit_k``.
     """
     H, B, lpad = G.shape
-    d = X.shape[1]
+    d8 = Xt.shape[0]
     bb = B if block_b is None else block_b
     assert lpad % block_l == 0 and B % bb == 0, (lpad, block_l, B, bb)
-    dtype = X.dtype
+    assert Xt.shape[1] >= lpad, (Xt.shape, lpad)
+    dtype = Xt.dtype
 
     lane_spec = pl.BlockSpec((H, bb, block_l), lambda c, b: (0, c, b))
     lane_row = lambda w: pl.BlockSpec((bb, w), lambda c, b: (c, 0))
@@ -234,14 +241,14 @@ def rbf_row_wss_batched_pallas(X, sqn, G, alpha, L, U, XQ, scalars,
         out_specs.append(pl.BlockSpec((bb, block_l), lambda c, b: (c, b)))
     masked = act is not None
     in_specs = [
-        lane_row(d),                                           # XQ
+        lane_row(d8),                                          # XQ
         lane_row(7),                                           # scalars
         lane_row(1),                                           # iscalars
-        pl.BlockSpec((block_l, d), lambda c, b: (b, 0)),       # X
+        pl.BlockSpec((d8, block_l), lambda c, b: (0, b)),      # Xt
         pl.BlockSpec((1, block_l), lambda c, b: (0, b)),       # sqn
         lane_spec, lane_spec, lane_spec, lane_spec,
     ]
-    args = [XQ, scalars, iscalars, X, sqn.reshape(1, lpad), G, alpha, L, U]
+    args = [XQ, scalars, iscalars, Xt, sqn.reshape(1, -1), G, alpha, L, U]
     if masked:
         in_specs.insert(0, lane_spec)
         args.insert(0, act)

@@ -18,7 +18,9 @@ Like pass A, the update/stopping algebra is dual-generic (arbitrary L/U
 boxes) and row-source-generic: the batched kernels take the lane state as
 an (H, B, lpad) stack of variable halves.  With H = 2 (the ε-SVR doubled
 operator) both base rows k_i / k_j are computed ONCE per grid step from
-the base (BL, d) X tile and applied to each half via index arithmetic —
+the base (d8, BL) tile of the feature-major X (see
+:mod:`repro.kernels.rbf_row_wss`) and applied to each half via index
+arithmetic —
 the matmuls stay l-wide.  The rows variant consumes pre-gathered base rows
 instead (Gram-bank mode).  The single-lane pass B is the rbf kernel at
 B = 1 with k_i read from HBM (``given_ki``) instead of recomputed.
@@ -104,7 +106,7 @@ def _kernel_batched(*refs, block_l: int, base_l: int, masked: bool = False,
     against the shared X tile, update every state half in-register, and
     fold the per-lane next-i argmax plus both KKT gap endpoints.
 
-    Both query blocks ride in ONE (2B, d) x (d, BL) matmul (the i rows
+    Both query blocks ride in ONE (2B, d8) x (d8, BL) matmul (the i rows
     then the j rows of the lane block): two matmuls against the same tile
     make the compiler hold several extra copies of it in VMEM.
 
@@ -124,7 +126,7 @@ def _kernel_batched(*refs, block_l: int, base_l: int, masked: bool = False,
     """
     act_ref, refs = (refs[0], refs[1:]) if masked else (None, refs)
     ki_ref, refs = (refs[0], refs[1:]) if given_ki else (None, refs)
-    (xq_ref, scal_ref, X_ref, sqn_ref, G_ref, alpha_ref, L_ref,
+    (xq_ref, scal_ref, Xt_ref, sqn_ref, G_ref, alpha_ref, L_ref,
      U_ref) = refs[:8]
     refs = refs[8:]
     dirv_ref, refs = (refs[0], refs[1:]) if conj else (None, refs)
@@ -137,10 +139,10 @@ def _kernel_batched(*refs, block_l: int, base_l: int, masked: bool = False,
     gamma = scal_ref[:, 3:4]
     mu2 = scal_ref[:, 4:5] if conj else None
 
-    x = X_ref[...]                      # (BL, d) shared tile
-    prod = jax.lax.dot_general(
-        xq_ref[...], x, (((1,), (1,)), ((), ())), precision=HIGHEST,
-        preferred_element_type=jnp.promote_types(x.dtype, jnp.float32))
+    x = Xt_ref[...]                     # (d8, BL) shared tile
+    prod = jnp.dot(xq_ref[...], x, precision=HIGHEST,
+                   preferred_element_type=jnp.promote_types(x.dtype,
+                                                            jnp.float32))
     sqn = sqn_ref[...]
 
     def row(p, sqq):
@@ -222,13 +224,16 @@ def _launch(kernel, args, in_specs, *, H, B, bb, lpad, block_l, dtype, act,
 
 @functools.partial(jax.jit, static_argnames=("block_l", "block_b",
                                              "interpret", "base_l"))
-def rbf_update_wss_batched_pallas(X, sqn, G, alpha_new, L, U, XQ, scalars,
+def rbf_update_wss_batched_pallas(Xt, sqn, G, alpha_new, L, U, XQ, scalars,
                                   act=None, dirv=None, ki=None, *,
                                   block_l: int = 1024,
                                   block_b: int | None = None,
                                   interpret: bool = False, base_l: int = 0):
-    """Launch lane-batched pass B.  The state leaves are (H, B, lpad) half
-    stacks (H = 2 for the doubled ε-SVR operator); ``XQ`` is (2B, d): per
+    """Launch lane-batched pass B.  ``Xt``/``sqn`` are the feature-major X
+    and its norms, as in
+    :func:`repro.kernels.rbf_row_wss.rbf_row_wss_batched_pallas`.  The
+    state leaves are (H, B, lpad) half
+    stacks (H = 2 for the doubled ε-SVR operator); ``XQ`` is (2B, d8): per
     lane block of ``block_b`` lanes, their *base* i query rows then their
     j query rows; ``scalars`` is the packed (B, 4) array
     [sqq_i, sqq_j, mu, gamma] per lane.  ``act`` is an optional
@@ -245,17 +250,18 @@ def rbf_update_wss_batched_pallas(X, sqn, G, alpha_new, L, U, XQ, scalars,
     supplies the k_i rows instead of recomputing them from the i query
     rows (which are then ignored)."""
     H, B, lpad = G.shape
-    d = X.shape[1]
+    d8 = Xt.shape[0]
     bb = B if block_b is None else block_b
     assert lpad % block_l == 0 and B % bb == 0, (lpad, block_l, B, bb)
+    assert Xt.shape[1] >= lpad, (Xt.shape, lpad)
     given_ki = ki is not None
     in_specs = [
-        pl.BlockSpec((2 * bb, d), lambda c, b: (c, 0)),        # XQ
+        pl.BlockSpec((2 * bb, d8), lambda c, b: (c, 0)),       # XQ
         pl.BlockSpec((bb, scalars.shape[1]), lambda c, b: (c, 0)),
-        pl.BlockSpec((block_l, d), lambda c, b: (b, 0)),       # X
+        pl.BlockSpec((d8, block_l), lambda c, b: (0, b)),      # Xt
         pl.BlockSpec((1, block_l), lambda c, b: (0, b)),       # sqn
     ]
-    args = [XQ, scalars, X, sqn.reshape(1, lpad), G, alpha_new, L, U]
+    args = [XQ, scalars, Xt, sqn.reshape(1, -1), G, alpha_new, L, U]
     if given_ki:
         in_specs.insert(0, pl.BlockSpec((bb, block_l), lambda c, b: (c, b)))
         args.insert(0, ki)
@@ -263,7 +269,7 @@ def rbf_update_wss_batched_pallas(X, sqn, G, alpha_new, L, U, XQ, scalars,
                                base_l=base_l, masked=act is not None,
                                conj=dirv is not None, given_ki=given_ki)
     return _launch(kernel, args, in_specs, H=H, B=B, bb=bb, lpad=lpad,
-                   block_l=block_l, dtype=X.dtype, act=act, dirv=dirv,
+                   block_l=block_l, dtype=Xt.dtype, act=act, dirv=dirv,
                    interpret=interpret, name="rbf_update_wss_batched_pallas")
 
 

@@ -37,8 +37,8 @@ from repro.kernels.ref import HIGHEST, rbf_exp
 
 @functools.partial(
     jax.tree_util.register_dataclass,
-    data_fields=("X", "sqn", "gammas", "gram", "gram_idx"),
-    meta_fields=("dup", "l"))
+    data_fields=("X", "sqn", "gammas", "gram", "gram_idx", "xt", "xt_sqn"),
+    meta_fields=("dup",))
 @dataclasses.dataclass(frozen=True)
 class RowSource:
     """Where pass A/B kernel rows come from (see module docstring).
@@ -47,10 +47,11 @@ class RowSource:
     rows; ``gammas`` is the (B,) per-lane RBF width (used by the rbf
     supplier and by :meth:`entry_pairs`).  ``dup`` marks the doubled ε-SVR
     operator: lane state indices live in [0, 2l) and fold onto the base
-    example axis through :meth:`base_idx`.  ``l`` is the true example
-    count where ``X``/``sqn`` carry zero padding rows and columns
-    (:func:`repro.kernels.ops.pad_source`); ``None`` means ``X`` is
-    unpadded.
+    example axis through :meth:`base_idx`.  ``xt``/``xt_sqn`` are the
+    stream the Pallas passes read: X feature-major and zero-padded, and
+    its norms padded alike, built once per engine call by
+    :func:`repro.kernels.ops.pad_source`; ``X``/``sqn`` stay unpadded
+    for the gathers, the O(1) entries and the exit check.
     """
 
     X: Optional[jax.Array] = None          # (l, d) base inputs
@@ -58,8 +59,9 @@ class RowSource:
     gammas: Optional[jax.Array] = None     # (B,) per-lane RBF widths
     gram: Optional[jax.Array] = None       # (n_stack, l, l) base Gram bank
     gram_idx: Optional[jax.Array] = None   # (B,) lane -> stack entry
+    xt: Optional[jax.Array] = None         # (d8, lpad) feature-major X
+    xt_sqn: Optional[jax.Array] = None     # (lpad,) norms of xt's columns
     dup: bool = False
-    l: Optional[int] = None                # true example count if X is padded
 
     # -- static structure ---------------------------------------------------
 
@@ -72,7 +74,7 @@ class RowSource:
         """True base example count (never the padded or doubled length)."""
         if self.is_bank:
             return self.gram.shape[-1]
-        return self.X.shape[0] if self.l is None else self.l
+        return self.X.shape[0]
 
     @property
     def n(self) -> int:
@@ -89,8 +91,7 @@ class RowSource:
         """Per-lane pass inputs at (stacked) coordinate indices ``idx``.
 
         Bank: the gathered (m, l) *base* rows.  Rbf: the (m, d) base query
-        rows (zero-padded columns included, if ``X`` carries them) plus
-        their squared norms.  Tiling for the doubled operator
+        rows plus their squared norms.  Tiling for the doubled operator
         happens downstream (in-kernel, or in the jnp oracle) — never here.
         """
         b = self.base_idx(idx)
@@ -131,8 +132,6 @@ class RowSource:
                      jnp.arange(v.shape[0], dtype=jnp.int32)]
         else:
             X, sqn = self.X, self.sqn
-            if X.shape[0] != l:              # padded rows are not examples
-                X, sqn = X[:l], sqn[:l]
             d = X.shape[1]
             pad = (-l) % block
             Xp = jnp.pad(X, ((0, pad), (0, 0)))
@@ -179,7 +178,7 @@ class RowSource:
                     v, m)
             return jax.vmap(lambda k, r, v: lane_rows(self.gram[k][r], v))(
                 self.gram_idx, r, v)
-        mean = jnp.sum(self.X, axis=0) / l    # padded rows are zero rows
+        mean = jnp.sum(self.X, axis=0) / l
 
         def lane_rows(r, v, gamma):
             xq = jnp.take(self.X, r, axis=0) - mean

@@ -6,7 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ops, ref
+from repro.kernels import ops, ref, row_source
 
 # tier-1 runs the two small shapes; the large tiling/padding shapes ride
 # the slow tier (nightly full run + REPRO_IMPL=interpret leg)
@@ -14,6 +14,9 @@ SHAPES = [(64, 2), (200, 7)] + [
     pytest.param(*s, marks=pytest.mark.slow)
     for s in [(1024, 16), (1500, 60), (2048, 128)]]
 DTYPES = [jnp.float32, jnp.float64]
+# the batched passes stream X feature-major, d padded to a multiple of 8:
+# d not a multiple of 8 (3, 22) and d above 128 (130), at B = 1, 3, 10
+STREAM_SHAPES = [(64, 3, 1), (200, 22, 3), (150, 130, 10)]
 
 
 def _setup(l, d, dtype, seed=0):
@@ -124,7 +127,8 @@ def _lane(M, idx):
 
 
 @pytest.mark.parametrize("l,d,B", [
-    (64, 2, 3), pytest.param(257, 33, 5, marks=pytest.mark.slow)])
+    (64, 2, 3), pytest.param(257, 33, 5, marks=pytest.mark.slow)]
+    + STREAM_SHAPES)
 @pytest.mark.parametrize("dtype", DTYPES)
 def test_pass_a_batched_matches_single_lane(l, d, B, dtype):
     """Batched pass A (jnp + interpret) == per-lane single-lane oracle."""
@@ -151,7 +155,8 @@ def test_pass_a_batched_matches_single_lane(l, d, B, dtype):
 
 
 @pytest.mark.parametrize("l,d,B", [
-    (64, 2, 3), pytest.param(257, 33, 5, marks=pytest.mark.slow)])
+    (64, 2, 3), pytest.param(257, 33, 5, marks=pytest.mark.slow)]
+    + STREAM_SHAPES)
 @pytest.mark.parametrize("dtype", DTYPES)
 def test_pass_b_batched_matches_single_lane(l, d, B, dtype):
     """Batched pass B (jnp + interpret) == per-lane single-lane oracle,
@@ -206,7 +211,8 @@ def _setup_doubled(l, d, B, dtype, seed=0):
 
 
 @pytest.mark.parametrize("l,d,B", [
-    (64, 2, 3), pytest.param(300, 17, 5, marks=pytest.mark.slow)])
+    (64, 2, 3), pytest.param(300, 17, 5, marks=pytest.mark.slow)]
+    + STREAM_SHAPES)
 @pytest.mark.parametrize("dtype", DTYPES)
 def test_pass_a_doubled_in_kernel_matches_jnp_oracle(l, d, B, dtype):
     """Tentpole parity: the in-kernel doubled row mode (interpret) — base
@@ -233,7 +239,8 @@ def test_pass_a_doubled_in_kernel_matches_jnp_oracle(l, d, B, dtype):
 
 
 @pytest.mark.parametrize("l,d,B", [
-    (64, 2, 3), pytest.param(300, 17, 5, marks=pytest.mark.slow)])
+    (64, 2, 3), pytest.param(300, 17, 5, marks=pytest.mark.slow)]
+    + STREAM_SHAPES)
 @pytest.mark.parametrize("dtype", DTYPES)
 def test_pass_b_doubled_in_kernel_matches_jnp_oracle(l, d, B, dtype):
     """Tentpole parity for pass B in doubled mode, incl. the bitwise
@@ -306,6 +313,79 @@ def test_rows_source_kernels_match_jnp(dup):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-11, atol=1e-12)
     np.testing.assert_array_equal(np.asarray(r_pl[0][1]), np.asarray(G[1]))
+
+
+@pytest.mark.parametrize("l,d,B", STREAM_SHAPES)
+@pytest.mark.parametrize("dup", [False, True])
+@pytest.mark.parametrize("variant", ["masked", "conjugate"])
+def test_masked_and_conjugate_passes_match_jnp_oracle(l, d, B, dup,
+                                                      variant):
+    """The feature-major passes under soft shrinking's active mask (pass A
+    and pass B) and under Conjugate-SMO's direction (pass B) equal the jnp
+    oracle, plain and doubled, including the bitwise mu = 0 lane freeze."""
+    dtype = jnp.float64
+    setup = _setup_doubled if dup else _setup_batched
+    X, sqn, G, alpha, L, U, gammas, i_idx = setup(l, d, B, dtype, seed=5)
+    n = G.shape[1]
+    rng = np.random.default_rng(6)
+    bi = i_idx % l
+    kw = {}
+    if variant == "masked":
+        kw["act"] = jnp.asarray(rng.uniform(size=(B, n)) < 0.7)
+        a_i, L_i, U_i = (_lane(a, i_idx) for a in (alpha, L, U))
+        aargs = (X, sqn, G, alpha, L, U, jnp.take(X, bi, axis=0),
+                 jnp.take(sqn, bi), a_i, L_i, U_i, _lane(G, i_idx), i_idx,
+                 jnp.asarray([b % 2 == 0 for b in range(B)]), gammas)
+        j_ref, gain_ref = ops.rbf_row_wss_batched(*aargs, impl="jnp",
+                                                  dup=dup, **kw)
+        j_pl, gain_pl = ops.rbf_row_wss_batched(*aargs, impl="interpret",
+                                                block_l=128, dup=dup, **kw)
+        assert [int(x) for x in j_pl] == [int(x) for x in j_ref]
+        np.testing.assert_allclose(np.asarray(gain_pl),
+                                   np.asarray(gain_ref), rtol=1e-11)
+    else:
+        base = jnp.asarray(rng.normal(size=(B, l)), dtype)
+        kw["dirv"] = ref.tile_rows(base) if dup else base
+        kw["mu2"] = jnp.asarray(rng.uniform(-0.2, 0.2, B), dtype).at[0].set(
+            0.0)
+    j_idx = jnp.asarray(rng.integers(0, n, B), jnp.int32)
+    mu = jnp.asarray(rng.uniform(-0.4, 0.4, B), dtype).at[0].set(0.0)
+    lanes = jnp.arange(B)
+    alpha_new = jnp.clip(alpha.at[lanes, i_idx].add(mu)
+                         .at[lanes, j_idx].add(-mu), L, U)
+    bj = j_idx % l
+    bargs = (X, sqn, G, alpha_new, L, U, jnp.take(X, bi, axis=0),
+             jnp.take(sqn, bi), jnp.take(X, bj, axis=0), jnp.take(sqn, bj),
+             mu, gammas)
+    r_ref = ops.rbf_update_wss_batched(*bargs, impl="jnp", dup=dup, **kw)
+    r_pl = ops.rbf_update_wss_batched(*bargs, impl="interpret", block_l=128,
+                                      dup=dup, **kw)
+    assert len(r_pl) == len(r_ref) == 4 + (variant == "conjugate")
+    assert [int(x) for x in r_pl[1]] == [int(x) for x in r_ref[1]]
+    for a, b in zip(r_pl, r_ref):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-11, atol=1e-12)
+    np.testing.assert_array_equal(np.asarray(r_pl[0][0]), np.asarray(G[0]))
+
+
+@pytest.mark.parametrize("d", [3, 22, 130])
+@pytest.mark.parametrize("dup", [False, True])
+def test_pad_source_streams_x_feature_major(d, dup):
+    """The passes' stream is X transposed to (round_up(d, 8), lpad), with
+    zeros in the padded feature rows and example columns, and the norms
+    padded alike; the row-major X the gathers read is left as it was."""
+    l, B = 300, 3
+    X = jnp.asarray(np.random.default_rng(7).normal(size=(l, d)))
+    src = ops.pad_source(row_source.rbf_source(X, 0.5, B, dup=dup), B, 128)
+    d8, lpad = -(-d // 8) * 8, -(-l // 128) * 128
+    assert src.xt.shape == (d8, lpad) and src.xt_sqn.shape == (lpad,)
+    xt = np.asarray(src.xt)
+    np.testing.assert_array_equal(xt[:d, :l], np.asarray(X).T)
+    assert not xt[d:].any() and not xt[:, l:].any()
+    sqn = np.asarray(src.xt_sqn)
+    np.testing.assert_array_equal(sqn[:l], np.asarray(src.sqn))
+    assert not sqn[l:].any()
+    assert src.X is X and src.base_l == l
 
 
 def test_index_channel_is_exact_beyond_float32_significand():

@@ -106,7 +106,9 @@ def _ragged_batch(mode):
 @pytest.mark.parametrize("mode", ["plain", "doubled", "shrinking",
                                   "conjugate"])
 def test_x_is_padded_once_before_the_loop(mode):
-    """No pad of X (l, d) or of its norms (l,) inside the while body.
+    """No pad of X (l, d) or of its norms (l,) inside the while body, and
+    the passes' feature-major stream transposed once, outside every loop:
+    no loop that runs the passes transposes anything.
 
     The check reads the jaxpr: XLA's CPU compiler hoists a loop-invariant
     pad out of the loop by itself, the TPU's does not, so compiled CPU HLO
@@ -116,11 +118,20 @@ def test_x_is_padded_once_before_the_loop(mode):
     fn = lambda *a: solve_fused_batched_qp(
         *a, cfg, impl="interpret", block_l=RAGGED_BLOCK, **kw)
     x_shapes = {(RAGGED_L, RAGGED_D), (RAGGED_L,)}
+    eqns = list(iter_eqns(jax.make_jaxpr(fn)(*args).jaxpr))
     pads = [("while" in path, tuple(eqn.invars[0].aval.shape))
-            for path, eqn in iter_eqns(jax.make_jaxpr(fn)(*args).jaxpr)
-            if eqn.primitive.name == "pad"]
+            for path, eqn in eqns if eqn.primitive.name == "pad"]
     assert [sh for in_loop, sh in pads if in_loop and sh in x_shapes] == []
     assert {sh for in_loop, sh in pads if not in_loop} >= x_shapes
+    d8 = -(-RAGGED_D // 8) * 8
+    xt = [eqn.outvars[0].aval.shape for path, eqn in eqns
+          if eqn.primitive.name == "transpose" and "while" not in path]
+    assert [(r, c >= RAGGED_L) for r, c in xt] == [(d8, True)]
+    trip_loops = [[e.primitive.name for _, e in
+                   iter_eqns(eqn.params["body_jaxpr"].jaxpr)]
+                  for _, eqn in eqns if eqn.primitive.name == "while"]
+    trip_loops = [names for names in trip_loops if "pallas_call" in names]
+    assert trip_loops and all("transpose" not in n for n in trip_loops)
     text = jax.jit(fn).lower(*args).as_text(debug_info=True)
     assert "x_pad" in text
 

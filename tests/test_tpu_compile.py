@@ -29,10 +29,18 @@ from repro.core.solver_fused import solve_fused_batched_qp
 from repro.kernels import ops
 
 L_EX = 51_200          # examples (ijcnn1-sized)
-D = 22                 # features (ijcnn1), padded to 128 in the wrappers
+D = 22                 # features (ijcnn1), streamed as 24 feature rows
+WIDTHS = (D, 780)      # ijcnn1's and mnist's feature counts
 LANES = (8, 110, 512)  # one head, the LIBSVM-guide grid, a wide batch
 PASS_A = ("plain", "masked", "doubled", "rows")
 PASS_B = ("plain", "masked", "doubled", "conjugate", "rows")
+
+
+def _at_widths(variants):
+    """(variant, d) cases: the rbf variants at every width of the X
+    stream, the Gram-bank rows variant (no X) once."""
+    return [pytest.param(v, d, id=v if d == D else f"{v}-d{d}")
+            for v in variants for d in WIDTHS if v != "rows" or d == D]
 
 
 @pytest.fixture(scope="module")
@@ -76,10 +84,11 @@ def _shapes(sharding, B, H, masked, conj, d=D):
 
 
 @pytest.mark.parametrize("B", LANES)
-@pytest.mark.parametrize("variant", PASS_A)
-def test_pass_a_compiles_for_v5e(one_chip, variant, B):
+@pytest.mark.parametrize("variant,d", _at_widths(PASS_A))
+def test_pass_a_compiles_for_v5e(one_chip, variant, B, d):
     dup = variant == "doubled"
-    s = _shapes(one_chip, B, 2 if dup else 1, variant == "masked", False)
+    s = _shapes(one_chip, B, 2 if dup else 1, variant == "masked", False,
+                d=d)
     st, ln = s["state"], s["lane"]
     if variant == "rows":
         def fn(KR, G, a, L, U, ai, Li, Ui, gi, i, ex):
@@ -101,11 +110,12 @@ def test_pass_a_compiles_for_v5e(one_chip, variant, B):
 
 
 @pytest.mark.parametrize("B", LANES)
-@pytest.mark.parametrize("variant", PASS_B)
-def test_pass_b_compiles_for_v5e(one_chip, variant, B):
+@pytest.mark.parametrize("variant,d", _at_widths(PASS_B))
+def test_pass_b_compiles_for_v5e(one_chip, variant, B, d):
     dup = variant == "doubled"
     conj = variant == "conjugate"
-    s = _shapes(one_chip, B, 2 if dup else 1, variant == "masked", conj)
+    s = _shapes(one_chip, B, 2 if dup else 1, variant == "masked", conj,
+                d=d)
     st, ln = s["state"], s["lane"]
     extra = tuple(v for v in (s["act"], s["dirv"]) if v is not None)
 
@@ -135,8 +145,8 @@ def test_pass_b_compiles_for_v5e(one_chip, variant, B):
 
 
 def test_wide_feature_passes_compile_for_v5e(one_chip):
-    # mnist width (d = 780 -> 896): the X tile, not the lane state, fills
-    # the step's VMEM, so the planner has to shrink the l block
+    # mnist width (d = 780, streamed as 784 feature rows): the X tile,
+    # not the lane state, fills most of the step's VMEM
     s = _shapes(one_chip, 10, 1, False, False, d=780)
     st, ln = s["state"], s["lane"]
 
@@ -192,7 +202,13 @@ def test_plan_keeps_the_callers_block_and_splits_wide_batches():
     assert wide.bpad % wide.block_b == 0 and wide.bpad >= 4096
     for t, kw in ((small, {}), (wide, dict(H=2, masked=True, conj=True))):
         fp = dict(H=kw.get("H", 1), stacks=5 + kw.get("masked", False),
-                  rows=2 * kw.get("conj", False), dpad=128)
+                  rows=2 * kw.get("conj", False), d=D)
         assert ops.vmem_bytes(t.block_b, t.block_l, **fp) <= ops.VMEM_BUDGET
     assert np.all([ops.pass_a_tiles(B, L_EX, D, 1024).block_l <= 1024
                    for B in LANES])
+    # both benchmark cells keep the caller's block: ijcnn1 (one lane) and
+    # mnist (ten lanes at d = 780, a (784, 1024) X tile)
+    for B, l, d in ((1, 49_990, D), (10, 60_000, 780)):
+        for t in (ops.pass_a_tiles(B, l, d, 1024),
+                  ops.pass_b_tiles(B, l, d, 1024)):
+            assert (t.block_l, t.lpad) == (1024, -(-l // 1024) * 1024)
