@@ -16,9 +16,7 @@ REGISTRY = [
     ("fig3", "benchmarks.fig3_stepsizes"),
     ("fig4", "benchmarks.fig4_multi"),
     ("ablation", "benchmarks.ablation_wss"),
-    ("solver_micro", "benchmarks.solver_micro"),
     ("grid", "benchmarks.grid_bench"),
-    ("kernels", "benchmarks.kernels_bench"),
     ("lm_step", "benchmarks.lm_step_bench"),
     ("roofline", "benchmarks.roofline_table"),
 ]

@@ -6,32 +6,30 @@ passes over O(l) state per iteration.  This solver restructures the
 iteration into exactly the two fused passes implemented by the Pallas
 kernels in :mod:`repro.kernels`:
 
-  pass A: k_i  + second-order j-selection           (reads X, G, masks)
-  pass B: k_j (VMEM-only) + gradient update + next i-pick + KKT gap ends
+  pass A: second-order j-selection                   (reads X, G, masks)
+  pass B: k_i and k_j (VMEM-only) + gradient update + next i-pick + KKT
+          gap ends
 
 All O(1) work in between — the truncated Newton step, the planning-ahead
 step size (eq. 8), the ≤4x4 kernel minor, Alg. 3's B^(t-2) candidate —
-runs on scalars, with single-row RBF evaluations costing O(d).
-
-Semantics are identical to ``solver.solve`` with an RBF oracle (same
+runs on per-lane scalars, with single-entry RBF evaluations costing O(d).
+Semantics are those of ``solver.solve`` with an RBF oracle (same
 Algorithms 3/4/5); trajectories agree modulo floating-point reassociation.
 ``impl`` selects pallas/interpret/jnp exactly as in ``repro.kernels.ops``.
 
-:func:`solve_fused_batched_qp` runs a whole *batch of lanes* — one lane
-per *general* dual QP (:mod:`repro.core.qp`: per-lane linear term ``P``
-and box ``L``/``U``; classification, ε-SVR with ``doubled=True`` lanes
-over a shared base ``X``, one-class via feasible warm starts) — through
-ONE ``lax.while_loop`` whose
-body is TWO batched kernel launches plus O(B) per-lane algebra.  The lane
-batching differs from the single-lane shape in one structural way: pass A
-returns only the selection, and pass B recomputes both rows k_i/k_j
-against the shared X tile.  That removes the k_i HBM round-trip and —
-crucially — the data-dependent pass-A relaunch when Alg. 3's B^(t-2)
-candidate wins, which has no batched equivalent.  Converged lanes are
-frozen *in kernel*: their step size is forced to 0, so pass B's update is
-a bitwise no-op on G.  A lane converges only through the exit check,
-which reads its gap from exact gradient rows (see
-:func:`solve_fused_batched_qp`).
+:func:`solve_fused_batched_qp` is the one fused engine.  It runs a whole
+*batch of lanes* — one lane per *general* dual QP (:mod:`repro.core.qp`:
+per-lane linear term ``P`` and box ``L``/``U``; classification, ε-SVR
+with ``doubled=True`` lanes over a shared base ``X``, one-class via
+feasible warm starts) — through ONE ``lax.while_loop`` whose body is TWO
+batched kernel launches plus O(B) per-lane algebra; a single QP is the
+batch of one.  Pass A returns only the selection, and pass B recomputes
+both rows k_i/k_j against the shared X tile: no kernel row makes an HBM
+round-trip, and when Alg. 3's B^(t-2) candidate wins no pass is
+relaunched.  Converged lanes are frozen *in kernel*: their step size is
+forced to 0, so pass B's update is a bitwise no-op on G.  A lane
+converges only through the exit check, which reads its gap from exact
+gradient rows (see :func:`solve_fused_batched_qp`).
 """
 
 from __future__ import annotations
@@ -50,7 +48,7 @@ from repro.core.qp import TAU
 from repro.core.solver import DEFAULT_SHRINK_EVERY, SolverConfig
 from repro.kernels import ops
 from repro.kernels import row_source
-from repro.kernels.ref import rbf_exp, take_lane as _take_lane
+from repro.kernels.ref import take_lane as _take_lane
 from repro.telemetry import phase_scope
 from repro.telemetry.ring import (RingConfig, TelemetryRing, ring_init,
                                   ring_update)
@@ -73,188 +71,6 @@ class FusedResult:
     # engine's exit-check reopenings (the carried gradient read a gap of
     # eps, the gap from exact rows did not)
     n_unshrink: jax.Array
-
-
-class _State(NamedTuple):
-    alpha: jax.Array
-    G: jax.Array
-    i: jax.Array        # next working-set first index (from pass B)
-    g_i: jax.Array      # G[i] == max gradient over I_up
-    gap: jax.Array
-    t: jax.Array
-    done: jax.Array
-    pi: jax.Array
-    pj: jax.Array
-    qi: jax.Array
-    qj: jax.Array
-    n_hist: jax.Array
-    p_smo: jax.Array
-    prev_free: jax.Array
-    prev_ratio_ok: jax.Array
-    n_planning: jax.Array
-
-
-@partial(jax.jit, static_argnames=("cfg", "impl", "block_l"))
-def solve_fused(X, y, C, gamma, cfg: SolverConfig = SolverConfig(),
-                *, impl: str = "auto", block_l: int = 1024) -> FusedResult:
-    assert cfg.algorithm in ("smo", "pasmo")
-    assert cfg.plan_candidates == 1
-    assert cfg.step == "plain", \
-        "step='conjugate' is a lane-batched mode (solve_fused_batched_qp)"
-    assert cfg.wss == "wss2", \
-        "the fused passes hardcode WSS2 selection (use the standard solver)"
-    assert not (cfg.record_trace or cfg.record_steps), \
-        "the fused solver does not record traces/steps"
-    X = jnp.asarray(X)
-    y = jnp.asarray(y)
-    dtype = y.dtype
-    n = y.shape[0]
-    C = jnp.asarray(C, dtype)
-    gamma = jnp.asarray(gamma, dtype)
-    L = jnp.minimum(0.0, y * C)
-    U = jnp.maximum(0.0, y * C)
-    sqn = jnp.sum(X * X, axis=-1)
-    eps = cfg.eps
-    eta = cfg.eta
-    planning = cfg.algorithm == "pasmo"
-
-    def entry(a, b):
-        """O(d) single RBF kernel entry."""
-        d2 = (jnp.take(sqn, a) + jnp.take(sqn, b)
-              - 2.0 * jnp.dot(jnp.take(X, b, axis=0), jnp.take(X, a, axis=0)))
-        return rbf_exp(-gamma * jnp.maximum(d2, 0.0))
-
-    def pass_a(G, alpha, i, g_i, use_exact):
-        return ops.rbf_row_wss(
-            X, sqn, G, alpha, L, U, jnp.take(X, i, axis=0),
-            jnp.take(alpha, i), jnp.take(L, i), jnp.take(U, i), g_i,
-            i, use_exact, gamma, impl=impl, block_l=block_l)
-
-    def body(s: _State) -> _State:
-        alpha, G = s.alpha, s.G
-        use_exact = jnp.asarray(planning) & (~s.p_smo) & (~s.prev_ratio_ok)
-
-        # ---- pass A: row k_i + j-selection ---------------------------------
-        k_i, j0, gain0 = pass_a(G, alpha, s.i, s.g_i, use_exact)
-
-        # ---- Alg. 3 extra candidate B^(t-2) (O(d)) -------------------------
-        if planning:
-            K_qq = entry(s.qi, s.qj)
-            G_qi = jnp.take(G, s.qi)
-            G_qj = jnp.take(G, s.qj)
-            l_q = G_qi - G_qj
-            q_q = jnp.maximum(2.0 - 2.0 * K_qq, TAU)
-            a_qi = jnp.take(alpha, s.qi)
-            a_qj = jnp.take(alpha, s.qj)
-            sb_q = step_mod.step_bounds(
-                a_qi, a_qj, jnp.take(L, s.qi), jnp.take(U, s.qi),
-                jnp.take(L, s.qj), jnp.take(U, s.qj))
-            mu_q = step_mod.clip_step(l_q / q_q, sb_q)
-            cg_exact = step_mod.gain_of_step(mu_q, l_q, q_q)
-            cg_tilde = 0.5 * l_q * l_q / q_q
-            cg = jnp.where(use_exact, cg_exact, cg_tilde)
-            adm = ((a_qi < jnp.take(U, s.qi)) & (a_qj > jnp.take(L, s.qj))
-                   & (l_q > 0) & (s.qi != s.qj) & (s.n_hist > 1))
-            take = (~s.p_smo) & adm & (cg > gain0)
-            i_sel = jnp.where(take, s.qi, s.i)
-            j_sel = jnp.where(take, s.qj, j0)
-            g_i_sel = jnp.where(take, G_qi, s.g_i)
-            # candidate won: the row belongs to qi — recompute pass A
-            k_i = jax.lax.cond(
-                take,
-                lambda: pass_a(G, alpha, s.qi, G_qi, use_exact)[0],
-                lambda: k_i)
-        else:
-            i_sel, j_sel, g_i_sel = s.i, j0, s.g_i
-
-        # ---- O(1) step computation ----------------------------------------
-        lw = g_i_sel - jnp.take(G, j_sel)
-        K_ij = jnp.take(k_i, j_sel)
-        q11 = jnp.maximum(2.0 - 2.0 * K_ij, TAU)
-        sb = step_mod.step_bounds(
-            jnp.take(alpha, i_sel), jnp.take(alpha, j_sel),
-            jnp.take(L, i_sel), jnp.take(U, i_sel),
-            jnp.take(L, j_sel), jnp.take(U, j_sel))
-        mu_star = lw / q11
-        mu_smo, free_smo = step_mod.smo_step(lw, q11, sb)
-
-        do_plan = jnp.asarray(False)
-        mu_plan = mu_smo
-        ratio_ok = s.prev_ratio_ok
-        if planning:
-            w2 = jnp.take(G, s.pi) - jnp.take(G, s.pj)
-            q22 = jnp.maximum(2.0 - 2.0 * entry(s.pi, s.pj), TAU)
-            q12 = (jnp.take(k_i, s.pi) - jnp.take(k_i, s.pj)
-                   - entry(j_sel, s.pi) + entry(j_sel, s.pj))
-            terms = step_mod.PlanningTerms(w1=lw, w2=w2, Q11=q11, Q22=q22,
-                                           Q12=q12)
-            mu1, okdet = step_mod.planning_step(terms)
-            mu2 = step_mod.planned_second_step(mu1, terms)
-            interior1 = (sb.lo < mu1) & (mu1 < sb.hi)
-            d_pi = ((s.pi == i_sel).astype(dtype)
-                    - (s.pi == j_sel).astype(dtype))
-            d_pj = ((s.pj == i_sel).astype(dtype)
-                    - (s.pj == j_sel).astype(dtype))
-            sb2 = step_mod.step_bounds(
-                jnp.take(alpha, s.pi) + mu1 * d_pi,
-                jnp.take(alpha, s.pj) + mu1 * d_pj,
-                jnp.take(L, s.pi), jnp.take(U, s.pi),
-                jnp.take(L, s.pj), jnp.take(U, s.pj))
-            interior2 = (sb2.lo < mu2) & (mu2 < sb2.hi)
-            feasible = okdet & interior1 & interior2 & (s.n_hist > 0)
-            do_plan = s.prev_free & feasible
-            mu_plan = jnp.where(do_plan, mu1, mu_smo)
-            ratio = mu1 / jnp.where(jnp.abs(mu_star) > 0, mu_star, 1.0)
-            ratio_ok = jnp.where(do_plan,
-                                 (ratio >= 1.0 - eta) & (ratio <= 1.0 + eta),
-                                 s.prev_ratio_ok)
-
-        mu = jnp.where(do_plan, mu_plan, mu_smo)
-        alpha_new = alpha.at[i_sel].add(mu).at[j_sel].add(-mu)
-
-        # ---- pass B: update + next i + gap ---------------------------------
-        G_new, i_next, g_i_next, g_dn = ops.rbf_update_wss(
-            X, sqn, G, k_i, alpha_new, L, U, jnp.take(X, j_sel, axis=0),
-            mu, gamma, impl=impl, block_l=block_l)
-        gap = qp_mod.finite_gap(g_i_next - g_dn)
-
-        return _State(
-            alpha=alpha_new, G=G_new, i=i_next.astype(jnp.int32),
-            g_i=g_i_next, gap=gap, t=s.t + 1, done=gap <= eps,
-            pi=i_sel.astype(jnp.int32), pj=j_sel.astype(jnp.int32),
-            qi=s.pi, qj=s.pj,
-            n_hist=jnp.minimum(s.n_hist + 1, 2),
-            p_smo=~do_plan, prev_free=(~do_plan) & free_smo,
-            prev_ratio_ok=ratio_ok,
-            n_planning=s.n_planning + do_plan.astype(jnp.int32))
-
-    # ---- init ---------------------------------------------------------------
-    alpha0 = jnp.zeros_like(y)
-    G0 = y
-    up0 = alpha0 < U
-    dn0 = alpha0 > L
-    v_up = jnp.where(up0, G0, -jnp.inf)
-    i0 = jax.lax.argmax(v_up, 0, jnp.int32)
-    g_i0 = v_up[i0]
-    gap0 = qp_mod.finite_gap(g_i0 - jnp.min(jnp.where(dn0, G0, jnp.inf)))
-    z = jnp.asarray(0, jnp.int32)
-    s0 = _State(alpha=alpha0, G=G0, i=i0, g_i=g_i0, gap=gap0, t=z,
-                done=gap0 <= eps, pi=z, pj=z, qi=z, qj=z, n_hist=z,
-                p_smo=jnp.asarray(True), prev_free=jnp.asarray(False),
-                prev_ratio_ok=jnp.asarray(True), n_planning=z)
-
-    s = jax.lax.while_loop(lambda s: (~s.done) & (s.t < cfg.max_iter),
-                           body, s0)
-
-    up = s.alpha < U
-    dn = s.alpha > L
-    g_up = jnp.max(jnp.where(up, s.G, -jnp.inf))
-    g_dn = jnp.min(jnp.where(dn, s.G, jnp.inf))
-    return FusedResult(
-        alpha=s.alpha, b=qp_mod.safe_bias(g_up, g_dn), G=s.G, iterations=s.t,
-        objective=0.5 * (jnp.dot(y, s.alpha) + jnp.dot(s.G, s.alpha)),
-        kkt_gap=s.gap, converged=s.done, n_planning=s.n_planning,
-        n_unshrink=jnp.asarray(0, jnp.int32))
 
 
 # ---------------------------------------------------------------------------

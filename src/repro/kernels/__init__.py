@@ -7,12 +7,12 @@ example dimension (DESIGN.md §3):
 
 * pass A (``rbf_row_wss``):   compute the kernel row k_i from X, and in the
   same VMEM-resident pass evaluate the WSS2 second-order gains (eq. 3, or
-  the exact clipped gain for Alg. 3's guard branch) and their per-block
-  argmax.  Outputs: k_i (needed by pass B) + per-block (max, arg).
-* pass B (``rbf_update_wss``): compute k_j (never materialized to HBM),
-  apply the gradient update G <- G - mu (k_i - k_j), and in the same pass
-  compute the next iteration's first-order argmax over I_up and both KKT
-  gap endpoints.
+  the exact clipped gain for Alg. 3's guard branch) and their running
+  per-lane argmax.  Output: the selection (j, gain); k_i stays in VMEM.
+* pass B (``rbf_update_wss``): recompute k_i and compute k_j (neither
+  materialized to HBM), apply the gradient update
+  G <- G - mu (k_i - k_j), and in the same pass compute the next
+  iteration's first-order argmax over I_up and both KKT gap endpoints.
 
 Everything O(1) in between (Newton step, planning-ahead eq. 8, box logic)
 happens on scalars outside the kernels.  ``gram_block`` provides the tiled

@@ -13,8 +13,9 @@ sharded.py for the same trick).  The passes read the shared X
 *feature-major*: :func:`pad_source` is the one builder of that stream,
 X padded to the block multiple of examples and a sublane multiple (8) of
 features, then transposed to (d8, lpad), with its row norms padded
-alike.  The fused engine builds it once per call, before its while loop;
-a wrapper handed raw X builds it per call through the same function.
+alike.  The Pallas path of a pass takes a source that already holds
+the stream: the fused engine builds it once per call, before its while
+loop, and the two raw-X wrappers build it for the one call they make.
 The block sizes come from the shapes (:func:`plan_tiles`): the caller's
 ``block_l`` is an upper bound that shrinks — and the lane batch splits
 into blocks — until one grid step's working set fits the VMEM budget.
@@ -51,12 +52,12 @@ def resolve_impl(impl: str) -> str:
     return "pallas" if jax.default_backend() == "tpu" else "jnp"
 
 
-def _pad_l(a, lpad, value=0.0):
+def _pad_l(a, lpad):
     pad = lpad - a.shape[0]
     if pad == 0:
         return a
     widths = [(0, pad)] + [(0, 0)] * (a.ndim - 1)
-    return jnp.pad(a, widths, constant_values=value)
+    return jnp.pad(a, widths, constant_values=0.0)
 
 
 def _pad_d(a, dpad):
@@ -189,71 +190,13 @@ def pad_source(src: RowSource, B: int, block_l: int, *,
 
 
 # ---------------------------------------------------------------------------
-# Single-lane wrappers (the B = 1 instance of the batched kernels)
-# ---------------------------------------------------------------------------
-
-
-def _row1(a, lpad, value=0.0):
-    """(l,) vector -> (1, 1, lpad) single-lane state stack."""
-    return _pad_l(a, lpad, value).reshape(1, 1, lpad)
-
-
-def rbf_row_wss(X, sqn, G, alpha, L, U, xq, a_i, L_i, U_i, g_i, i_idx,
-                use_exact, gamma, *, impl: str = "auto",
-                block_l: int = 1024):
-    """Pass A: returns (k_i (l,), j (int32), gain_j)."""
-    impl = resolve_impl(impl)
-    l, d = X.shape
-    if impl == "jnp":
-        return ref_ops.rbf_row_wss(X, sqn, G, alpha, L, U, xq, a_i, L_i,
-                                   U_i, g_i, i_idx, use_exact, gamma)
-    src = pad_source(RowSource(X=X, sqn=sqn), 1, block_l)
-    t = pass_a_tiles(1, l, d, block_l)
-    dtype = X.dtype
-    scal = jnp.stack([jnp.dot(xq, xq, precision=ref_ops.HIGHEST),
-                      jnp.asarray(gamma, dtype), a_i, L_i,
-                      U_i, g_i, use_exact.astype(dtype)]).reshape(1, 7)
-    j, gain, k = rbf_row_wss_batched_pallas(
-        src.xt, src.xt_sqn, _row1(G, t.lpad), _row1(alpha, t.lpad),
-        _row1(L, t.lpad), _row1(U, t.lpad),
-        _pad_d(xq, _d8(d)).reshape(1, -1), scal.astype(dtype),
-        _iscal(i_idx, 1), block_l=t.block_l,
-        interpret=(impl == "interpret"), base_l=l, emit_k=True)
-    return k[0, :l], j[0], gain[0]
-
-
-def rbf_update_wss(X, sqn, G, k_i, alpha_new, L, U, xq_j, mu, gamma,
-                   *, impl: str = "auto", block_l: int = 1024):
-    """Pass B: returns (G_new (l,), i_next, g_i_next, g_dn)."""
-    impl = resolve_impl(impl)
-    l, d = X.shape
-    if impl == "jnp":
-        return ref_ops.rbf_update_wss(X, sqn, G, k_i, xq_j, mu, alpha_new,
-                                      L, U, gamma)
-    src = pad_source(RowSource(X=X, sqn=sqn), 1, block_l)
-    t = pass_b_tiles(1, l, d, block_l)
-    dtype = X.dtype
-    xq = jnp.broadcast_to(_pad_d(xq_j, _d8(d)), (2, _d8(d)))  # (i, j) rows
-    scal = jnp.stack([jnp.zeros((), dtype),
-                      jnp.dot(xq_j, xq_j, precision=ref_ops.HIGHEST),
-                      jnp.asarray(mu, dtype),
-                      jnp.asarray(gamma, dtype)]).reshape(1, 4)
-    G_new, i_next, g_i_next, g_dn = rbf_update_wss_batched_pallas(
-        src.xt, src.xt_sqn, _row1(G, t.lpad), _row1(alpha_new, t.lpad),
-        _row1(L, t.lpad), _row1(U, t.lpad), xq, scal.astype(dtype),
-        ki=_pad_l(k_i, t.lpad).reshape(1, t.lpad), block_l=t.block_l,
-        interpret=(impl == "interpret"), base_l=l)
-    return G_new[0, 0, :l], i_next[0], g_i_next[0], g_dn[0]
-
-
-# ---------------------------------------------------------------------------
 # Lane-batched wrappers (one lane = one QP; X is shared across lanes)
 # ---------------------------------------------------------------------------
 #
-# The example dimension is padded exactly as above; the lane dimension is
-# padded to the lane-block multiple with *inert* lanes: L = U = alpha = 0
-# rows can never be selected in pass A, and mu = 0 makes pass B a no-op, so
-# padded lanes never influence any lane's result.
+# The example dimension is padded to the block multiple with inert rows;
+# the lane dimension is padded to the lane-block multiple with *inert*
+# lanes: L = U = alpha = 0 rows can never be selected in pass A, and mu = 0
+# makes pass B a no-op, so padded lanes never influence any lane's result.
 #
 # The doubled ε-SVR operator (dup=True, lane state n = 2l) is carried as an
 # (2, bpad, lpad) half stack: the kernels compute the base row tile once
@@ -303,8 +246,8 @@ def rbf_row_wss_batched(X, sqn, G, alpha, L, U, XQ, sqq, a_i, L_i, U_i,
     """Batched pass A: per-lane WSS2 selection, returns (j (B,), gain (B,)).
 
     ``X``/``sqn`` are the shared (l, d) examples and their norms, which
-    the Pallas path streams through :func:`pad_source` per call (the
-    fused engine builds that stream once and calls
+    the Pallas path streams through :func:`pad_source` for this one call
+    (the fused engine builds that stream once and calls
     :func:`source_row_wss`); ``G``/``alpha``/``L``/``U`` are (B, n);
     ``XQ`` is the (B, d) gathered *base* query rows; the rest are (B,)
     per-lane scalars.  ``dup=True`` runs the doubled ε-SVR operator
@@ -314,26 +257,28 @@ def rbf_row_wss_batched(X, sqn, G, alpha, L, U, XQ, sqq, a_i, L_i, U_i,
     the matmul never widens past l.  ``act`` is an optional (B, n)
     active-set mask (soft shrinking: restricts the j-scan only).
     """
-    return _rbf_row_wss(RowSource(X=X, sqn=sqn, gammas=gammas, dup=dup),
-                        G, alpha, L, U, XQ, sqq, a_i, L_i, U_i, g_i, i_idx,
-                        use_exact, impl=impl, block_l=block_l, act=act)
+    src = RowSource(X=X, sqn=sqn, gammas=gammas, dup=dup)
+    if resolve_impl(impl) != "jnp":
+        src = pad_source(src, G.shape[0], block_l, masked=act is not None)
+    return _rbf_row_wss(src, G, alpha, L, U, XQ, sqq, a_i, L_i, U_i, g_i,
+                        i_idx, use_exact, impl=impl, block_l=block_l,
+                        act=act)
 
 
 def _rbf_row_wss(src, G, alpha, L, U, XQ, sqq, a_i, L_i, U_i, g_i, i_idx,
                  use_exact, *, impl, block_l, act):
     """Batched pass A against an rbf row source: the jnp oracle on
-    ``src.X``, or the Pallas kernel on its stream (built here if ``src``
-    comes without one)."""
+    ``src.X``, or the Pallas kernel on its stream ``src.xt``, which the
+    caller builds with :func:`pad_source`."""
     impl = resolve_impl(impl)
     if impl == "jnp":
         return ref_ops.rbf_row_wss_batched(src.X, src.sqn, G, alpha, L, U,
                                            XQ, sqq, a_i, L_i, U_i, g_i,
                                            i_idx, use_exact, src.gammas,
                                            dup=src.dup, act=act)
+    assert src.xt is not None, "the Pallas path needs pad_source's stream"
     H = 2 if src.dup else 1
     B, (l, d) = G.shape[0], src.X.shape
-    if src.xt is None:
-        src = pad_source(src, B, block_l, masked=act is not None)
     t = pass_a_tiles(B, l, d, block_l, H=H, masked=act is not None)
     dtype = src.xt.dtype
     scal = jnp.stack([sqq, jnp.broadcast_to(src.gammas, (B,)),
@@ -394,10 +339,13 @@ def rbf_update_wss_batched(X, sqn, G, alpha_new, L, U, XQi, sqqi, XQj, sqqj,
     ``r = k_i - k_j`` (at full lane-state width) — see
     :func:`repro.kernels.ref.update_wss_batched_from_rows`.
     """
-    return _rbf_update_wss(RowSource(X=X, sqn=sqn, gammas=gammas, dup=dup),
-                           G, alpha_new, L, U, XQi, sqqi, XQj, sqqj, mu,
-                           impl=impl, block_l=block_l, act=act, dirv=dirv,
-                           mu2=mu2)
+    src = RowSource(X=X, sqn=sqn, gammas=gammas, dup=dup)
+    if resolve_impl(impl) != "jnp":
+        src = pad_source(src, G.shape[0], block_l, masked=act is not None,
+                         conj=dirv is not None)
+    return _rbf_update_wss(src, G, alpha_new, L, U, XQi, sqqi, XQj, sqqj,
+                           mu, impl=impl, block_l=block_l, act=act,
+                           dirv=dirv, mu2=mu2)
 
 
 def _rbf_update_wss(src, G, alpha_new, L, U, XQi, sqqi, XQj, sqqj, mu, *,
@@ -409,11 +357,10 @@ def _rbf_update_wss(src, G, alpha_new, L, U, XQi, sqqi, XQj, sqqj, mu, *,
                                               L, U, XQi, sqqi, XQj, sqqj,
                                               mu, src.gammas, dup=src.dup,
                                               act=act, dirv=dirv, mu2=mu2)
+    assert src.xt is not None, "the Pallas path needs pad_source's stream"
     H = 2 if src.dup else 1
     B, (l, d) = G.shape[0], src.X.shape
     conj = dirv is not None
-    if src.xt is None:
-        src = pad_source(src, B, block_l, masked=act is not None, conj=conj)
     t = pass_b_tiles(B, l, d, block_l, H=H, masked=act is not None,
                      conj=conj)
     dtype = src.xt.dtype
@@ -510,9 +457,9 @@ def source_row_wss(src: RowSource, G, alpha, L, U, i_idx, a_i, L_i, U_i,
 
     ``act`` is an optional (B, n) active-set mask (soft shrinking).
     Returns (j (B,), gain (B,)) — the per-lane WSS2 selection.  An rbf
-    source read by a Pallas backend streams ``src.xt``
+    source read by a Pallas backend must hold its stream ``src.xt``
     (:func:`pad_source`, which the fused engine calls once, before its
-    loop); a source without it has the stream built for this call.
+    loop).
     """
     if src.is_bank:
         KR = src.query(i_idx).astype(G.dtype)

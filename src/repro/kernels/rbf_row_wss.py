@@ -35,8 +35,8 @@ specialized.  Row sources (see :mod:`repro.kernels.row_source`):
   the tile is a (B, BL) slab of the gathered row block (also honouring
   the doubled half structure).
 
-The single-lane pass A is the same kernel at B = 1 that also writes the
-kernel row k_i back (``emit_k``).
+Pass A writes no kernel row back: it only ranks candidates, so its row
+keeps the hardware exp, and pass B recomputes both rows it updates G with.
 
 Working-set indices travel through a dedicated int32 channel (``iscal``),
 never through the data dtype — exact for any l (a float32 round-trip is
@@ -52,7 +52,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.ref import HIGHEST, rbf_exp
+from repro.kernels.ref import HIGHEST
 
 TAU = 1e-12
 # lane-dense width of the per-lane running-selection outputs: TPU blocks
@@ -139,23 +139,21 @@ def _select_from_k(k, G, alpha, L, U, scal, i_idx, b, *, block_l: int,
     return best, barg
 
 
-def _kernel_batched(*refs, block_l: int, base_l: int, masked: bool = False,
-                    emit_k: bool = False):
+def _kernel_batched(*refs, block_l: int, base_l: int, masked: bool = False):
     """Lane-batched pass A (rbf row source): every lane shares the
     (d8, BL) tile of the feature-major X.
 
     The B query rows hit the tile as ONE (B, d8) x (d8, BL) MXU matmul; the
     per-lane gain algebra and masked argmax run on the VPU over (B, BL)
-    registers.  The batched engine writes no k-row back — its pass B
-    recomputes it, trading one extra matmul for an HBM round-trip of
-    (B, l) and for launch-free Alg. 3 candidate swaps; the single-lane
-    engine asks for the row (``emit_k``).  With ``masked=True`` an
+    registers.  No k-row is written back — pass B recomputes it, trading
+    one extra matmul for an HBM round-trip of (B, l) and for launch-free
+    Alg. 3 candidate swaps.  With ``masked=True`` an
     (H, B, BL) active-set tile rides first in the ref list and restricts
     the j-scan (soft shrinking).
     """
     act_ref, refs = (refs[0], refs[1:]) if masked else (None, refs)
     (xq_ref, scal_ref, iscal_ref, Xt_ref, sqn_ref, G_ref, alpha_ref,
-     L_ref, U_ref, bmax_out, barg_out) = refs[:11]
+     L_ref, U_ref, bmax_out, barg_out) = refs
     b = pl.program_id(1)
     sqq = scal_ref[:, 0:1]
     gamma = scal_ref[:, 1:2]
@@ -166,11 +164,8 @@ def _kernel_batched(*refs, block_l: int, base_l: int, masked: bool = False,
                    preferred_element_type=jnp.promote_types(x.dtype,
                                                             jnp.float32))
     d2 = sqq + sqn_ref[...] - 2.0 * prod                    # (B, BL)
-    # a row that only ranks candidates keeps the hardware exp; the row the
-    # single-lane engine updates G with takes the accurate one
-    k = (rbf_exp if emit_k else jnp.exp)(-gamma * jnp.maximum(d2, 0.0))
-    if emit_k:
-        refs[11][...] = k.astype(refs[11].dtype)
+    # a row that only ranks candidates keeps the hardware exp
+    k = jnp.exp(-gamma * jnp.maximum(d2, 0.0))
 
     m, a = _select_from_k(
         k, G_ref[...], alpha_ref[...], L_ref[...], U_ref[...],
@@ -203,12 +198,11 @@ def _selection_out(B: int, block_b: int, dtype):
 
 
 @functools.partial(jax.jit, static_argnames=("block_l", "block_b",
-                                             "interpret", "base_l", "emit_k"))
+                                             "interpret", "base_l"))
 def rbf_row_wss_batched_pallas(Xt, sqn, G, alpha, L, U, XQ, scalars,
                                iscalars, act=None, *, block_l: int = 1024,
                                block_b: int | None = None,
-                               interpret: bool = False, base_l: int = 0,
-                               emit_k: bool = False):
+                               interpret: bool = False, base_l: int = 0):
     """Launch lane-batched pass A.  ``Xt`` is the feature-major X,
     (d8, >= lpad) with zero padding (:func:`repro.kernels.ops.pad_source`),
     and ``sqn`` its column norms, (>= lpad,); the grid reads their first
@@ -223,8 +217,7 @@ def rbf_row_wss_batched_pallas(Xt, sqn, G, alpha, L, U, XQ, scalars,
     stack in the data dtype (1.0/0.0; soft shrinking).  ``block_b``
     (default: all B lanes) splits the lanes into independent grid blocks.
 
-    Returns (j (B,) int32, gain (B,)), plus the base kernel rows
-    (B, lpad) when ``emit_k``.
+    Returns (j (B,) int32, gain (B,)).
     """
     H, B, lpad = G.shape
     d8 = Xt.shape[0]
@@ -236,9 +229,6 @@ def rbf_row_wss_batched_pallas(Xt, sqn, G, alpha, L, U, XQ, scalars,
     lane_spec = pl.BlockSpec((H, bb, block_l), lambda c, b: (0, c, b))
     lane_row = lambda w: pl.BlockSpec((bb, w), lambda c, b: (c, 0))
     out_shapes, out_specs = _selection_out(B, bb, dtype)
-    if emit_k:
-        out_shapes.append(jax.ShapeDtypeStruct((B, lpad), dtype))
-        out_specs.append(pl.BlockSpec((bb, block_l), lambda c, b: (c, b)))
     masked = act is not None
     in_specs = [
         lane_row(d8),                                          # XQ
@@ -252,9 +242,9 @@ def rbf_row_wss_batched_pallas(Xt, sqn, G, alpha, L, U, XQ, scalars,
     if masked:
         in_specs.insert(0, lane_spec)
         args.insert(0, act)
-    out = pl.pallas_call(
+    bmax, barg = pl.pallas_call(
         functools.partial(_kernel_batched, block_l=block_l, base_l=base_l,
-                          masked=masked, emit_k=emit_k),
+                          masked=masked),
         grid=(B // bb, lpad // block_l),
         in_specs=in_specs,
         out_specs=out_specs,
@@ -263,8 +253,7 @@ def rbf_row_wss_batched_pallas(Xt, sqn, G, alpha, L, U, XQ, scalars,
         interpret=interpret,
         name="rbf_row_wss_batched_pallas",
     )(*args)
-    sel = (out[1][:, 0], out[0][:, 0])
-    return sel + (out[2],) if emit_k else sel
+    return barg[:, 0], bmax[:, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("block_l", "block_b",

@@ -20,10 +20,9 @@ an (H, B, lpad) stack of variable halves.  With H = 2 (the ε-SVR doubled
 operator) both base rows k_i / k_j are computed ONCE per grid step from
 the base (d8, BL) tile of the feature-major X (see
 :mod:`repro.kernels.rbf_row_wss`) and applied to each half via index
-arithmetic —
-the matmuls stay l-wide.  The rows variant consumes pre-gathered base rows
-instead (Gram-bank mode).  The single-lane pass B is the rbf kernel at
-B = 1 with k_i read from HBM (``given_ki``) instead of recomputed.
+arithmetic — the matmuls stay l-wide.  The rows variant consumes
+pre-gathered base rows instead (Gram-bank mode).  The rbf kernel always
+recomputes k_i from the i query rows; no row reaches it from HBM.
 """
 
 from __future__ import annotations
@@ -101,7 +100,7 @@ def _emit(refs, G_new, bmax, barg, bmin, b):
 
 
 def _kernel_batched(*refs, block_l: int, base_l: int, masked: bool = False,
-                    conj: bool = False, given_ki: bool = False):
+                    conj: bool = False):
     """Lane-batched pass B (rbf source): recompute BOTH base rows k_i, k_j
     against the shared X tile, update every state half in-register, and
     fold the per-lane next-i argmax plus both KKT gap endpoints.
@@ -120,12 +119,8 @@ def _kernel_batched(*refs, block_l: int, base_l: int, masked: bool = False,
     gains the axpy ``- mu2 dirv`` and the *base* row difference
     ``r = k_i - k_j`` — next iteration's direction — is emitted as a fifth
     output (base width: the doubled halves tile it outside the kernel).
-    With ``given_ki=True`` a (B, BL) k_i row tile rides next (the
-    single-lane engine carries it from pass A) and the i query rows are
-    ignored.
     """
     act_ref, refs = (refs[0], refs[1:]) if masked else (None, refs)
-    ki_ref, refs = (refs[0], refs[1:]) if given_ki else (None, refs)
     (xq_ref, scal_ref, Xt_ref, sqn_ref, G_ref, alpha_ref, L_ref,
      U_ref) = refs[:8]
     refs = refs[8:]
@@ -148,7 +143,7 @@ def _kernel_batched(*refs, block_l: int, base_l: int, masked: bool = False,
     def row(p, sqq):
         return rbf_exp(-gamma * jnp.maximum(sqq + sqn - 2.0 * p, 0.0))
 
-    k_i = ki_ref[...] if given_ki else row(prod[:bb], sqq_i)
+    k_i = row(prod[:bb], sqq_i)
     k_j = row(prod[bb:], sqq_j)
 
     G_new, bmax, barg, bmin = _update_from_rows(
@@ -225,7 +220,7 @@ def _launch(kernel, args, in_specs, *, H, B, bb, lpad, block_l, dtype, act,
 @functools.partial(jax.jit, static_argnames=("block_l", "block_b",
                                              "interpret", "base_l"))
 def rbf_update_wss_batched_pallas(Xt, sqn, G, alpha_new, L, U, XQ, scalars,
-                                  act=None, dirv=None, ki=None, *,
+                                  act=None, dirv=None, *,
                                   block_l: int = 1024,
                                   block_b: int | None = None,
                                   interpret: bool = False, base_l: int = 0):
@@ -246,15 +241,12 @@ def rbf_update_wss_batched_pallas(Xt, sqn, G, alpha_new, L, U, XQ, scalars,
     previous-direction row (the doubled operator's direction is
     half-symmetric, so one base row serves both halves); with it,
     ``scalars`` is (B, 5) [..., mu2] and a fifth output ``r`` (B, lpad) —
-    the base row difference k_i - k_j — is returned.  ``ki`` (B, lpad)
-    supplies the k_i rows instead of recomputing them from the i query
-    rows (which are then ignored)."""
+    the base row difference k_i - k_j — is returned."""
     H, B, lpad = G.shape
     d8 = Xt.shape[0]
     bb = B if block_b is None else block_b
     assert lpad % block_l == 0 and B % bb == 0, (lpad, block_l, B, bb)
     assert Xt.shape[1] >= lpad, (Xt.shape, lpad)
-    given_ki = ki is not None
     in_specs = [
         pl.BlockSpec((2 * bb, d8), lambda c, b: (c, 0)),       # XQ
         pl.BlockSpec((bb, scalars.shape[1]), lambda c, b: (c, 0)),
@@ -262,12 +254,9 @@ def rbf_update_wss_batched_pallas(Xt, sqn, G, alpha_new, L, U, XQ, scalars,
         pl.BlockSpec((1, block_l), lambda c, b: (0, b)),       # sqn
     ]
     args = [XQ, scalars, Xt, sqn.reshape(1, -1), G, alpha_new, L, U]
-    if given_ki:
-        in_specs.insert(0, pl.BlockSpec((bb, block_l), lambda c, b: (c, b)))
-        args.insert(0, ki)
     kernel = functools.partial(_kernel_batched, block_l=block_l,
                                base_l=base_l, masked=act is not None,
-                               conj=dirv is not None, given_ki=given_ki)
+                               conj=dirv is not None)
     return _launch(kernel, args, in_specs, H=H, B=B, bb=bb, lpad=lpad,
                    block_l=block_l, dtype=Xt.dtype, act=act, dirv=dirv,
                    interpret=interpret, name="rbf_update_wss_batched_pallas")

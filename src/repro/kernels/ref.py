@@ -113,10 +113,11 @@ def rbf_update_wss(X, sqn, G, k_i, xq_j, mu, alpha_new, L, U, gamma):
 # same X / sqn; per-lane state is stacked on a leading B axis.  The O(l d B)
 # part — the squared-distance rows — is ONE (B, d) x (d, l) matmul over the
 # shared X, and per-lane gamma costs one extra exp on that shared d2 row
-# (mirroring the solve_grid factorization).  Unlike the single-lane pass A,
-# the batched pass A returns only the selection (j, gain): pass B recomputes
-# both rows k_i / k_j in place of an HBM round-trip, which also lets the
-# Alg. 3 candidate swap the i-row without a data-dependent relaunch.
+# (mirroring the solve_grid factorization).  Unlike the per-lane oracle
+# :func:`rbf_row_wss`, the batched pass A returns only the selection
+# (j, gain): pass B recomputes both rows k_i / k_j in place of an HBM
+# round-trip, which also lets the Alg. 3 candidate swap the i-row without
+# a data-dependent relaunch.
 
 
 def tile_rows(k):

@@ -26,7 +26,7 @@ from repro.analysis import jaxpr_audit
 from repro.core import grid as grid_mod
 from repro.core import qp as qp_mod
 from repro.core.solver import SolverConfig, solve
-from repro.core.solver_fused import (solve_fused, solve_fused_batched,
+from repro.core.solver_fused import (solve_fused_batched,
                                      solve_fused_batched_qp)
 from repro.svm.data import chessboard, gaussian_blobs
 
@@ -50,13 +50,6 @@ def test_conjugate_requires_plain_smo_base():
         SolverConfig(algorithm="pasmo", step="conjugate")
     with pytest.raises(AssertionError):
         SolverConfig(step="newton")
-
-
-def test_single_lane_fused_rejects_conjugate():
-    X, y, C, gamma = _chessboard_problem(n=32)
-    cfg = SolverConfig(eps=1e-3, max_iter=100, **CONJ)
-    with pytest.raises(AssertionError, match="lane-batched"):
-        solve_fused(X, y, C, gamma, cfg, impl="jnp")
 
 
 # ---------------------------------------------------------------------------

@@ -1,6 +1,8 @@
 """Per-kernel allclose tests: Pallas (interpret=True) vs pure-jnp oracle,
 swept over shapes and dtypes."""
 
+from typing import NamedTuple
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -8,11 +10,11 @@ import pytest
 
 from repro.kernels import ops, ref, row_source
 
-# tier-1 runs the two small shapes; the large tiling/padding shapes ride
-# the slow tier (nightly full run + REPRO_IMPL=interpret leg)
-SHAPES = [(64, 2), (200, 7)] + [
-    pytest.param(*s, marks=pytest.mark.slow)
-    for s in [(1024, 16), (1500, 60), (2048, 128)]]
+# single-QP shapes (l, d): tier-1 runs the two small ones; the large
+# tiling/padding shapes ride the slow tier (nightly full run +
+# REPRO_IMPL=interpret leg)
+SHAPES = [(64, 2), (200, 7), (1024, 16), (1500, 60), (2048, 128)]
+TIER1_SHAPES = SHAPES[:2]
 DTYPES = [jnp.float32, jnp.float64]
 # the batched passes stream X feature-major, d padded to a multiple of 8:
 # d not a multiple of 8 (3, 22) and d above 128 (130), at B = 1, 3, 10
@@ -32,50 +34,6 @@ def _setup(l, d, dtype, seed=0):
     sqn = jnp.sum(X * X, axis=-1)
     gamma = jnp.asarray(0.3, dtype)
     return X, sqn, G, alpha, L, U, gamma
-
-
-@pytest.mark.parametrize("l,d", SHAPES)
-@pytest.mark.parametrize("dtype", DTYPES)
-@pytest.mark.parametrize("use_exact", [False, True])
-def test_pass_a_row_wss(l, d, dtype, use_exact):
-    X, sqn, G, alpha, L, U, gamma = _setup(l, d, dtype)
-    i = 3
-    xq = X[i]
-    a_i, L_i, U_i = alpha[i], L[i], U[i]
-    g_i = G[i]
-    args = (X, sqn, G, alpha, L, U, xq, a_i, L_i, U_i, g_i,
-            jnp.asarray(i, jnp.int32), jnp.asarray(use_exact), gamma)
-    k_ref, j_ref, gain_ref = ref.rbf_row_wss(*args)
-    k_pl, j_pl, gain_pl = ops.rbf_row_wss(*args, impl="interpret",
-                                          block_l=256)
-    tol = 1e-5 if dtype == jnp.float32 else 1e-12
-    np.testing.assert_allclose(np.asarray(k_pl), np.asarray(k_ref),
-                               rtol=tol, atol=tol)
-    np.testing.assert_allclose(float(gain_pl), float(gain_ref),
-                               rtol=10 * tol)
-    assert int(j_pl) == int(j_ref)
-
-
-@pytest.mark.parametrize("l,d", SHAPES)
-@pytest.mark.parametrize("dtype", DTYPES)
-def test_pass_b_update_wss(l, d, dtype):
-    X, sqn, G, alpha, L, U, gamma = _setup(l, d, dtype, seed=1)
-    i, j = 3, 11
-    k_i = ref.rbf_row(X, sqn, X[i], gamma)
-    mu = jnp.asarray(0.37, dtype)
-    alpha_new = alpha.at[i].add(mu).at[j].add(-mu)
-    alpha_new = jnp.clip(alpha_new, L, U)
-    G_ref, i_ref, gi_ref, gdn_ref = ref.rbf_update_wss(
-        X, sqn, G, k_i, X[j], mu, alpha_new, L, U, gamma)
-    G_pl, i_pl, gi_pl, gdn_pl = ops.rbf_update_wss(
-        X, sqn, G, k_i, alpha_new, L, U, X[j], mu, gamma,
-        impl="interpret", block_l=256)
-    tol = 1e-5 if dtype == jnp.float32 else 1e-12
-    np.testing.assert_allclose(np.asarray(G_pl), np.asarray(G_ref),
-                               rtol=tol, atol=tol)
-    assert int(i_pl) == int(i_ref)
-    np.testing.assert_allclose(float(gi_pl), float(gi_ref), rtol=10 * tol)
-    np.testing.assert_allclose(float(gdn_pl), float(gdn_ref), rtol=10 * tol)
 
 
 @pytest.mark.parametrize("l1,l2,d", [
@@ -126,18 +84,76 @@ def _lane(M, idx):
     return jnp.take_along_axis(M, idx[:, None], axis=1)[:, 0]
 
 
-@pytest.mark.parametrize("l,d,B", [
-    (64, 2, 3), pytest.param(257, 33, 5, marks=pytest.mark.slow)]
-    + STREAM_SHAPES)
-@pytest.mark.parametrize("dtype", DTYPES)
-def test_pass_a_batched_matches_single_lane(l, d, B, dtype):
-    """Batched pass A (jnp + interpret) == per-lane single-lane oracle."""
-    X, sqn, G, alpha, L, U, gammas, i_idx = _setup_batched(l, d, B, dtype)
+class Lane(NamedTuple):
+    """One QP run as a batch of one: :func:`_setup`'s data at ``seed``,
+    first working-set index ``i``, pass A's gain rule ``use_exact``, pass
+    B's second index ``j`` and step ``mu``, and the l block size."""
+    seed: int
+    i: int
+    block_l: int
+    use_exact: bool = False
+    j: int = 11
+    mu: float = 0.37
+
+
+def _lane_batch(l, d, dtype, lane: Lane):
+    """``lane``'s QP in :func:`_setup_batched`'s (B = 1) layout."""
+    X, sqn, G, alpha, L, U, gamma = _setup(l, d, dtype, seed=lane.seed)
+    return (X, sqn, G[None], alpha[None], L[None], U[None], gamma[None],
+            jnp.asarray([lane.i], jnp.int32))
+
+
+# the batched passes on _setup_batched's lanes, (l, d, B), the 257 shape
+# on the slow tier
+BATCHED_SHAPES = [(64, 2, 3), (257, 33, 5)] + STREAM_SHAPES
+
+
+def _pass_params(lanes):
+    """(dtype, l, d, B, lane) cases of a batched pass against its per-lane
+    oracle: every batched shape at both dtypes (``lane`` None), then the
+    single-QP ``lanes``, each a (dtype, l, d, Lane, id, slow) tuple, at
+    B = 1."""
+    slow = lambda on: [pytest.mark.slow] if on else []
+    name = lambda dt: jnp.dtype(dt).name
+    return ([pytest.param(dt, l, d, B, None, id=f"{name(dt)}-{l}-{d}-{B}",
+                          marks=slow(l == 257))
+             for dt in DTYPES for l, d, B in BATCHED_SHAPES]
+            + [pytest.param(dt, l, d, 1, lane, id=f"{name(dt)}-{id_}",
+                            marks=slow(on))
+               for dt, l, d, lane, id_, on in lanes])
+
+
+PASS_A_LANES = (
+    [(dt, l, d, Lane(seed=0, i=3, block_l=256, use_exact=ue),
+      f"{l}-{d}-1-{'exact' if ue else 'approx'}",
+      (l, d) not in TIER1_SHAPES)
+     for ue in (False, True) for dt in DTYPES for l, d in SHAPES]
+    # the block shape must not change results (padding/tiling invariance)
+    + [(jnp.float64, 389, 13, Lane(seed=4, i=42, block_l=bl),
+        f"389-13-1-block{bl}", bl > 256) for bl in (128, 256, 512, 1024)])
+PASS_B_LANES = [(dt, l, d, Lane(seed=1, i=3, block_l=256), f"{l}-{d}-1",
+                 (l, d) not in TIER1_SHAPES)
+                for dt in DTYPES for l, d in SHAPES]
+
+
+@pytest.mark.parametrize("dtype,l,d,B,lane", _pass_params(PASS_A_LANES))
+def test_pass_a_batched_matches_single_lane(dtype, l, d, B, lane):
+    """Batched pass A (jnp + interpret) == per-lane single-lane oracle, on
+    B lanes or on one QP as a batch of one (``lane``)."""
+    if lane is None:
+        X, sqn, G, alpha, L, U, gammas, i_idx = _setup_batched(l, d, B,
+                                                               dtype)
+        use_exact = jnp.asarray([b % 2 == 0 for b in range(B)])
+        block_l = 128
+    else:
+        X, sqn, G, alpha, L, U, gammas, i_idx = _lane_batch(l, d, dtype,
+                                                            lane)
+        use_exact = jnp.asarray([lane.use_exact])
+        block_l = lane.block_l
     XQ = jnp.take(X, i_idx, axis=0)
     sqq = jnp.take(sqn, i_idx)
     a_i, L_i, U_i = _lane(alpha, i_idx), _lane(L, i_idx), _lane(U, i_idx)
     g_i = _lane(G, i_idx)
-    use_exact = jnp.asarray([b % 2 == 0 for b in range(B)])
     js, gains = [], []
     for b in range(B):
         _, j, g = ref.rbf_row_wss(X, sqn, G[b], alpha[b], L[b], U[b], XQ[b],
@@ -149,23 +165,29 @@ def test_pass_a_batched_matches_single_lane(l, d, B, dtype):
     for impl in ("jnp", "interpret"):
         j_b, gain_b = ops.rbf_row_wss_batched(
             X, sqn, G, alpha, L, U, XQ, sqq, a_i, L_i, U_i, g_i, i_idx,
-            use_exact, gammas, impl=impl, block_l=128)
+            use_exact, gammas, impl=impl, block_l=block_l)
         assert [int(x) for x in j_b] == js, impl
         np.testing.assert_allclose(np.asarray(gain_b), gains, rtol=tol)
 
 
-@pytest.mark.parametrize("l,d,B", [
-    (64, 2, 3), pytest.param(257, 33, 5, marks=pytest.mark.slow)]
-    + STREAM_SHAPES)
-@pytest.mark.parametrize("dtype", DTYPES)
-def test_pass_b_batched_matches_single_lane(l, d, B, dtype):
-    """Batched pass B (jnp + interpret) == per-lane single-lane oracle,
-    including a frozen (mu = 0) lane whose G must come back unchanged."""
-    X, sqn, G, alpha, L, U, gammas, i_idx = _setup_batched(l, d, B, dtype,
-                                                           seed=1)
-    rng = np.random.default_rng(2)
-    j_idx = jnp.asarray(rng.integers(0, l, B), jnp.int32)
-    mu = jnp.asarray(rng.uniform(-0.5, 0.5, B), dtype).at[0].set(0.0)
+@pytest.mark.parametrize("dtype,l,d,B,lane", _pass_params(PASS_B_LANES))
+def test_pass_b_batched_matches_single_lane(dtype, l, d, B, lane):
+    """Batched pass B (jnp + interpret) == per-lane single-lane oracle, on
+    B lanes, including a frozen (mu = 0) lane whose G must come back
+    unchanged, or on one QP as a batch of one (``lane``)."""
+    if lane is None:
+        X, sqn, G, alpha, L, U, gammas, i_idx = _setup_batched(
+            l, d, B, dtype, seed=1)
+        rng = np.random.default_rng(2)
+        j_idx = jnp.asarray(rng.integers(0, l, B), jnp.int32)
+        mu = jnp.asarray(rng.uniform(-0.5, 0.5, B), dtype).at[0].set(0.0)
+        block_l = 128
+    else:
+        X, sqn, G, alpha, L, U, gammas, i_idx = _lane_batch(l, d, dtype,
+                                                            lane)
+        j_idx = jnp.asarray([lane.j], jnp.int32)
+        mu = jnp.asarray([lane.mu], dtype)
+        block_l = lane.block_l
     XQi = jnp.take(X, i_idx, axis=0)
     XQj = jnp.take(X, j_idx, axis=0)
     sqqi, sqqj = jnp.take(sqn, i_idx), jnp.take(sqn, j_idx)
@@ -178,20 +200,23 @@ def test_pass_b_batched_matches_single_lane(l, d, B, dtype):
         refs.append(ref.rbf_update_wss(X, sqn, G[b], k_i, XQj[b], mu[b],
                                        alpha_new[b], L[b], U[b], gammas[b]))
     tol = 1e-4 if dtype == jnp.float32 else 1e-11
+    # the single-QP cases hold G to a tenth of that
+    g_tol = tol if lane is None else tol / 10
     for impl in ("jnp", "interpret"):
         Gn, i_n, gi_n, gdn = ops.rbf_update_wss_batched(
             X, sqn, G, alpha_new, L, U, XQi, sqqi, XQj, sqqj, mu, gammas,
-            impl=impl, block_l=128)
+            impl=impl, block_l=block_l)
         np.testing.assert_allclose(np.asarray(Gn),
                                    np.stack([np.asarray(r[0]) for r in refs]),
-                                   rtol=tol, atol=tol)
+                                   rtol=g_tol, atol=g_tol)
         assert [int(x) for x in i_n] == [int(r[1]) for r in refs], impl
         np.testing.assert_allclose(np.asarray(gi_n),
                                    [float(r[2]) for r in refs], rtol=tol)
         np.testing.assert_allclose(np.asarray(gdn),
                                    [float(r[3]) for r in refs], rtol=tol)
-        # the frozen lane: bitwise no-op on G
-        np.testing.assert_array_equal(np.asarray(Gn[0]), np.asarray(G[0]))
+        if lane is None:   # the frozen lane: bitwise no-op on G
+            np.testing.assert_array_equal(np.asarray(Gn[0]),
+                                          np.asarray(G[0]))
 
 
 def _setup_doubled(l, d, B, dtype, seed=0):
@@ -388,6 +413,24 @@ def test_pad_source_streams_x_feature_major(d, dup):
     assert src.X is X and src.base_l == l
 
 
+def test_pallas_passes_require_the_stream():
+    """An rbf source reaches a Pallas pass only with the stream
+    :func:`ops.pad_source` builds: no pass pads X for itself (inside the
+    engine's loop that would pad X on every trip)."""
+    X, sqn, G, alpha, L, U, gammas, i_idx = _setup_batched(64, 3, 2,
+                                                           jnp.float64)
+    src = row_source.rbf_source(X, gammas, 2)
+    lane = lambda M: _lane(M, i_idx)
+    with pytest.raises(AssertionError, match="pad_source"):
+        ops.source_row_wss(src, G, alpha, L, U, i_idx, lane(alpha),
+                           lane(L), lane(U), lane(G),
+                           jnp.asarray([True, False]), impl="interpret",
+                           block_l=128)
+    with pytest.raises(AssertionError, match="pad_source"):
+        ops.source_update_wss(src, G, alpha, L, U, i_idx, i_idx,
+                              jnp.zeros(2), impl="interpret", block_l=128)
+
+
 def test_index_channel_is_exact_beyond_float32_significand():
     """Satellite regression: working-set indices travel through the int32
     side channel, never the data dtype — a float32 round-trip corrupts
@@ -405,23 +448,12 @@ def test_index_channel_is_exact_beyond_float32_significand():
     X, sqn, G, alpha, L, U, gamma = _setup(130, 3, jnp.float32, seed=7)
     alpha = alpha.at[5].set(0.5 * (L[5] + U[5]))   # strictly inside the box
     g_i = G[5] + 50.0
-    args = (X, sqn, G, alpha, L, U, X[5], alpha[5], L[5], U[5], g_i,
-            jnp.asarray(5, jnp.int32), jnp.asarray(False), gamma)
-    _, j_ref, _ = ref.rbf_row_wss(*args)
-    _, j_pl, _ = ops.rbf_row_wss(*args, impl="interpret", block_l=128)
-    assert int(j_pl) == int(j_ref) != 5
-
-
-@pytest.mark.parametrize("block_l", [
-    128, 256, pytest.param(512, marks=pytest.mark.slow),
-    pytest.param(1024, marks=pytest.mark.slow)])
-def test_pass_a_block_size_sweep(block_l):
-    """Block shape must not change results (padding/tiling invariance)."""
-    X, sqn, G, alpha, L, U, gamma = _setup(389, 13, jnp.float64, seed=4)
-    i = 42
-    args = (X, sqn, G, alpha, L, U, X[i], alpha[i], L[i], U[i], G[i],
-            jnp.asarray(i, jnp.int32), jnp.asarray(False), gamma)
-    k_ref, j_ref, gain_ref = ref.rbf_row_wss(*args)
-    k, j, gain = ops.rbf_row_wss(*args, impl="interpret", block_l=block_l)
-    np.testing.assert_allclose(np.asarray(k), np.asarray(k_ref), rtol=1e-12)
-    assert int(j) == int(j_ref)
+    i_idx = jnp.asarray([5], jnp.int32)
+    _, j_ref, _ = ref.rbf_row_wss(X, sqn, G, alpha, L, U, X[5], alpha[5],
+                                  L[5], U[5], g_i, i_idx[0],
+                                  jnp.asarray(False), gamma)
+    j_pl, _ = ops.rbf_row_wss_batched(
+        X, sqn, G[None], alpha[None], L[None], U[None], X[5:6], sqn[5:6],
+        alpha[5:6], L[5:6], U[5:6], g_i[None], i_idx, jnp.asarray([False]),
+        gamma[None], impl="interpret", block_l=128)
+    assert int(j_pl[0]) == int(j_ref) != 5

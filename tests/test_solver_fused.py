@@ -1,5 +1,6 @@
 """Fused two-pass solver vs the standard solver: same optimum, same
-algorithm semantics, on both the jnp and the Pallas-interpret backends."""
+algorithm semantics, on both the jnp and the Pallas-interpret backends.
+A single QP runs as a batch of one lane."""
 
 import jax
 import jax.numpy as jnp
@@ -9,7 +10,7 @@ import pytest
 from repro.analysis.jaxpr_audit import iter_eqns
 from repro.core import qp as qp_mod
 from repro.core.solver import SolverConfig, solve
-from repro.core.solver_fused import solve_fused, solve_fused_batched_qp
+from repro.core.solver_fused import solve_fused_batched, solve_fused_batched_qp
 from repro.svm.data import gaussian_blobs, ring, xor_gaussians
 
 
@@ -21,14 +22,21 @@ def _problem(name, n, seed=0):
     return X, y, C, gamma
 
 
+def _solve_one(X, y, C, gamma, cfg, **kw):
+    """One QP through the lane-batched engine (``Y = y[None]``), read on
+    lane 0."""
+    r = solve_fused_batched(jnp.asarray(X), jnp.asarray(y)[None], C, gamma,
+                            cfg, **kw)
+    return jax.tree_util.tree_map(lambda a: a[0], r)
+
+
 @pytest.mark.parametrize("alg", ["smo", "pasmo"])
 @pytest.mark.parametrize("name", [
     "blobs", pytest.param("ring", marks=pytest.mark.slow), "xor"])
 def test_fused_jnp_matches_standard(alg, name):
     X, y, C, gamma = _problem(name, 64)
     cfg = SolverConfig(algorithm=alg, eps=1e-4, max_iter=100_000)
-    rf = solve_fused(jnp.asarray(X), jnp.asarray(y), C, gamma, cfg,
-                     impl="jnp")
+    rf = _solve_one(X, y, C, gamma, cfg, impl="jnp")
     rs = solve(qp_mod.make_rbf(jnp.asarray(X), gamma), jnp.asarray(y), C,
                cfg)
     assert bool(rf.converged) and bool(rs.converged)
@@ -45,10 +53,8 @@ def test_fused_pallas_interpret_matches_jnp(alg):
     """The Pallas kernels inside the full solve loop (interpret mode)."""
     X, y, C, gamma = _problem("xor", 64, seed=1)
     cfg = SolverConfig(algorithm=alg, eps=1e-3, max_iter=20_000)
-    r_jnp = solve_fused(jnp.asarray(X), jnp.asarray(y), C, gamma, cfg,
-                        impl="jnp")
-    r_pl = solve_fused(jnp.asarray(X), jnp.asarray(y), C, gamma, cfg,
-                       impl="interpret", block_l=128)
+    r_jnp = _solve_one(X, y, C, gamma, cfg, impl="jnp")
+    r_pl = _solve_one(X, y, C, gamma, cfg, impl="interpret", block_l=128)
     assert bool(r_pl.converged)
     np.testing.assert_allclose(float(r_pl.objective), float(r_jnp.objective),
                                rtol=1e-6)
@@ -59,8 +65,7 @@ def test_fused_pallas_interpret_matches_jnp(alg):
 def test_fused_feasible():
     X, y, C, gamma = _problem("ring", 70, seed=2)
     cfg = SolverConfig(algorithm="pasmo", eps=1e-4)
-    r = solve_fused(jnp.asarray(X), jnp.asarray(y), C, gamma, cfg,
-                    impl="jnp")
+    r = _solve_one(X, y, C, gamma, cfg, impl="jnp")
     bounds = qp_mod.make_bounds(jnp.asarray(y), C)
     assert bool(qp_mod.is_feasible(r.alpha, bounds, atol=1e-8))
     # maintained gradient equals y - K alpha
